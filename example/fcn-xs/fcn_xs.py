@@ -22,10 +22,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
-plat = os.environ.get("JAX_PLATFORMS")
-if plat:
-    import jax
-    jax.config.update("jax_platforms", plat)
 
 import numpy as np
 
@@ -118,7 +114,7 @@ def main():
     rng = np.random.RandomState(7)
     mx.random.seed(1)  # deterministic init from the framework stream (r5)
     net = get_fcn16s()
-    mod = mx.mod.Module(net, context=mx.tpu() if mx.num_tpus() else mx.cpu(),
+    mod = mx.mod.Module(net,
                         label_names=("softmax_label",))
     mod.bind(data_shapes=[("data", (args.batch_size, 1, SIDE, SIDE))],
              label_shapes=[("softmax_label", (args.batch_size, SIDE, SIDE))])

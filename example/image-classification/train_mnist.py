@@ -73,7 +73,7 @@ def main():
 
     net = get_mlp() if args.network == "mlp" else get_lenet()
     train, val = get_mnist_iters(args.batch_size, args.data_dir)
-    mod = mx.mod.Module(net, context=mx.tpu() if mx.num_tpus() else mx.cpu())
+    mod = mx.mod.Module(net)
     cb = [mx.callback.Speedometer(args.batch_size, 50)]
     if args.model_prefix:
         epoch_cb = mx.callback.do_checkpoint(args.model_prefix)

@@ -17,10 +17,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
-plat = os.environ.get("JAX_PLATFORMS")
-if plat:
-    import jax
-    jax.config.update("jax_platforms", plat)
 
 import numpy as np
 

@@ -19,10 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
-plat = os.environ.get("JAX_PLATFORMS")
-if plat:
-    import jax
-    jax.config.update("jax_platforms", plat)
 
 import numpy as np
 
@@ -92,7 +88,6 @@ def main():
     print("train triples %d, test triples %d" % (tu.size, vu.size))
 
     mod = mx.mod.Module(get_mf(args.rank),
-                        context=mx.tpu() if mx.num_tpus() else mx.cpu(),
                         data_names=("user", "item"), label_names=("lro_label",))
     mod.bind(data_shapes=[("user", (args.batch_size,)),
                           ("item", (args.batch_size,))],

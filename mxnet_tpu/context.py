@@ -9,14 +9,19 @@ first-class accelerator; ``mx.gpu(i)`` is kept as a compatibility alias that
 resolves to the i-th accelerator so reference scripts run unchanged.  There is
 no pinned/shared distinction — host staging is managed by XLA transfers and
 DataLoader workers ship numpy through shared memory at the Python level.
+
+The default context is the first local device of JAX's default backend: the
+chip where there is one, the host CPU under ``JAX_PLATFORMS=cpu``.  It is the
+same on every thread; ``with ctx:`` overrides it for the enclosing thread only.
 """
 from __future__ import annotations
 
+import functools
 import threading
 
-__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
+from .base import MXNetError
 
-_context_stack = threading.local()
+__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
 
 
 class Context:
@@ -24,6 +29,7 @@ class Context:
 
     devtype2str = {1: "cpu", 2: "tpu", 3: "cpu_pinned", 5: "cpu_shared"}
     devstr2type = {"cpu": 1, "tpu": 2, "gpu": 2, "cpu_pinned": 3, "cpu_shared": 5}
+    # per-thread `with ctx:` override; unset means the process default
     _default_ctx = threading.local()
 
     def __init__(self, device_type, device_id=0):
@@ -53,9 +59,7 @@ class Context:
     __repr__ = __str__
 
     def __enter__(self):
-        if not hasattr(Context._default_ctx, "value"):
-            Context._default_ctx.value = Context("cpu", 0)
-        self._old_ctx = Context._default_ctx.value
+        self._old_ctx = getattr(Context._default_ctx, "value", None)
         Context._default_ctx.value = self
         return self
 
@@ -64,33 +68,36 @@ class Context:
 
     # --- JAX resolution -------------------------------------------------
     def jax_device(self):
-        """Resolve to a concrete jax.Device (lazily; may fall back to cpu).
+        """Resolve to a concrete jax.Device.
 
         Only ADDRESSABLE devices are eligible: under multi-process
         jax.distributed, jax.devices() includes other workers' devices and
         placing an array there raises (each process owns its local shard —
-        the reference's one-Context-per-worker model, kvstore_dist.h:50)."""
+        the reference's one-Context-per-worker model, kvstore_dist.h:50).
+
+        ``cpu(i)`` always means the host, also where the chip is JAX's
+        default backend (check_consistency depends on this).  ``tpu(i)``
+        names exactly the i-th local accelerator and raises when there is
+        none: a missing chip is an error, never a CPU device or another
+        chip."""
         import jax
-        local = jax.local_devices()
-        if self.device_type == "cpu" or self.device_typeid in (3, 5):
-            # local_devices() lists only the DEFAULT backend — on a TPU
-            # host that excludes the always-present cpu backend, and the
-            # old platform filter silently fell back to the accelerator.
-            # Ask the cpu backend directly so cpu(0) means host cpu even
-            # when tpu is default (check_consistency depends on this).
-            try:
-                devs = jax.local_devices(backend="cpu")
-            except RuntimeError:
-                devs = [d for d in local if d.platform == "cpu"] or local
+        if self.device_type != "tpu":
+            devs = jax.local_devices(backend="cpu")
             return devs[min(self.device_id, len(devs) - 1)]
-        # accelerator ('tpu' or legacy 'gpu' alias)
-        accel = [d for d in local if d.platform != "cpu"]
-        if not accel:  # no accelerator present (test / CI): fall back
-            return local[min(self.device_id, len(local) - 1)]
-        return accel[min(self.device_id, len(accel) - 1)]
+        accel = [d for d in jax.local_devices() if d.platform != "cpu"]
+        if not 0 <= self.device_id < len(accel):
+            raise MXNetError(
+                "%s: this process has %d local accelerator device(s) "
+                "(JAX default backend: %s)"
+                % (self, len(accel), jax.default_backend()))
+        return accel[self.device_id]
 
 
-Context._default_ctx.value = Context("cpu", 0)
+@functools.lru_cache(maxsize=None)
+def _default_device_type():
+    """Device type of JAX's default backend (fixed once backends exist)."""
+    import jax
+    return "cpu" if jax.default_backend() == "cpu" else "tpu"
 
 
 def cpu(device_id=0):
@@ -107,9 +114,8 @@ def tpu(device_id=0):
 
 
 def current_context():
-    if not hasattr(Context._default_ctx, "value"):
-        Context._default_ctx.value = Context("cpu", 0)
-    return Context._default_ctx.value
+    ctx = getattr(Context._default_ctx, "value", None)
+    return ctx if ctx is not None else Context(_default_device_type(), 0)
 
 
 def num_gpus():

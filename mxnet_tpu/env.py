@@ -73,8 +73,6 @@ _V = [
     # --- profiling / testing ----------------------------------------------
     EnvVar("MXNET_PROFILER_AUTOSTART", bool, False,
            "Start the jax.profiler trace at import (profiler.py)."),
-    EnvVar("MXNET_TEST_DEVICE", str, "cpu",
-           "Device the test harness targets (cpu simulation vs real TPU)."),
     EnvVar("MXNET_TEST_SEED", int, None,
            "Fixed RNG seed for test reproduction (conftest logs it)."),
     # --- benchmarks -------------------------------------------------------
@@ -94,16 +92,6 @@ _V = [
     EnvVar("BENCH_LAYOUT", str, "auto",
            "bench.py conv data layout: auto (measure NCHW and NHWC, report "
            "the faster), NCHW, or NHWC."),
-    EnvVar("BENCH_BUDGET", float, 1400.0,
-           "bench.py total wall-clock budget across probes and retries."),
-    EnvVar("BENCH_TIMEOUT", float, 380.0,
-           "bench.py per-attempt child timeout (seconds); retried while "
-           "budget remains."),
-    EnvVar("BENCH_PROBE_TIMEOUT", float, 45.0,
-           "bench.py pre-flight backend-probe timeout (a down relay hangs "
-           "init, so each attempt is gated on a disposable probe)."),
-    EnvVar("BENCH_RETRY_DELAY", float, 10.0,
-           "bench.py base delay between probe/attempt retries."),
 ]
 
 VARIABLES = {v.name: v for v in _V}

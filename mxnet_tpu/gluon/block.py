@@ -19,7 +19,7 @@ import warnings
 from collections import OrderedDict
 
 from ..base import MXNetError
-from ..context import Context, cpu, current_context
+from ..context import Context, current_context
 from ..ndarray import NDArray
 from .. import ndarray as nd_mod
 from .. import autograd

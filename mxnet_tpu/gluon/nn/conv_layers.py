@@ -142,14 +142,14 @@ class _Conv(HybridBlock):
                 + self._kernel
 
     def hybrid_forward(self, F, x, weight, bias=None):
-        attrs = {k: v for k, v in self._kwargs.items() if k != "num_filter"}
+        # num_filter rides along: symbolic shape inference deduces a
+        # deferred weight's in_channels from it (ops/nn_ops.py param shapes)
+        attrs = {k: v for k, v in self._kwargs.items() if k != "no_bias"}
         op = getattr(F, self._op_name)
         if bias is None:
-            act = op(x, weight, no_bias=True,
-                     **{k: v for k, v in attrs.items() if k != "no_bias"})
+            act = op(x, weight, no_bias=True, **attrs)
         else:
-            act = op(x, weight, bias, no_bias=False,
-                     **{k: v for k, v in attrs.items() if k != "no_bias"})
+            act = op(x, weight, bias, no_bias=False, **attrs)
         if self.act is not None:
             act = self.act(act) if not F.__name__.endswith("symbol") \
                 else self.act._build_symbol(act)

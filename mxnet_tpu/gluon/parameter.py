@@ -17,7 +17,7 @@ from collections import OrderedDict
 import numpy as _np
 
 from ..base import MXNetError
-from ..context import Context, cpu, current_context
+from ..context import Context, current_context
 from ..ndarray import NDArray, zeros, array
 from .. import autograd
 from .. import initializer as init_mod
@@ -158,7 +158,7 @@ class Parameter:
             ctx = [ctx]
         if self._data is None:
             self._deferred_init = ()
-            self._init_impl(data, ctx or [cpu()])
+            self._init_impl(data, ctx or [current_context()])
         else:
             for d in self._data:
                 d._set_data(data.as_in_context(d.context)._data)

@@ -58,7 +58,10 @@ class Initializer:
             desc.global_init = self
         init = getattr(desc, "attrs", {}).get("__init__", "")
         if init:
-            klass, kwargs = json.loads(init)
+            # a Variable's own initializer: dumps() JSON, or a bare registry
+            # name ("ones" — what gluon Parameter.var() records)
+            klass, kwargs = json.loads(init) if init.startswith("[") \
+                else (init, {})
             _INITIALIZER_REGISTRY[klass.lower()](**kwargs)._init_weight(desc, arr)
             return
         name = str(desc)

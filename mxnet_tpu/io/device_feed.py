@@ -256,9 +256,9 @@ class DeviceFeed:
         if depth < 1:
             raise ValueError("DeviceFeed depth must be >= 1, got %r" % depth)
         if stage and ctx is None and mesh is None:
-            # snapshot the CALLER's context scope here: the worker thread
-            # has its own (fresh, cpu-default) thread-local context stack,
-            # so resolving there would silently ignore `with mx.tpu(0):`
+            # snapshot the CALLER's context scope here: a `with ctx:`
+            # block belongs to the thread that entered it, so resolving on
+            # the worker thread would silently ignore `with mx.tpu(0):`
             from ..context import current_context
             ctx = current_context()
         self._state = _FeedState(source, ctx, mesh, transform, depth, name,

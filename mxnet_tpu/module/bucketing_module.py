@@ -14,7 +14,7 @@ import warnings
 
 from .base_module import BaseModule
 from .module import Module
-from ..context import cpu
+from ..context import current_context
 
 
 class BucketingModule(BaseModule):
@@ -25,7 +25,8 @@ class BucketingModule(BaseModule):
         assert default_bucket_key is not None
         self._default_bucket_key = default_bucket_key
         self._sym_gen = sym_gen
-        self._context = context if context is not None else cpu()
+        self._context = context if context is not None \
+            else current_context()
         self._work_load_list = work_load_list
         self._fixed_param_names = fixed_param_names
         self._state_names = state_names

@@ -276,7 +276,7 @@ class CompiledTrainStep:
             import jax
             import jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from ..parallel.collectives import allgather
             from ..parallel.zero import (flatten_param, unflatten_param,
                                          quantized_reduce_scatter)
@@ -341,7 +341,7 @@ class CompiledTrainStep:
                                 for _ in gf),
                           s_specs, r_specs, P(), P()),
                 out_specs=(tuple(P() for _ in wf), s_specs, r_specs),
-                check_rep=False)
+                check_vma=False)
             new_w, new_s, new_r = region_sh(wf, gf, sf, rf, lr_t, t_t)
             for i, (index, pkey, template, leaf_keys) in \
                     enumerate(opt_bindings):

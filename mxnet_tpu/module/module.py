@@ -14,7 +14,7 @@ from .base_module import BaseModule, _check_input_names
 from .executor_group import DataParallelExecutorGroup
 from .. import ndarray as nd
 from .. import optimizer as opt
-from ..context import cpu, Context
+from ..context import Context, current_context
 from ..initializer import Uniform, InitDesc
 from ..model import (_create_kvstore, _initialize_kvstore, _update_params,
                      _update_params_on_kvstore, _update_params_on_kvstore_nccl,
@@ -29,7 +29,7 @@ class Module(BaseModule):
                  fixed_param_names=None, state_names=None, group2ctxs=None,
                  compression_params=None):
         super().__init__(logger=logger)
-        ctxs = context if context is not None else cpu()
+        ctxs = context if context is not None else current_context()
         self._context = [ctxs] if isinstance(ctxs, Context) else list(ctxs)
         self._work_load_list = (list(work_load_list) if work_load_list
                                 else [1] * len(self._context))

@@ -619,8 +619,7 @@ def waitall():
     pending computation.  We track live NDArrays weakly and
     block_until_ready each — plus an effects barrier for callbacks."""
     import jax
-    if hasattr(jax, "effects_barrier"):
-        jax.effects_barrier()
+    jax.effects_barrier()
     for arr in list(_LIVE_ARRAYS):
         data = getattr(arr, "_data_buf", None)
         if data is not None and hasattr(data, "block_until_ready"):

@@ -6,24 +6,15 @@ the accelerator kernels are Pallas: tiled flash attention with the streaming
 log-sum-exp softmax, keeping the working set in VMEM and the QK^T / PV matmuls
 on the MXU.
 
-Every kernel has a pure-XLA fallback (used on CPU and as the vjp path);
-``_use_pallas()`` picks the implementation by backend.
+On a TPU backend the entry points run the kernel, and a kernel the compiler
+refuses is an error the caller sees.  Elsewhere they run the dense XLA
+reference (also the vjp path): the Pallas TPU lowering exists only for TPUs.
 """
 from __future__ import annotations
-
-import functools
 
 import numpy as _np
 
 from .registry import register
-
-
-def _use_pallas():
-    import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -51,19 +42,22 @@ def _attention_reference(q, k, v, causal, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
-def _flash_attention_pallas(q, k, v, causal, scale, block_q=128, block_k=128,
+def _flash_attention_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
                             interpret=False):
-    """Tiled attention: grid over (batch*heads, q blocks); inner fori_loop
-    streams K/V blocks through VMEM with the online-softmax accumulator.
+    """Tiled attention: grid over (batch*heads, q blocks, k blocks).  K/V
+    stream through VMEM one ``(block_k, D)`` block per grid step while the
+    online-softmax state (running max, normalizer, accumulator) lives in
+    VMEM scratch across the k steps of one q block.  VMEM use is therefore
+    bounded by the block sizes, never by the sequence length.
 
     Ragged sequence lengths are handled by padding q/k/v up to the tile
     size and masking the padded key columns to -inf inside the kernel (the
     padded query rows compute garbage that is sliced off afterwards) — so
-    T % 128 != 0 workloads keep the fused path instead of falling back to
-    the dense XLA reference."""
+    T % block != 0 workloads stay on the fused path."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Tk = k.shape[2]
@@ -81,71 +75,83 @@ def _flash_attention_pallas(q, k, v, causal, scale, block_q=128, block_k=128,
     k_tail = bool(pad_k)  # static: tail masking compiled in only if needed
     c_off = _causal_offset(causal, T, Tk)  # offsets use UNPADDED lengths
 
-    def kernel(q_ref, k_ref, v_ref, o_ref):
+    def last_k_block(qi):
+        """Last k block the causal q block ``qi`` can see."""
+        return jnp.minimum(((qi + 1) * block_q - 1 + c_off) // block_k,
+                           n_k_blocks - 1)
+
+    def kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
         qi = pl.program_id(1)
-        q_blk = q_ref[...].astype(jnp.float32) * scale        # (bq, D)
-        m = jnp.full((block_q,), -1e30, jnp.float32)
-        l = jnp.zeros((block_q,), jnp.float32)
-        acc = jnp.zeros((block_q, D), jnp.float32)
+        ki = pl.program_id(2)
 
-        def make_body(with_tail):
-            def body(ki, carry):
-                m_, l_, acc_ = carry
-                k_blk = k_ref[pl.dslice(ki * block_k, block_k), :].astype(
-                    jnp.float32)
-                v_blk = v_ref[pl.dslice(ki * block_k, block_k), :].astype(
-                    jnp.float32)
-                s = q_blk @ k_blk.T                           # MXU
-                if causal or with_tail:
-                    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1)
-                    keep = jnp.ones_like(k_pos, dtype=bool)
-                    if causal:
-                        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                            jnp.int32, (block_q, block_k), 0)
-                        keep &= q_pos + c_off >= k_pos
-                    if with_tail:
-                        keep &= k_pos < Tk  # padded keys contribute nothing
-                    s = jnp.where(keep, s, -1e30)
-                m_cur = jnp.max(s, axis=1)
-                m_new = jnp.maximum(m_, m_cur)
-                p = jnp.exp(s - m_new[:, None])
-                alpha = jnp.exp(m_ - m_new)
-                l_new = alpha * l_ + jnp.sum(p, axis=1)
-                acc_new = acc_ * alpha[:, None] + p @ v_blk   # MXU
-                return m_new, l_new, acc_new
-            return body
+        @pl.when(ki == 0)
+        def _():
+            m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-        carry = (m, l, acc)
+        def accumulate():
+            q_blk = q_ref[...].astype(jnp.float32) * scale        # (bq, D)
+            k_blk = k_ref[...].astype(jnp.float32)                # (bk, D)
+            v_blk = v_ref[...].astype(jnp.float32)
+            s = jax.lax.dot_general(                              # MXU
+                q_blk, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)               # (bq, bk)
+            if causal or k_tail:
+                k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                keep = jnp.ones_like(k_pos, dtype=bool)
+                if causal:
+                    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_q, block_k), 0)
+                    keep &= q_pos + c_off >= k_pos
+                if k_tail:
+                    keep &= k_pos < Tk  # padded keys contribute nothing
+                s = jnp.where(keep, s, -1e30)
+            m_prev = m_ref[...]                                   # (bq, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(        # MXU
+                p, v_blk, preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+
         if causal:
-            # per-row masks are computed anyway; fold the tail predicate in
-            upper = jax.lax.clamp(0, ((qi + 1) * block_q + c_off) // block_k
-                                  + 1, n_k_blocks)
-            carry = jax.lax.fori_loop(0, upper, make_body(k_tail), carry)
-        elif k_tail:
-            # peel the final block: interior blocks skip the mask entirely
-            carry = jax.lax.fori_loop(0, n_k_blocks - 1, make_body(False),
-                                      carry)
-            carry = make_body(True)(n_k_blocks - 1, carry)
+            # k blocks wholly above the diagonal contribute nothing
+            pl.when(ki <= last_k_block(qi))(accumulate)
         else:
-            carry = jax.lax.fori_loop(0, n_k_blocks, make_body(False), carry)
-        m, l, acc = carry
-        o_ref[...] = (acc / l[:, None]).astype(o_ref.dtype)
+            accumulate()
+
+        @pl.when(ki == n_k_blocks - 1)
+        def _():
+            o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
     qf = q.reshape(B * H, Tq_t, D)
     kf = k.reshape(B * H, Tk_t, D)
     vf = v.reshape(B * H, Tk_t, D)
 
+    # a causal q block re-names its last visible k block for the steps past
+    # the diagonal: an unchanged block index is not fetched again
+    kv_spec = pl.BlockSpec(
+        (None, block_k, D),
+        (lambda b, i, j: (b, jnp.minimum(j, last_k_block(i)), 0)) if causal
+        else (lambda b, i, j: (b, j, 0)))
     out = pl.pallas_call(
         kernel,
-        grid=(B * H, Tq_t // block_q),
+        grid=(B * H, Tq_t // block_q, n_k_blocks),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk_t, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk_t, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
+            kv_spec, kv_spec,
         ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq_t, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
     out = out.reshape(B, H, Tq_t, D)
@@ -154,6 +160,7 @@ def _flash_attention_pallas(q, k, v, causal, scale, block_q=128, block_k=128,
 
 def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
     """Fused attention entry: Pallas kernel on TPU, XLA reference elsewhere.
+    ``interpret`` (tests only) forces the kernel, interpreted or compiled.
 
     q/k/v: (B, H, T, D).  Differentiable: custom_vjp with the reference
     backward (recompute-based, XLA-fused).
@@ -184,18 +191,15 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
         raise ValueError(
             "causal='bottom' needs q length <= k length, got %d vs %d"
             % (q.shape[2], k.shape[2]))
-    use_pallas = _use_pallas() if interpret is None else True
+    use_pallas = interpret is not None or jax.default_backend() == "tpu"
 
     @jax.custom_vjp
     def f(q_, k_, v_):
         # ragged lengths stay on the fused path: the kernel pads to tile
         # multiples and masks the tail keys itself
-        if use_pallas or interpret:
-            try:
-                return _flash_attention_pallas(q_, k_, v_, causal, scale,
-                                               interpret=bool(interpret))
-            except Exception:
-                return _attention_reference(q_, k_, v_, causal, scale)
+        if use_pallas:
+            return _flash_attention_pallas(q_, k_, v_, causal, scale,
+                                           interpret=bool(interpret))
         return _attention_reference(q_, k_, v_, causal, scale)
 
     def f_fwd(q_, k_, v_):

@@ -396,10 +396,14 @@ class SGLD(Optimizer):
         g = grad * self.rescale_grad
         if self.clip_gradient is not None:
             g = g.clip(-self.clip_gradient, self.clip_gradient)
-        from ..ndarray import random as ndrandom
-        noise = ndrandom.normal(0, math.sqrt(lr), shape=weight.shape,
-                                dtype="float32", ctx=weight.context)
-        weight[:] = weight - lr / 2 * (g + wd * weight) + noise
+        from ..ndarray import array, random as ndrandom
+        # under a schedule lr changes every update: it enters the kernels
+        # as an operand, not as a constant to compile against anew
+        half_lr = array([lr / 2], ctx=weight.context)
+        noise_std = array([math.sqrt(lr)], ctx=weight.context)
+        noise = ndrandom.normal(0, 1, shape=weight.shape, dtype="float32",
+                                ctx=weight.context)
+        weight[:] = weight - half_lr * (g + wd * weight) + noise_std * noise
 
 
 @register

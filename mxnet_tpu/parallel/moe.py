@@ -109,7 +109,7 @@ def make_expert_parallel_moe(mesh, expert_fn, axis_name="ep", k=2,
     """
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = int(mesh.shape[axis_name])
 
@@ -130,7 +130,7 @@ def make_expert_parallel_moe(mesh, expert_fn, axis_name="ep", k=2,
                               k=k, capacity_factor=capacity_factor),
             mesh=mesh,
             in_specs=(p_specs, P(), P(axis_name)),
-            out_specs=P(axis_name), check_rep=False)
+            out_specs=P(axis_name), check_vma=False)
         return fn(expert_params, gate_w, x)
 
     return jax.jit(run)

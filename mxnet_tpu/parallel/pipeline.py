@@ -89,7 +89,7 @@ def make_pipeline_step(stage_fn, mesh, n_microbatches, axis_name="pp",
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def p_specs(params):
         return jax.tree_util.tree_map(
@@ -117,7 +117,7 @@ def make_pipeline_step(stage_fn, mesh, n_microbatches, axis_name="pp",
             functools.partial(pipeline_apply, stage_fn, axis_name=axis_name),
             mesh=mesh,
             in_specs=(p_specs(params), P()),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
         return fn(params, x_micro)
 
     if loss_fn is None:

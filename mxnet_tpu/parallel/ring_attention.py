@@ -94,7 +94,7 @@ def sequence_parallel_attention(mesh, q, k, v, causal=False):
     and run ring_attention under shard_map."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = int(mesh.shape["sp"])
     if q.shape[2] % n:
@@ -104,7 +104,7 @@ def sequence_parallel_attention(mesh, q, k, v, causal=False):
     spec = P(None, None, "sp", None)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+                       out_specs=spec, check_vma=False)
     def run(q_, k_, v_):
         return ring_attention(q_, k_, v_, axis_name="sp", causal=causal)
 

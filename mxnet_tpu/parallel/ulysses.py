@@ -64,7 +64,7 @@ def ulysses_parallel_attention(mesh, q, k, v, causal=False, axis_name="sp"):
     """Convenience wrapper: (B, H, T, D) tensors sharded over ``axis_name``
     on the T axis, exact attention via the two-all-to-all scheme."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = mesh.shape[axis_name]
     if q.shape[1] % n:
@@ -77,7 +77,7 @@ def ulysses_parallel_attention(mesh, q, k, v, causal=False, axis_name="sp"):
     spec = P(None, None, axis_name, None)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+                       out_specs=spec, check_vma=False)
     def run(q_, k_, v_):
         return ulysses_attention_local(q_, k_, v_, axis_name=axis_name,
                                        causal=causal)

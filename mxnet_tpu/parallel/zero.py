@@ -191,7 +191,7 @@ def make_sharded_update_step(loss_fn, optimizer_update, mesh,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from .collectives import allgather, pmean, reduce_scatter
 
     _check_wire_format(wire_format)
@@ -255,7 +255,7 @@ def make_sharded_update_step(loss_fn, optimizer_update, mesh,
             body, mesh=mesh,
             in_specs=(P(), opt_specs, res_specs, batch_specs),
             out_specs=(P(), opt_specs, res_specs, P()),
-            check_rep=False)
+            check_vma=False)
         new_params, new_opt, new_res, loss = sharded(
             params, state["opt"], res_leaves, batch)
         new_state = {"opt": new_opt,

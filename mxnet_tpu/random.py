@@ -79,8 +79,7 @@ def derived_numpy_rng():
     import jax
     import numpy as _np
     sub = next_key()
-    data = jax.random.key_data(sub) if hasattr(jax.random, "key_data") \
-        else sub
+    data = jax.random.key_data(sub)
     # seed with EVERY key word (RandomState accepts array seeds): folding
     # to one 31-bit word would give ~2^-32 per-pair collision odds between
     # independently-initialized parameters — silent perfectly-correlated

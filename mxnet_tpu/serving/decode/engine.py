@@ -88,6 +88,7 @@ from ... import faults
 from ... import util
 from ...base import MXNetError
 from ...cached_op import CachedOp
+from ...context import current_context
 from ..buckets import BucketLadder
 from ..health import CircuitBreaker, PROBE, REJECT
 from ..server import (OK, TIMEOUT, OVERLOADED, INVALID_INPUT, ERROR,
@@ -378,9 +379,18 @@ class DecodeEngine:
         self._cache = PagedKVCache(model.num_layers, num_blocks, block_size,
                                    model.num_heads, model.head_dim,
                                    account_region="kv:%s" % name)
-        self._params = model.param_dict()
-        # mesh footprint: a sharded model (sharding.py) spans tp devices;
-        # the fleet's placement and scaling advice count them through here
+        # the engine lives where it was built: the constructing thread's
+        # current context (``with ctx:`` around construction places it —
+        # FleetRouter does exactly that per replica).  Weights, pools and
+        # every per-step input the scheduler thread stages go to that one
+        # device; a mesh-sharded model (sharding.py) spans its mesh instead.
+        self.ctx = current_context()
+        mesh = getattr(model, "mesh", None)
+        self.devices = tuple(mesh.devices.flat) if mesh is not None \
+            else (self.ctx.jax_device(),)
+        self._params = self._place_params(model)
+        # mesh footprint: a sharded model spans tp devices; the fleet's
+        # placement and scaling advice count them through here
         self.tp_degree = int(getattr(model, "tp_degree", 1))
         self.stats = DecodeStats(name, kv_capacity=self._cache.capacity(),
                                  tp_degree=self.tp_degree)
@@ -412,7 +422,7 @@ class DecodeEngine:
         self._draft_params = None
         self._dpools = None      # [draft k_pool, draft v_pool], worker-only
         if self.spec_k > 0:
-            self._draft_params = draft_model.param_dict()
+            self._draft_params = self._place_params(draft_model)
             self._verify_cop = CachedOp(self._verify_forward, self._params,
                                         flags=mflags)  # mxmem: nodonate(verify reads the same pools the decode path owns; rejected drafts roll back to them)
             self._verify_exec = retry(self._verify_once)
@@ -436,6 +446,7 @@ class DecodeEngine:
         self._draining = False     # admission closed, worker parking
         self._quiesced = threading.Event()  # worker parked, pools published
         self._pools = None         # (k_pool, v_pool) while quiesced
+        self._pool_bytes = None    # written once by the worker (placement)
         self._seq_counter = itertools.count()
         self._thread = threading.Thread(
             target=self._run, name="mx-decode-%s" % name, daemon=True)
@@ -471,22 +482,18 @@ class DecodeEngine:
 
     # -- execution (retry envelope + fault point, like ServableModel) ---
     def _prefill_once(self, tokens, length, table, k_pool, v_pool):
-        from ... import ndarray as nd
         faults.fault_point("serving.predict", model=self.name)
         with autograd.pause():
             return self._prefill_cop(
-                self._params, nd.array(tokens, dtype="int32"),
-                nd.array(length, dtype="int32"),
-                nd.array(table, dtype="int32"), k_pool, v_pool)
+                self._params, self._i32(tokens), self._i32(length),
+                self._i32(table), k_pool, v_pool)
 
     def _decode_once(self, tokens, positions, tables, k_pool, v_pool):
-        from ... import ndarray as nd
         faults.fault_point("serving.predict", model=self.name)
         with autograd.pause():
             return self._decode_cop(
-                self._params, nd.array(tokens, dtype="int32"),
-                nd.array(positions, dtype="int32"),
-                nd.array(tables, dtype="int32"), k_pool, v_pool)
+                self._params, self._i32(tokens), self._i32(positions),
+                self._i32(tables), k_pool, v_pool)
 
     # chunked prefill / speculative forwards: every one a FIXED shape —
     # [1, C] chunk, [S, K+1] verify, [S] draft — so turning the features
@@ -501,14 +508,11 @@ class DecodeEngine:
         return [NDArray(logits), NDArray(kp), NDArray(vp)]
 
     def _chunk_once(self, tokens, start, length, table, k_pool, v_pool):
-        from ... import ndarray as nd
         faults.fault_point("serving.predict", model=self.name)
         with autograd.pause():
             return self._chunk_cop(
-                self._params, nd.array(tokens, dtype="int32"),
-                nd.array(start, dtype="int32"),
-                nd.array(length, dtype="int32"),
-                nd.array(table, dtype="int32"), k_pool, v_pool)
+                self._params, self._i32(tokens), self._i32(start),
+                self._i32(length), self._i32(table), k_pool, v_pool)
 
     def _verify_forward(self, params, tokens, positions, valids, tables,
                         k_pool, v_pool):
@@ -521,14 +525,11 @@ class DecodeEngine:
 
     def _verify_once(self, tokens, positions, valids, tables, k_pool,
                      v_pool):
-        from ... import ndarray as nd
         faults.fault_point("serving.predict", model=self.name)
         with autograd.pause():
             return self._verify_cop(
-                self._params, nd.array(tokens, dtype="int32"),
-                nd.array(positions, dtype="int32"),
-                nd.array(valids, dtype="int32"),
-                nd.array(tables, dtype="int32"), k_pool, v_pool)
+                self._params, self._i32(tokens), self._i32(positions),
+                self._i32(valids), self._i32(tables), k_pool, v_pool)
 
     def _draft_forward(self, params, tokens, positions, tables, k_pool,
                        v_pool):
@@ -540,13 +541,11 @@ class DecodeEngine:
         return [NDArray(props), NDArray(kp), NDArray(vp)]
 
     def _draft_once(self, tokens, positions, tables, k_pool, v_pool):
-        from ... import ndarray as nd
         faults.fault_point("serving.predict", model=self.name)
         with autograd.pause():
             return self._draft_cop(
-                self._draft_params, nd.array(tokens, dtype="int32"),
-                nd.array(positions, dtype="int32"),
-                nd.array(tables, dtype="int32"), k_pool, v_pool)
+                self._draft_params, self._i32(tokens), self._i32(positions),
+                self._i32(tables), k_pool, v_pool)
 
     def _draft_chunk_forward(self, params, tokens, start, length, table,
                              k_pool, v_pool):
@@ -559,31 +558,41 @@ class DecodeEngine:
 
     def _draft_chunk_once(self, tokens, start, length, table, k_pool,
                           v_pool):
-        from ... import ndarray as nd
         faults.fault_point("serving.predict", model=self.name)
         with autograd.pause():
             return self._draft_chunk_cop(
-                self._draft_params, nd.array(tokens, dtype="int32"),
-                nd.array(start, dtype="int32"),
-                nd.array(length, dtype="int32"),
-                nd.array(table, dtype="int32"), k_pool, v_pool)
+                self._draft_params, self._i32(tokens), self._i32(start),
+                self._i32(length), self._i32(table), k_pool, v_pool)
+
+    def _i32(self, host_array):
+        """Stage one per-step host input on the engine's device."""
+        from ... import ndarray as nd
+        return nd.array(host_array, ctx=self.ctx, dtype="int32")
+
+    def _place_params(self, model):
+        """The model's live parameter handles, on the engine's device
+        (a no-op for params already there; a mesh-sharded model keeps
+        its own placement)."""
+        params = model.param_dict()
+        if getattr(model, "mesh", None) is not None:
+            return params
+        return {n: a.as_in_context(self.ctx) for n, a in params.items()}
 
     @staticmethod
     def _placement_flags(model):
         place = getattr(model, "place_inputs", None)
         return {"place_inputs": place} if place is not None else None
 
-    @staticmethod
-    def _zeros_pools(model, shape):
+    def _zeros_pools(self, model, shape):
         """A pair of fresh zeroed pools for ``shape``; a sharded model
         places them head-sharded over its mesh (sharding.py), the default
-        is plain device zeros."""
+        is zeros on the engine's device."""
         zeros = getattr(model, "zeros_pool", None)
         if zeros is not None:
             return [zeros(shape), zeros(shape)]
         from ... import ndarray as nd
-        return [nd.zeros(shape, dtype="float32"),
-                nd.zeros(shape, dtype="float32")]
+        return [nd.zeros(shape, ctx=self.ctx, dtype="float32"),
+                nd.zeros(shape, ctx=self.ctx, dtype="float32")]
 
     def _record_pools(self, pools, shape):
         """Charge a freshly materialized K/V pool set to the engine's pool
@@ -605,7 +614,8 @@ class DecodeEngine:
         """Fresh target-model K/V pools on the model's placement."""
         shape = self._cache.pool_shape()
         if getattr(self.model, "zeros_pool", None) is None:
-            return self._record_pools(self._cache.init_pools(), shape)
+            return self._record_pools(self._cache.init_pools(self.ctx),
+                                      shape)
         return self._record_pools(self._zeros_pools(self.model, shape),
                                   shape)
 
@@ -869,6 +879,7 @@ class DecodeEngine:
 
     def _run_loop(self):  # mxflow: hot (decode prefill/step loop)
         k_pool, v_pool = self._init_pools()
+        self._pool_bytes = util.bytes_by_device([k_pool, v_pool])
         if self.spec_k > 0 and self._dpools is None:
             self._dpools = self._draft_pools()
         while True:
@@ -1731,12 +1742,21 @@ class DecodeEngine:
             "max_slots": self.max_slots,
             "tokens_per_s": snap["tokens_per_s"],
             "tp_degree": self.tp_degree,
+            "devices": [d.id for d in self.devices],
             "draining": draining,
             "generation": self.generation,
             "prefix_hits": kv["prefix_hits"],
             "prefix_blocks_shared": kv["prefix_blocks_shared"],
             "cow_forks": kv["cow_forks"],
         }
+
+    def placement(self):
+        """Bytes per device id of the engine's weights and of its live
+        K/V pools, read off the arrays' own shards (``pools`` is None until
+        the scheduler has made them).  ``devices`` names where the engine
+        means to live; this says where its memory actually is."""
+        return {"params": util.bytes_by_device(self._params.values()),
+                "pools": self._pool_bytes}
 
     # -- reference path ---------------------------------------------------
     def generate_reference(self, prompt, max_new_tokens=None,

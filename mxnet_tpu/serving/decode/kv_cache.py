@@ -175,12 +175,12 @@ class PagedKVCache:
         return (self.num_layers, self.num_blocks, self.block_size,
                 self.num_heads, self.head_dim)
 
-    def init_pools(self):
-        """Fresh zeroed (k_pool, v_pool) NDArray pair."""
+    def init_pools(self, ctx=None):
+        """Fresh zeroed (k_pool, v_pool) NDArray pair on ``ctx``."""
         from ... import ndarray as nd
         shape = self.pool_shape()
-        return nd.zeros(shape, dtype=self.dtype), \
-            nd.zeros(shape, dtype=self.dtype)
+        return nd.zeros(shape, ctx=ctx, dtype=self.dtype), \
+            nd.zeros(shape, ctx=ctx, dtype=self.dtype)
 
     # -- host accounting ------------------------------------------------
     def blocks_for_tokens(self, n_tokens):

@@ -92,15 +92,8 @@ class TinyCausalLM:
         self.max_len = int(max_len)
         self.eos_id = eos_id
         from ... import ndarray as nd
-        expected = {"embed": (self.vocab_size, self.hidden),
-                    "pos": (self.max_len, self.hidden)}
-        for l in range(self.num_layers):
-            expected["l%d_wq" % l] = (self.hidden, self.hidden)
-            expected["l%d_wk" % l] = (self.hidden, self.hidden)
-            expected["l%d_wv" % l] = (self.hidden, self.hidden)
-            expected["l%d_wo" % l] = (self.hidden, self.hidden)
-            expected["l%d_w1" % l] = (self.hidden, 2 * self.hidden)
-            expected["l%d_w2" % l] = (2 * self.hidden, self.hidden)
+        expected = self.param_shapes(self.vocab_size, self.hidden,
+                                     self.num_layers, self.max_len)
         if params is not None:
             # checkpoint-loaded weights (serving/deploy.py builds each new
             # generation this way) — validate against the geometry before
@@ -127,6 +120,17 @@ class TinyCausalLM:
             return nd.array(rng.randn(*shape).astype(np.float32) * scale)
 
         self._params = {k: w(*shape) for k, shape in expected.items()}
+
+    @staticmethod
+    def param_shapes(vocab_size, hidden, num_layers, max_len):
+        """``{name: shape}`` of every parameter of that geometry."""
+        shapes = {"embed": (vocab_size, hidden), "pos": (max_len, hidden)}
+        for l in range(num_layers):
+            for role in ("wq", "wk", "wv", "wo"):
+                shapes["l%d_%s" % (l, role)] = (hidden, hidden)
+            shapes["l%d_w1" % l] = (hidden, 2 * hidden)
+            shapes["l%d_w2" % l] = (2 * hidden, hidden)
+        return shapes
 
     def param_dict(self):
         return dict(self._params)

@@ -110,17 +110,24 @@ def decode_mesh(tp, sp=1, devices=None):
     ``make_mesh`` folds leftover devices into the leading axis — right
     for training (use everything), wrong for serving where a tp=2 engine
     must consume exactly 2 devices so the fleet can place others on the
-    rest.  Raises ValueError naming both extents when the machine cannot
-    honor the request."""
+    rest.  Without ``devices`` the mesh starts at the current context's
+    device and takes the following local devices (wrapping), so two
+    meshes built under different contexts hold different chips — which
+    is how ``FleetRouter`` places sharded replicas.  Raises ValueError
+    naming both extents when the machine cannot honor the request."""
     import jax
     from jax.sharding import Mesh
+    from ...context import current_context
     tp, sp = int(tp), int(sp)
     if tp < 1 or sp < 1:
         raise ValueError("decode_mesh: tp=%d, sp=%d must both be >= 1"
                          % (tp, sp))
-    if devices is None:
-        devices = jax.devices()
     need = tp * sp
+    if devices is None:
+        first_dev = current_context().jax_device()
+        local = jax.local_devices(backend=first_dev.platform)
+        first = local.index(first_dev)
+        devices = (local[first:] + local[:first])[:need]
     if len(devices) < need:
         raise ValueError(
             "decode_mesh: tp=%d x sp=%d needs %d device(s); only %d "
@@ -749,7 +756,7 @@ class ShardedDecodeModel:
         their shards, each Megatron half-block ends in its single psum,
         and the kernels write the LOCAL head slice of the pool carries
         directly — no gather, no slice-back."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         geom = self._geom
         pool_spec = P(None, None, None, "tp")
@@ -774,7 +781,7 @@ class ShardedDecodeModel:
             in_specs=(pspecs, tuple(P() for _ in range(n_small)),
                       pool_spec, pool_spec),
             out_specs=(P(), pool_spec, pool_spec),
-            check_rep=False)
+            check_vma=False)
 
     @staticmethod
     def _make_call(sm, n_small):

@@ -83,6 +83,7 @@ import time
 
 from .. import faults
 from ..base import MXNetError
+from ..context import Context, current_context
 from ..kvstore_server import MembershipTable
 from .health import (CircuitBreaker, HEALTHY, DEGRADED, UNAVAILABLE_HEALTH,
                      REJECT, worst_health)
@@ -309,18 +310,20 @@ class _EngineSpec:
     ``factory(name)`` must return a warmed DecodeEngine; ``max_new`` is
     learned from the first committed engine (the QoS need estimate for
     submissions that leave max_new_tokens to the engine default).  ``tp``
-    is the declared tensor-parallel degree: every placement of this
-    engine spans that many mesh devices (1 = unsharded), checked against
-    the built engine's ``tp_degree``."""
+    is the declared tensor-parallel degree (1 = unsharded), checked
+    against the built engine's ``tp_degree``; ``span`` is the declared
+    extent of its mesh, tp*sp: the devices every placement takes."""
 
-    __slots__ = ("name", "factory", "replicas", "max_new", "tp", "wgen")
+    __slots__ = ("name", "factory", "replicas", "max_new", "tp", "span",
+                 "wgen")
 
-    def __init__(self, name, factory, replicas, tp=None):
+    def __init__(self, name, factory, replicas, tp=None, sp=1):
         self.name = name
         self.factory = factory
         self.replicas = replicas
         self.max_new = 0
         self.tp = tp
+        self.span = (tp or 1) * sp
         self.wgen = None         # weight generation the spec serves
 
 
@@ -578,7 +581,7 @@ class FleetRouter:
             return sorted(self._specs)
 
     # -- stateful decode tier ---------------------------------------------
-    def load_decode(self, name, factory, replicas=1, tp=None):
+    def load_decode(self, name, factory, replicas=1, tp=None, sp=1):
         """Place decode engines for ``name`` on the ``replicas``
         least-loaded live replicas.  ``factory(name)`` must build one
         warmed :class:`~mxnet_tpu.serving.decode.DecodeEngine` (identical
@@ -592,7 +595,11 @@ class FleetRouter:
         ``ShardedDecodeModel(tp=k)``) and consumes k devices per
         placement in ``scaling_advice()``'s footprint accounting.  The
         built engine's ``tp_degree`` must match the declaration —
-        mismatch fails the load with an MXNetError naming both.  KV
+        mismatch fails the load with an MXNetError naming both.  ``sp``
+        declares the second extent of that mesh where the factory builds
+        one (``ShardedDecodeModel(tp=k, sp=j)``): each placement is given
+        a window of tp*sp devices that no other engine holds, while such
+        a window remains.  KV
         headroom needs no tp awareness: the engine reports its logical
         pool once (the pool is head-SHARDED over the mesh, not
         replicated), so summing placements never double-counts shards."""
@@ -600,6 +607,8 @@ class FleetRouter:
             raise ValueError("replicas must be >= 1")
         if tp is not None and int(tp) < 1:
             raise ValueError("tp must be >= 1 (or None for unsharded)")
+        if int(sp) < 1:
+            raise ValueError("sp must be >= 1")
         with self._lock:
             if self._closed:
                 raise MXNetError("fleet is stopped; create a new FleetRouter")
@@ -609,7 +618,7 @@ class FleetRouter:
                 raise MXNetError("no live replicas; add_replica() first")
             self._dspecs[name] = _EngineSpec(
                 name, factory, int(replicas),
-                tp=None if tp is None else int(tp))
+                tp=None if tp is None else int(tp), sp=int(sp))
             self._dplacement[name] = []
         try:
             self._rebalance()
@@ -622,6 +631,29 @@ class FleetRouter:
             self.unload_decode(name)
             raise MXNetError("could not place decode engine %r on any live "
                              "replica" % name)
+
+    def _engine_context(self, need):
+        """The context to build the next decode engine under: the first
+        device of the ``need``-wide window of local devices that carries
+        the fewest engines (ties to the lowest index).  Replicas therefore
+        take distinct chips while free ones remain and share only once
+        every chip is taken.  An unsharded engine lives on that device; a
+        ``decode_mesh`` built under it takes the window."""
+        import jax
+        here = current_context()
+        devs = jax.local_devices(backend=here.jax_device().platform)
+        with self._lock:
+            engines = list(self._dengines.values())
+            engines += [e["eng"] for e in self._retiring if "eng" in e]
+        load = {d.id: 0 for d in devs}
+        for eng in engines:
+            for d in eng.devices:
+                if d.id in load:
+                    load[d.id] += 1
+        n = len(devs)
+        start = min(range(n), key=lambda i: sum(
+            load[devs[(i + k) % n].id] for k in range(need)))
+        return Context(here.device_type, start)
 
     def unload_decode(self, name):
         with self._lock:
@@ -1152,7 +1184,7 @@ class FleetRouter:
                     "engines": {},
                     "reasons": ["no decode engines placed"]}
         utils, fills = [], []
-        devices_in_use = 0
+        devices_in_use = set()    # distinct device ids that hold an engine
         kv_bytes_free = kv_bytes_capacity = 0
         per_name = {}
         for (name, _rid), eng in engines:
@@ -1160,20 +1192,20 @@ class FleetRouter:
             cap = max(1, sig["kv_capacity"])
             util = 1.0 - sig["kv_blocks_free"] / cap
             fill = sig["queue_depth"] / max(1, sig["max_queue"])
-            devs = max(1, int(sig.get("tp_degree", 1)))
+            devs = set(sig["devices"])
             b_free = int(sig.get("kv_bytes_free", 0))
             b_cap = int(sig.get("kv_bytes_capacity", 0))
             utils.append(util)
             fills.append(fill)
-            devices_in_use += devs
+            devices_in_use |= devs
             kv_bytes_free += b_free
             kv_bytes_capacity += b_cap
             row = per_name.setdefault(
-                name, {"replicas": 0, "devices_in_use": 0,
+                name, {"replicas": 0, "devices_in_use": set(),
                        "kv_bytes_free": 0, "kv_bytes_capacity": 0,
                        "_utils": [], "_fills": []})
             row["replicas"] += 1
-            row["devices_in_use"] += devs
+            row["devices_in_use"] |= devs
             row["kv_bytes_free"] += b_free
             row["kv_bytes_capacity"] += b_cap
             row["_utils"].append(util)
@@ -1190,7 +1222,7 @@ class FleetRouter:
                 n_reasons.append("queue fill %.2f >= %.2f" % (n_fill, high))
             breakdown[name] = {
                 "replicas": row["replicas"],
-                "devices_in_use": row["devices_in_use"],
+                "devices_in_use": len(row["devices_in_use"]),
                 "kv_utilization": n_util,
                 "queue_fill": n_fill,
                 "kv_bytes_free": row["kv_bytes_free"],
@@ -1216,6 +1248,7 @@ class FleetRouter:
         else:
             action = "hold"
             reasons = ["within thresholds"]
+        devices_in_use = len(devices_in_use)
         if action == "scale_out" and devices_in_use >= devices_total:
             reasons.append("device budget exhausted: %d/%d devices in use"
                            % (devices_in_use, devices_total))
@@ -1538,7 +1571,8 @@ class FleetRouter:
                 # commit the placement
                 name, spec, rep, wgen0 = dtask
                 try:
-                    eng = spec.factory(name)
+                    with self._engine_context(spec.span):
+                        eng = spec.factory(name)
                 except MXNetError:
                     failed.add((name, rep.rid))
                     continue
@@ -1688,8 +1722,12 @@ class FleetRouter:
                                  % (name, rid))
             if (name, rid) in st["engines"]:
                 raise MXNetError("(%r, %s) is already staged" % (name, rid))
+            old_ctx = self._dengines[(name, rid)].ctx
         srv_name = "%s@g%s" % (name, g)
-        eng = factory(srv_name)
+        # a Context of its own: the engine's is shared, and `with` keeps
+        # the context to restore on the object it enters
+        with Context(old_ctx.device_type, old_ctx.device_id):
+            eng = factory(srv_name)
         if getattr(eng, "generation", None) is None:
             eng.generation = g
         built_tp = int(getattr(eng, "tp_degree", 1))

@@ -1,12 +1,11 @@
 """Test helpers (reference: python/mxnet/test_utils.py — assert_almost_equal,
 check_numeric_gradient finite differences, check_consistency cpu-vs-device,
-rand_ndarray, default_context switched by env)."""
+rand_ndarray, default_context)."""
 from __future__ import annotations
 
-import os
 import numpy as _np
 
-from .context import Context, cpu, tpu, current_context
+from .context import Context, cpu, current_context
 from .ndarray import NDArray, array
 from . import ndarray as nd
 from . import autograd
@@ -18,12 +17,8 @@ __all__ = ["default_context", "set_default_context", "assert_almost_equal",
 
 
 def default_context():
-    """Context under test, switched by MXNET_TEST_DEVICE (cpu-sim vs real TPU
-    context injection, the reference's gpu/cpu test trick)."""
-    dev = os.environ.get("MXNET_TEST_DEVICE", "cpu")
-    if dev == "tpu" or dev == "gpu":
-        return tpu(0)
-    return cpu(0)
+    """Context under test: the process default (context.py)."""
+    return current_context()
 
 
 def set_default_context(ctx):

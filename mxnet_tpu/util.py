@@ -29,12 +29,44 @@ def get_gpu_count():
 
 
 def get_gpu_memory(dev_id=0):
+    """(bytes in use, bytes limit) of the ``dev_id``-th accelerator."""
+    from .base import MXNetError
+    from .context import tpu
+    dev = tpu(dev_id).jax_device()
+    stats = dev.memory_stats()
+    if stats is None:
+        raise MXNetError("%s reports no memory statistics" % (dev,))
+    return stats["bytes_in_use"], stats["bytes_limit"]
+
+
+def bytes_by_device(arrays):
+    """{device id: bytes held there} over the shards of ``arrays``
+    (NDArrays or jax arrays): where memory is, not where it was meant."""
+    out = {}
+    for arr in arrays:
+        for shard in getattr(arr, "_data", arr).addressable_shards:
+            out[shard.device.id] = out.get(shard.device.id, 0) \
+                + shard.data.nbytes
+    return out
+
+
+def compile_cache_dir():
+    """Directory of JAX's persistent compilation cache for this process.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set in code, so the cache can be placed from outside.
+    Otherwise the cache goes to ``.jax_cache`` beside the package: a fixed
+    path, because the path is part of what the cache is keyed on and a
+    directory that moves never hits."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
     import jax
-    try:
-        stats = jax.devices()[dev_id].memory_stats()
-        return stats.get("bytes_in_use", 0), stats.get("bytes_limit", 0)
-    except Exception:
-        return 0, 0
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ test pins.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mxnet_tpu import memory_accounting
@@ -62,7 +62,7 @@ def sharded_gather(x):
         return allgather(v, "tp")  # MEM005: full-shape temp, no budget
 
     fn = shard_map(body, mesh=mesh, in_specs=(P("tp"),), out_specs=P("tp"),
-                   check_rep=False)
+                   check_vma=False)
     return fn(x)
 
 

@@ -9,7 +9,7 @@ contract.  Keep line numbers stable or update the test pins.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mxnet_tpu.parallel.collectives import allgather, allreduce, ppermute
@@ -40,7 +40,7 @@ def block(x, w):
 def run_block(x, w):
     mesh = bad_mesh()
     fn = shard_map(block, mesh=mesh, in_specs=partition_specs(),  # SPD004
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(x, w)
 
 
@@ -55,7 +55,7 @@ def scan_reshard(x):
         return allreduce(out, "tp")  # SPD005: psum on a bitwise path
 
     fn = shard_map(shifted, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(x)
 
 

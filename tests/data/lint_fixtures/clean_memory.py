@@ -8,7 +8,7 @@ pass must report zero findings on this file (tests/test_mxmem.py).
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mxnet_tpu.parallel.collectives import allgather
@@ -40,7 +40,7 @@ def budgeted_gather(x):
         return allgather(v, "tp")  # covered by the budget above
 
     fn = shard_map(body, mesh=mesh, in_specs=(P("tp"),), out_specs=P("tp"),
-                   check_rep=False)
+                   check_vma=False)
     return fn(x)
 
 
@@ -51,7 +51,7 @@ def sanctioned_gather(x):
         return allgather(v, "tp")  # mxmem: fullshape-ok(the gathered operand is one scalar row per shard)
 
     return shard_map(body, mesh=mesh, in_specs=(P("tp"),),
-                     out_specs=P("tp"), check_rep=False)(x)
+                     out_specs=P("tp"), check_vma=False)(x)
 
 
 # mxflow: hot

@@ -8,7 +8,7 @@ eagerly, and every axis named anywhere is declared by the mesh.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mxnet_tpu.parallel.collectives import allgather, allreduce
@@ -38,7 +38,7 @@ def run_block(x, w):
             "block: weight columns of %d are not divisible by the mesh "
             "'tp' axis extent %d" % (w.shape[1], n))
     fn = shard_map(block, mesh=mesh, in_specs=partition_specs(),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(x, w)
 
 

@@ -20,9 +20,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", ".."))
 
 import jax
-plat = os.environ.get("JAX_PLATFORMS")
-if plat:
-    jax.config.update("jax_platforms", plat)
 
 import numpy as np
 

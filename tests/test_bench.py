@@ -1,12 +1,10 @@
-"""bench.py must always produce a valid JSON line — a silent bench break
-means another null driver capture (BENCH_r01..r03), so every mode gets a
-tiny-config CPU smoke through the REAL watchdog entrypoint."""
+"""bench.py must always produce a valid JSON line, so every mode gets a
+tiny-config CPU rehearsal through the real entrypoint; off the TPU every
+line says so (``platform``) and carries no utilization."""
 import json
 import os
 import subprocess
 import sys
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -14,9 +12,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run_bench(extra_env, timeout=420):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    env.update({"JAX_PLATFORMS": "cpu", "BENCH_ITERS": "2",
-                "BENCH_BUDGET": "360", "BENCH_TIMEOUT": "330",
-                "BENCH_PROBE_TIMEOUT": "60"})
+    env.update({"JAX_PLATFORMS": "cpu", "BENCH_ITERS": "2"})
     env.update(extra_env)
     res = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
                          env=env, cwd=REPO, timeout=timeout,
@@ -40,7 +36,8 @@ def test_bench_train_mode_smoke():
     assert rec["unit"] == "images/sec"
     assert rec["metric"] == "resnet50_train_imgs_per_sec_bs2_img32"
     assert rec["layout"] == "NCHW" and rec["mode"] == "train"
-    assert "step_flops" in rec        # cost model surfaced (may be None)
+    assert rec["step_flops"] > 0      # XLA's cost model
+    assert rec["platform"] == "cpu" and rec["mfu"] is None   # a rehearsal
 
 
 def test_bench_inference_mode_smoke():
@@ -78,56 +75,3 @@ def test_bench_int8_mode_smoke():
     assert rec["metric"] == "resnet50_int8_infer_imgs_per_sec_bs2"
     assert rec["calib"] == "minmax"
     assert rec["timed_window"]["iters"] >= 1
-
-
-# ---------------------------------------------------------------------------
-# probe-failure classification (round-6: BENCH_r05's 13/13 failed probes
-# left no evidence of WHY — every failure now gets a class + detail)
-# ---------------------------------------------------------------------------
-
-def _load_module(name, path):
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-_CLASSIFY_CASES = [
-    # (timed_out, rc, stdout, stderr) -> expected class
-    ((True, None, "", ""), "timeout"),
-    ((False, 1, "", "ConnectionRefusedError: [Errno 111] Connection "
-                    "refused"), "connect"),
-    ((False, 1, "", "socket error: no route to host"), "connect"),
-    ((False, 1, "", "gaierror: getaddrinfo failed"), "connect"),
-    ((False, 1, "", "urllib.error.HTTPError: HTTP Error 502: Bad "
-                    "Gateway"), "http"),
-    ((False, 1, "", "relay returned status code 503 service "
-                    "unavailable"), "http"),
-    ((False, 1, "", "Traceback (most recent call last):\n"
-                    "RuntimeError: backend init exploded"), "backend"),
-    ((False, 0, "", ""), "no-output"),
-    ((False, 0, "garbage but no PROBE_OK", ""), "no-output"),
-]
-
-
-def test_probe_failure_classifier(monkeypatch):
-    # a stray BENCH_MODE in the test env would make bench.py sys.exit at
-    # import; pin the defaults
-    monkeypatch.delenv("BENCH_MODE", raising=False)
-    monkeypatch.delenv("BENCH_LAYOUT", raising=False)
-    bench = _load_module("_bench_ut", os.path.join(REPO, "bench.py"))
-    watcher = _load_module("_relay_watcher_ut",
-                           os.path.join(REPO, "tools", "relay_watcher.py"))
-    for args, want in _CLASSIFY_CASES:
-        b_cls, b_detail = bench._classify_probe_failure(*args)
-        w_cls, w_detail = watcher.classify_probe_failure(*args)
-        assert b_cls == want, (args, b_cls)
-        # the watcher's copy must never drift from bench.py's
-        assert (w_cls, w_detail) == (b_cls, b_detail), (args, w_cls)
-        assert b_cls in bench._PROBE_FAILURE_CLASSES
-        assert isinstance(b_detail, str)
-    # detail carries the most specific stderr evidence
-    _, detail = bench._classify_probe_failure(
-        False, 1, "", "noise line\nConnectionRefusedError: refused")
-    assert detail == "ConnectionRefusedError: refused"

@@ -1,0 +1,146 @@
+"""The main path's kernels, compiled for the chip that is not attached.
+
+The TPU's compiler is installed beside the CPU backend and compiles for a
+chip that is described (``v5e:2x2``), so what it refuses (a misaligned
+slice, too much VMEM, a program that does not fit the device) is found here
+at no chip time.  Nothing runs: these tests say nothing about results or
+times.
+
+Only one process may load the TPU's library, so the topology is described
+inside a fixture of this one file and nowhere at import time: every xdist
+worker then collects the same tests and only the worker that runs this file
+loads the library.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from mxnet_tpu.ndarray import NDArray
+from mxnet_tpu.ops.pallas_ops import _flash_attention_pallas
+from mxnet_tpu.serving.decode import (DecodeEngine, ShardedDecodeModel,
+                                      TinyCausalLM)
+
+HBM_BYTES = 16 * 2 ** 30      # one v5e chip
+
+# chip_smoke.py's serve phase: the width a chip is built for
+GEOM = dict(vocab_size=50304, hidden=2048, num_layers=16, max_len=4096)
+HEADS, SLOTS, BLOCK, CHUNK = 16, 8, 16, 128
+WIDTH = DecodeEngine.worst_case_width(512, 48, BLOCK)
+POOL = (GEOM["num_layers"], SLOTS * WIDTH + 1, BLOCK, HEADS,
+        GEOM["hidden"] // HEADS)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # an executable compiled for a described chip can be written to the
+    # persistent cache but not read back without a chip
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _model():
+    """TinyCausalLM of GEOM whose parameters are shapes, not arrays."""
+    shapes = TinyCausalLM.param_shapes(**GEOM)
+    handles = {k: NDArray(jax.ShapeDtypeStruct(v, jnp.float32))
+               for k, v in shapes.items()}
+    return TinyCausalLM(num_heads=HEADS, params=handles, **GEOM), shapes
+
+
+def _fits(compiled):
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert used < HBM_BYTES, "%.2f GB on a 16 GB chip" % (used / 2 ** 30)
+    return used
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 12, 1024, 64), jnp.bfloat16),
+    ((2, 16, 4096, 128), jnp.bfloat16),
+    ((2, 12, 1000, 64), jnp.bfloat16),      # ragged: padded and masked
+    # K/V stream through the grid one block at a time, so VMEM use does
+    # not grow with the sequence: with a whole (Tk, D) row of K and of V
+    # resident per program this shape was refused (16.25M of 16.00M VMEM)
+    ((1, 16, 8192, 128), jnp.float32),
+])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    scale = 1.0 / np.sqrt(shape[-1])
+    compiled = jax.jit(lambda q, k, v: _flash_attention_pallas(
+        q, k, v, True, scale)).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "chunk_prefill"])
+def test_decode_kernels_compile_for_v5e(one_chip, kernel):
+    model, shapes = _model()
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {k: s(v) for k, v in shapes.items()}
+    i32 = jnp.int32
+    if kernel == "decode":
+        small = (s((SLOTS,), i32), s((SLOTS,), i32), s((SLOTS, WIDTH), i32))
+        fn = model.decode_fn
+    else:
+        small = (s((1, CHUNK), i32), s((1,), i32), s((1,), i32),
+                 s((1, WIDTH), i32))
+        fn = model.chunk_prefill_fn
+    compiled = jax.jit(fn).lower(params, *small, s(POOL), s(POOL)).compile()
+    # weights + both pools in, logits + both pools out (pools are not
+    # donated: ROADMAP S1), and all of it fits one chip
+    used = _fits(compiled)
+    weights = 4 * sum(int(np.prod(v)) for v in shapes.values())
+    assert used > weights + 4 * 4 * int(np.prod(POOL))
+
+
+def test_sharded_decode_step_compiles_for_four_chips(topo, monkeypatch):
+    """The tp=4 decode step over a mesh of the described devices: each
+    device holds a quarter of the weights (all but nothing of the logits)
+    and a quarter of each pool."""
+    model, shapes = _model()
+    # nothing can be placed on a described device: the wrapper's own
+    # device_put of the weights passes the shapes through
+    monkeypatch.setattr(jax, "device_put", lambda x, sharding: x)
+    sharded = ShardedDecodeModel(model, tp=4, devices=list(topo.devices)[:4])
+    monkeypatch.undo()
+    assert sharded.mesh.devices.size == 4
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(sharded.mesh, spec))
+
+    specs = model.partition_specs()
+    params = {k: s(v, jnp.float32, specs[k]) for k, v in shapes.items()}
+    pool = s(POOL, jnp.float32, P(None, None, None, "tp"))
+    i32 = jnp.int32
+    compiled = jax.jit(sharded.decode_fn).lower(
+        params, s((SLOTS,), i32, P()), s((SLOTS,), i32, P()),
+        s((SLOTS, WIDTH), i32, P()), pool, pool).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text and "all-gather" not in text
+    # memory_analysis() counts one device of the mesh
+    per_device = _fits(compiled)
+    weights = 4 * sum(int(np.prod(v)) for v in shapes.values())
+    pools = 2 * 4 * int(np.prod(POOL))
+    assert per_device < (weights + 2 * pools) / 4 * 1.25

@@ -102,7 +102,7 @@ def test_spd004_propagates_through_spec_indirection():
     # the lint must chase the indirection to see the sharded in_spec
     src = """\
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from mxnet_tpu.parallel.collectives import allreduce
 
     def make_mesh(devs):
@@ -286,14 +286,14 @@ def test_axis_size_is_exempt_from_counting():
     """
     assert _pairs(_analyze(src)) == []
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from mxnet_tpu.parallel.collectives import axis_size
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
     reset_collective_counters()
     try:
         out = shard_map(lambda: axis_size("tp"), mesh=mesh, in_specs=(),
-                        out_specs=P(), check_rep=False)()
+                        out_specs=P(), check_vma=False)()
         assert int(np.asarray(out)) == 2
         assert collective_totals() == {}
     finally:

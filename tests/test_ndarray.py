@@ -186,3 +186,83 @@ def test_nested_view_observes_base_mutation():
     z = y[0]
     x[:] = 0
     np.testing.assert_allclose(z.asnumpy(), 0)
+
+
+# ---------------------------------------------------------------------------
+# device contexts: one default for the process, no stand-in devices
+# ---------------------------------------------------------------------------
+
+def test_default_context_is_jax_default_backends_first_device():
+    import jax
+    ctx = mx.current_context()
+    assert ctx.jax_device() == jax.local_devices()[0]
+    assert nd.zeros((2,))._data.devices() == {jax.local_devices()[0]}
+    # ... and what takes no context takes that default
+    assert mx.mod.Module(mx.sym.var("data") * 2, label_names=None)._context \
+        == [ctx]
+
+
+def test_new_thread_sees_the_same_default_context():
+    import threading
+    seen = {}
+
+    def look(key):
+        seen[key] = (mx.current_context(),
+                     nd.ones((1,))._data.devices())
+
+    t = threading.Thread(target=look, args=("plain",))
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    assert seen["plain"][0] == mx.current_context()
+    # a `with ctx:` block is the enclosing thread's own business
+    with mx.cpu(3):
+        t = threading.Thread(target=look, args=("scoped",))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+        assert mx.current_context() == mx.cpu(3)
+    assert seen["scoped"] == seen["plain"]
+    assert mx.current_context() == seen["plain"][0]
+
+
+@pytest.mark.parametrize("device_id", [0, 99])
+def test_tpu_context_raises_without_that_chip(device_id):
+    """A CPU-only process has no chip 0, and no process has chip 99: naming
+    one is an error, never a CPU device or the last chip."""
+    ctx = mx.tpu(device_id)
+    with pytest.raises(mx.MXNetError, match="0 local accelerator"):
+        ctx.jax_device()
+    with pytest.raises(mx.MXNetError):
+        nd.zeros((1,), ctx=ctx)
+    with pytest.raises(mx.MXNetError):
+        nd.zeros((1,), ctx=mx.gpu(device_id))
+
+
+def test_decode_engines_hold_their_pools_on_the_device_asked_for():
+    import jax
+    from mxnet_tpu.serving.decode import DecodeEngine, TinyCausalLM
+    engines = []
+    try:
+        for i in (2, 5):
+            with mx.cpu(i):
+                model = TinyCausalLM(vocab_size=20, hidden=16, num_layers=1,
+                                     num_heads=2, max_len=24, seed=3)
+                engines.append(DecodeEngine(
+                    model, name="eng%d" % i, max_slots=2, block_size=4,
+                    max_prompt_len=4, max_new_tokens=5, width_blocks=[4]))
+        streams = [e.submit([1, 2, 3], max_new_tokens=4) for e in engines]
+        for s in streams:
+            assert s.wait(60) and s.status == "OK", (s.status, s.error)
+        # same weights, same prompt: the device changes nothing else
+        assert streams[0].tokens() == streams[1].tokens()
+        for e, i in zip(engines, (2, 5)):
+            want = jax.local_devices()[i]
+            assert e.ctx == mx.cpu(i) and e.devices == (want,)
+            assert e.routing_signals()["devices"] == [want.id]
+            placed = e.placement()
+            assert list(placed["params"]) == [want.id]
+            assert list(placed["pools"]) == [want.id]
+    finally:
+        for e in engines:
+            e.stop()

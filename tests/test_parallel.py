@@ -49,11 +49,11 @@ def test_data_parallel_spec_places_batch_axis():
 # ---------------------------------------------------------- collectives
 
 def _shmap(mesh, fn, in_spec, out_spec, *args):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     import functools
     wrapped = functools.partial(
         shard_map, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-        check_rep=False)(fn)
+        check_vma=False)(fn)
     return wrapped(*args)
 
 

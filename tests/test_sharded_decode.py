@@ -431,11 +431,11 @@ def _sp_mesh(n):
 
 
 def _run_replicated(mesh, fn, *args):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     wrapped = shard_map(fn, mesh=mesh,
                         in_specs=tuple(P() for _ in args), out_specs=P(),
-                        check_rep=False)
+                        check_vma=False)
     return wrapped(*args)
 
 
@@ -488,7 +488,7 @@ def test_long_context_attention_routes_and_falls_back():
 
 
 def test_expert_sharded_ffn_matches_single_member():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     rng = np.random.RandomState(1)
     E, T, d = 4, 8, 6
@@ -504,7 +504,7 @@ def test_expert_sharded_ffn_matches_single_member():
         f = shard_map(
             lambda wl, g, xx: expert_sharded_ffn(expert_fn, wl, g, xx),
             mesh=mesh, in_specs=(P("sp"), P(), P()), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         return np.asarray(f(w, gate, x))
 
     np.testing.assert_allclose(run(2), run(1), rtol=2e-5, atol=2e-5)
@@ -515,13 +515,13 @@ def test_expert_sharded_ffn_matches_single_member():
         shard_map(
             lambda wl, g, xx: expert_sharded_ffn(expert_fn, wl, g, xx),
             mesh=mesh, in_specs=(P("sp"), P(), P()), out_specs=P(),
-            check_rep=False)(w, gate, x[:7])
+            check_vma=False)(w, gate, x[:7])
     with pytest.raises(ValueError, match="expert count of 3 is not "
                                          "divisible"):
         shard_map(
             lambda wl, g, xx: expert_sharded_ffn(expert_fn, wl, g, xx),
             mesh=mesh, in_specs=(P("sp"), P(), P()), out_specs=P(),
-            check_rep=False)(w, gate[:, :3], x)
+            check_vma=False)(w, gate[:, :3], x)
 
 
 # ---------------------------------------------------------------------------
@@ -534,13 +534,37 @@ _FLEET_EKW = dict(max_slots=2, block_size=4, num_blocks=9, max_prompt_len=4,
                   max_new_tokens=5, max_queue=6, width_blocks=[4])
 
 
-def _fleet_factory(tp):
+def _fleet_factory(tp, sp=1):
     def make(name):
         m = TinyCausalLM(**_FLEET_CFG)
-        if tp > 1:
-            m = ShardedDecodeModel(m, tp=tp)
+        if tp * sp > 1:
+            m = ShardedDecodeModel(m, tp=tp, sp=sp)
         return DecodeEngine(m, name=name, **_FLEET_EKW)
     return make
+
+
+def test_fleet_places_a_tp_by_sp_mesh_on_a_window_of_free_devices():
+    """The window an engine is given is as wide as its mesh, tp*sp: with
+    device 2 taken and 0-1 free, a 2x2 mesh goes to 3-6, not onto 0-3."""
+    from mxnet_tpu.serving.fleet import FleetRouter
+    r = FleetRouter(replicas=1)
+    try:
+        for name in "abc":      # one chip each: devices 0, 1, 2
+            r.load_decode(name, _fleet_factory(1))
+        r.unload_decode("a")
+        r.unload_decode("b")
+        r.load_decode("lm", _fleet_factory(2, sp=2), tp=2, sp=2)
+        rid = r.stats()["decode_models"]["lm"]["placement"][0]
+        held = {n: [d.id for d in r.engine(n, rid).devices]
+                for n in ("c", "lm")}
+        assert held == {"c": [2], "lm": [3, 4, 5, 6]}
+        assert r.scaling_advice()["devices_in_use"] == 5
+        s = r.submit_stream("lm", [1, 2, 3], max_new_tokens=4)
+        assert s.wait(60) and s.status == "OK", (s.status, s.error)
+        with pytest.raises(ValueError, match="sp must be >= 1"):
+            r.load_decode("lm2", _fleet_factory(1), sp=0)
+    finally:
+        r.stop()
 
 
 def test_fleet_tp_footprint_and_headroom_not_double_counted():
@@ -819,7 +843,7 @@ def test_wire_2bit_psum_bitwise_at_representable_inputs():
     reconstruct each member's contribution with zero residual."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.serving.decode import sharding as shd
 
@@ -834,10 +858,10 @@ def test_wire_2bit_psum_bitwise_at_representable_inputs():
                     jnp.float32)
     quant = shard_map(lambda x: shd._psum_2bit(geom, x), mesh=mesh,
                       in_specs=P("tp"), out_specs=P("tp"),
-                      check_rep=False)
+                      check_vma=False)
     exact = shard_map(lambda x: jax.lax.psum(x, "tp"), mesh=mesh,
                       in_specs=P("tp"), out_specs=P("tp"),
-                      check_rep=False)
+                      check_vma=False)
     assert np.asarray(quant(y)).tobytes() == np.asarray(exact(y)).tobytes()
 
 

@@ -178,7 +178,7 @@ def test_sync_batch_norm_cross_device_stats():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from mxnet_tpu.ops.registry import get_op
     op = get_op("_contrib_SyncBatchNorm")
     devs = np.array(jax.devices("cpu")[:4])

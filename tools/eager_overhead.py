@@ -25,10 +25,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-plat = os.environ.get("JAX_PLATFORMS")
-if plat:
-    import jax
-    jax.config.update("jax_platforms", plat)
 
 import numpy as np
 

@@ -60,9 +60,7 @@ def _run_fit(compiled, data, labels, batch, channels, classes, epochs,
 
     mx.random.seed(42)
     it = io.NDArrayIter(data, labels, batch_size=batch, shuffle=False)
-    mod = mx.mod.Module(_make_symbol(channels, classes), context=mx.cpu()
-                        if os.environ.get("JAX_PLATFORMS") == "cpu"
-                        else None)
+    mod = mx.mod.Module(_make_symbol(channels, classes))
     marks = []
     stats = []
 
@@ -88,9 +86,6 @@ def _run_fit(compiled, data, labels, batch, channels, classes, epochs,
 def run(smoke=False, out_path=None, emit=True):
     import jax
 
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
     devs = jax.devices()
     device_kind = getattr(devs[0], "device_kind", devs[0].platform)
 

@@ -171,9 +171,6 @@ def run(smoke=False, out_path=None, emit=True):
     import mxnet_tpu as mx
     from mxnet_tpu import gluon, nd
 
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
     devs = jax.devices()
     device_kind = getattr(devs[0], "device_kind", devs[0].platform)
 
@@ -183,7 +180,7 @@ def run(smoke=False, out_path=None, emit=True):
     n_batches = int(os.environ.get("BENCH_PIPE_BATCHES",
                                    "8" if smoke else "20"))
     ratio = float(os.environ.get("BENCH_DECODE_RATIO", "0.7"))
-    ctx = mx.cpu(0) if devs[0].platform == "cpu" else mx.tpu(0)
+    ctx = mx.current_context()
 
     cached_op, step = _build_trainer(batch, img, channels)
     cache_before = cached_op.cache_stats()

@@ -11,6 +11,13 @@ worker processes on localhost (the reference's multi-node simulator used by
 tests/nightly/dist_sync_kvstore.py); ``--launcher ssh`` runs one process per
 host from a hostfile.  Each worker gets MX_KV_RANK / MX_KV_NUM_WORKERS /
 MX_KV_ROOT_URI (DMLC_* names also set for reference-script compatibility).
+
+Chips: a process that reaches the TPU claims every chip of its host, and a
+chip belongs to one process at a time.  Local mode assigns no chip to any
+worker, so it refuses more than one local worker unless the workers are held
+to the CPU (``JAX_PLATFORMS=cpu``, the simulator).  On a host with chips, run
+one process and let it drive all of them through a mesh; across hosts, use
+``--launcher ssh`` with one process per host.
 """
 from __future__ import annotations
 
@@ -22,6 +29,10 @@ import sys
 
 
 def launch_local(num_workers, command, env_base):
+    if num_workers > 1 and env_base.get("JAX_PLATFORMS") != "cpu":
+        sys.exit("launch.py: %d local workers would each claim every chip "
+                 "of this host; set JAX_PLATFORMS=cpu to simulate them on "
+                 "the CPU, or run one process over a mesh" % num_workers)
     procs = []
     for rank in range(num_workers):
         env = dict(env_base)

@@ -100,8 +100,6 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
