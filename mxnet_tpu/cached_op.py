@@ -25,7 +25,6 @@ executor + memory planner + op bulking, all in the compiler).  Notes:
 from __future__ import annotations
 
 import threading as _threading
-import time as _time
 
 from . import autograd
 from . import profiler
@@ -35,7 +34,8 @@ __all__ = ["CachedOp"]
 
 
 class CachedOp:
-    def __init__(self, forward_fn, param_dict, aux_names=(), flags=None):
+    def __init__(self, forward_fn, param_dict, aux_names=(), flags=None,
+                 name="traced"):
         """
         forward_fn(params: dict name->NDArray, *inputs: NDArray) -> NDArray or
             list/tuple of NDArray.  Must be jax-traceable (the gluon
@@ -43,8 +43,11 @@ class CachedOp:
         param_dict: dict name -> NDArray handle (live parameter storage).
         aux_names: parameter names whose mutation during forward must be
             captured and written back (BatchNorm running stats).
+        name: the jitted function's name: the XLA module is ``jit_<name>``
+            in traces and in the persistent cache's key.
         """
         self._forward_fn = forward_fn
+        self._name = name
         self._param_names = sorted(param_dict.keys())
         self._aux_names = [n for n in self._param_names if n in set(aux_names)]
         self._flags = dict(flags or {})
@@ -71,6 +74,8 @@ class CachedOp:
         return "|".join(parts)
 
     def _note_dispatch(self, training, input_vals):
+        """Count the dispatch; True where it is the first of its signature
+        (jax.jit traces, lowers and compiles or loads inside the call)."""
         sig = self._signature(training, input_vals)
         with self._stats_lock:
             rec = self._sig_stats.get(sig)
@@ -78,6 +83,7 @@ class CachedOp:
                 self._sig_stats[sig] = [0, 1]
             else:
                 rec[0] += 1
+        return rec is None
 
     def cache_stats(self):
         """Per-signature compile-cache counters (debugging / serving aid).
@@ -133,6 +139,7 @@ class CachedOp:
             aux_vals = tuple(param_nds[n]._data for n in aux_names)
             return out_vals + aux_vals
 
+        traced.__name__ = traced.__qualname__ = self._name
         return traced
 
     def _make_lowerable(self, training):
@@ -236,18 +243,19 @@ class CachedOp:
 
         jitted = self._get_jitted(training)
         n_aux = len(self._aux_names)
-        self._note_dispatch(training, input_vals)
-
-        if profiler.profiling_imperative():
-            # one span per compiled-graph dispatch, named like the
-            # reference's _CachedOp engine op (cached_op.cc registers the
-            # whole capture as a single profilable op)
-            _t0 = _time.time()
-            flat_out = jitted(*vals)
-            profiler.record_op_span("_CachedOp", _t0, _time.time(),
-                                    cat="cached_op")
-        else:
-            flat_out = jitted(*vals)
+        first = self._note_dispatch(training, input_vals)
+        # the dispatch, not the work: jitted() returns once the program is
+        # enqueued (and, on a first call, traced, lowered and compiled)
+        with profiler.span("cachedop.first_call" if first
+                           else "cachedop.call", op=self._name):
+            if profiler.profiling_imperative():
+                # in a session also under the name of the reference's
+                # _CachedOp engine op (cached_op.cc registers the whole
+                # capture as a single profilable op)
+                with profiler.span("_CachedOp", cat="cached_op"):
+                    flat_out = jitted(*vals)
+            else:
+                flat_out = jitted(*vals)
         vjp_fn = (_LazyVjp(self._get_bwd(training), vals)
                   if recording else None)
 

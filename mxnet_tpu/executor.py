@@ -141,7 +141,10 @@ class Executor:
                     # default-context behavior)
                     dev = group2dev.get(n.attrs.get("ctx_group"), default_dev)
                     in_vals = [jax.device_put(v, dev) for v in in_vals]
-                out = op.fcompute(attrs, *in_vals)
+                # the node's name in every device operation's op_name
+                # (trace time only): .../fwd/stage1_conv0/conv_general_dilated
+                with jax.named_scope(n.name):
+                    out = op.fcompute(attrs, *in_vals)
                 outs = out if isinstance(out, (tuple, list)) else [out]
                 for i, o in enumerate(outs):
                     env[(id(n), i)] = o

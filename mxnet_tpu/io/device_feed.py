@@ -33,17 +33,20 @@ separate state object): an iterator abandoned mid-epoch stays collectable,
 so the ``__del__`` backstop can run and stop the worker instead of leaking
 it for the life of the process.
 
-Observability matches the serving counters (serving/stats.py): a ``feed``
-profiler Domain carries ``<name>:queue_depth`` / ``<name>:h2d_ms`` /
-``<name>:starved_ms`` Counters, gated on ``profiler.profiling_active()``;
-``stats()`` returns the always-on numeric totals (batches, h2d time,
-consumer starvation, peak depth) that the pipeline bench reports.
+Observability: the worker's stages are ``profiler.span``s (``feed.source``,
+``feed.transform``, ``feed.h2d``, ``feed.put_wait``, numbered by batch, with
+the thread's CPU time), the consumer's blocking ``get`` is ``feed.wait``, and
+``feed.batches`` counts into ``profiler.totals()``, which outlives the feed.
+``stats()`` returns this feed's own totals (batches, h2d time, consumer
+starvation, peak depth) that the pipeline bench reports, and, as the serving
+counters do (serving/stats.py), a ``feed`` profiler Domain carries
+``<name>:queue_depth`` / ``<name>:h2d_ms`` / ``<name>:starved_ms`` Counters
+while a profiler session runs.
 """
 from __future__ import annotations
 
 import queue as _queue
 import threading
-import time
 
 from .. import profiler
 from .. import util as _util
@@ -197,21 +200,29 @@ _stage_with_retry = _util.retry(attempts=3, backoff=0.002)(stage_batch)
 def _feed_worker(state):  # mxflow: hot (device feed staging worker)
     try:
         it = iter(state.source)
+        number = 0                   # batch k here is step k of the loop
         while not state.stop.is_set():
             try:
-                item = next(it)
+                with profiler.span("feed.source", seq=number, cpu=True):
+                    item = next(it)
             except StopIteration:
                 state.put(_END)
                 return
             if state.transform is not None:
-                item = state.transform(item)
-            t0 = time.perf_counter()
-            staged = (_stage_with_retry(item, ctx=state.ctx, mesh=state.mesh)
-                      if state.stage else item)
-            h2d_ms = (time.perf_counter() - t0) * 1e3
-            if not state.put(staged):
-                return
+                with profiler.span("feed.transform", seq=number, cpu=True):
+                    item = state.transform(item)
+            staged = item
+            if state.stage:
+                with profiler.span("feed.h2d", seq=number, cpu=True) as h2d:
+                    staged = _stage_with_retry(item, ctx=state.ctx,
+                                               mesh=state.mesh)
+            with profiler.span("feed.put_wait", seq=number, cpu=True):
+                if not state.put(staged):
+                    return
+            number += 1
+            profiler.count("feed.batches")
             depth = state.queue.qsize()
+            h2d_ms = h2d.wall_ns / 1e6 if state.stage else 0.0
             with state.lock:
                 state.batches += 1
                 state.h2d_ms += h2d_ms
@@ -296,9 +307,9 @@ class DeviceFeed:
             item = state.queue.get_nowait()
             starved_ms = 0.0
         except _queue.Empty:
-            t0 = time.perf_counter()
-            item = state.queue.get()
-            starved_ms = (time.perf_counter() - t0) * 1e3
+            with profiler.span("feed.wait") as wait:
+                item = state.queue.get()
+            starved_ms = wait.wall_ns / 1e6
         if starved_ms:
             with self._lock:
                 state.starved_ms += starved_ms

@@ -69,8 +69,7 @@ def _profile_count(name, n=1):
     from . import profiler
     if profiler.state() != "run":
         return
-    import time as _time
-    ts = _time.time() * 1e6
+    ts = profiler._now_us()
     for _ in range(n):
         profiler._record(name, "kvstore", "B", ts=ts)
         profiler._record(name, "kvstore", "E", ts=ts)

@@ -10,6 +10,7 @@ import logging
 import time
 
 from .. import metric as metric_mod
+from .. import profiler
 from ..model import BatchEndParam
 from ..base import string_types
 from ..ndarray import NDArray
@@ -233,16 +234,20 @@ class BaseModule:
                 self.logger.info("Resuming from checkpoint %r epoch %d",
                                  resume_prefix, resume_epoch)
 
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
+        with profiler.span("fit.bind"):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
+        with profiler.span("fit.init_params"):
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        with profiler.span("fit.init_optimizer"):
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
 
         if resume_epoch is not None:
             # optimizer state rides along only when the manifest committed
@@ -278,14 +283,19 @@ class BaseModule:
                 reason = "sparse_row_id_fn prefetch is an eager-loop hook"
             else:
                 try:
-                    compiled_step = CompiledTrainStep.from_module(
-                        self, eval_metric=eval_metric,
-                        steps_per_call=steps_per_call, donate=donate,
-                        shard_update=shard_update, wire_format=wire_format,
-                        wire_threshold=wire_threshold)
+                    with profiler.span("fit.build_step"):
+                        compiled_step = CompiledTrainStep.from_module(
+                            self, eval_metric=eval_metric,
+                            steps_per_call=steps_per_call, donate=donate,
+                            shard_update=shard_update,
+                            wire_format=wire_format,
+                            wire_threshold=wire_threshold)
                 except CompiledStepUnsupported as exc:
                     reason = str(exc)
             if compiled_step is None:
+                # how many fits of this process ran eager; the warning
+                # below says why
+                profiler.count("fit.eager_fallback")
                 if shard_update or wire_format is not None:
                     self.logger.warning(
                         "fit(shard_update=%s, wire_format=%s): the ZeRO "
@@ -317,32 +327,41 @@ class BaseModule:
                         batch_end_callback, metric_interval)
                     data_batch = _NO_BATCH
                 else:
-                    data_batch = next(batches, _NO_BATCH)
+                    with profiler.span("fit.next", seq=0):
+                        data_batch = next(batches, _NO_BATCH)
                     nbatch = 0
                 while data_batch is not _NO_BATCH:
-                    if monitor is not None:
-                        monitor.tic()
-                    self.forward_backward(data_batch)
-                    self.update()
-                    self._metric_from_batch(eval_metric, data_batch)
-                    # only fetch the next batch AFTER training on this one —
-                    # a DataIter may reuse the previous batch's buffers on
-                    # next() (the feed path is exempt: batches arrive as
-                    # device copies, staged before the source advances)
-                    upcoming = next(batches, _NO_BATCH)
-                    if upcoming is not _NO_BATCH:
-                        # prefetch hook for the next batch (sparse row pull)
-                        self.prepare(upcoming,
-                                     sparse_row_id_fn=sparse_row_id_fn)
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if upcoming is _NO_BATCH:
-                        # snapshot before callbacks may auto-reset the metric
-                        eval_name_vals = eval_metric.get_name_value()
-                    _fire(batch_end_callback,
-                          BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                        eval_metric=eval_metric,
-                                        locals=locals()))
+                    with profiler.span("fit.step", seq=nbatch, cpu=True):
+                        if monitor is not None:
+                            monitor.tic()
+                        with profiler.span("step.dispatch"):
+                            self.forward_backward(data_batch)
+                            self.update()
+                        with profiler.span("fit.metric_sync"):
+                            self._metric_from_batch(eval_metric, data_batch)
+                        # only fetch the next batch AFTER training on this
+                        # one — a DataIter may reuse the previous batch's
+                        # buffers on next() (the feed path is exempt: batches
+                        # arrive as device copies, staged before the source
+                        # advances)
+                        with profiler.span("fit.next", seq=nbatch + 1):
+                            upcoming = next(batches, _NO_BATCH)
+                        if upcoming is not _NO_BATCH:
+                            # prefetch hook for the next batch (sparse row
+                            # pull)
+                            self.prepare(upcoming,
+                                         sparse_row_id_fn=sparse_row_id_fn)
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if upcoming is _NO_BATCH:
+                            # snapshot before callbacks may auto-reset the
+                            # metric
+                            eval_name_vals = eval_metric.get_name_value()
+                        with profiler.span("fit.callback"):
+                            _fire(batch_end_callback,
+                                  BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                                eval_metric=eval_metric,
+                                                locals=locals()))
                     data_batch = upcoming
                     nbatch += 1
             finally:
@@ -384,34 +403,45 @@ class BaseModule:
         nbatch = 0
         eval_name_vals = []
         window = []
-        data_batch = next(batches, _NO_BATCH)
+        step = 0         # the loop's iteration, and the number of its batch
+        with profiler.span("fit.next", seq=0):
+            data_batch = next(batches, _NO_BATCH)
         while data_batch is not _NO_BATCH:
             if isinstance(data_batch, list):
                 raise ValueError("pre-sliced multi-device batches reach the "
                                  "compiled path only through a bug: "
                                  "multi-context binds fall back to eager")
-            window.append(data_batch)
-            upcoming = next(batches, _NO_BATCH)
-            if len(window) == cstep.steps_per_call or upcoming is _NO_BATCH:
-                cstep.run_window([tuple(b.data) + tuple(b.label or ())
-                                  for b in window])
-                last_in_epoch = upcoming is _NO_BATCH
-                for i in range(len(window)):
-                    done = nbatch + 1
-                    is_final = last_in_epoch and i == len(window) - 1
-                    if is_final or (metric_interval
-                                    and done % metric_interval == 0):
-                        cstep.sync_metric()
-                    if is_final:
-                        # snapshot before callbacks may auto-reset the metric
-                        eval_name_vals = eval_metric.get_name_value()
-                    _fire(batch_end_callback,
-                          BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                        eval_metric=eval_metric,
-                                        locals=locals()))
-                    nbatch = done
-                window = []
+            with profiler.span("fit.step", seq=step, cpu=True):
+                window.append(data_batch)
+                # batch step + 1: the feed numbers it so too
+                with profiler.span("fit.next", seq=step + 1):
+                    upcoming = next(batches, _NO_BATCH)
+                if len(window) == cstep.steps_per_call or \
+                        upcoming is _NO_BATCH:
+                    with profiler.span("step.dispatch"):
+                        cstep.run_window([tuple(b.data) + tuple(b.label or ())
+                                          for b in window])
+                    last_in_epoch = upcoming is _NO_BATCH
+                    for i in range(len(window)):
+                        done = nbatch + 1
+                        is_final = last_in_epoch and i == len(window) - 1
+                        if is_final or (metric_interval
+                                        and done % metric_interval == 0):
+                            with profiler.span("fit.metric_sync"):
+                                cstep.sync_metric()
+                        if is_final:
+                            # snapshot before callbacks may auto-reset the
+                            # metric
+                            eval_name_vals = eval_metric.get_name_value()
+                        with profiler.span("fit.callback"):
+                            _fire(batch_end_callback,
+                                  BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                                eval_metric=eval_metric,
+                                                locals=locals()))
+                        nbatch = done
+                    window = []
             data_batch = upcoming
+            step += 1
         return nbatch, eval_name_vals
 
     # ------------------------------------------------------------------

@@ -53,6 +53,7 @@ import contextlib
 import numpy as _np
 
 from .. import autograd
+from .. import profiler
 from ..base import MXNetError
 from ..cached_op import CachedOp
 from ..ndarray import NDArray, _wrap
@@ -228,7 +229,8 @@ class CompiledTrainStep:
         flags = {"donate_params": True} if _resolve_donate(donate, ctx) \
             else {}
         self.cached_op = CachedOp(self._make_forward_fn(), state_nd,
-                                  aux_names=tuple(state_nd), flags=flags)  # mxmem: nodonate(donate='auto' resolves per backend at dispatch: CPU XLA cannot alias, accelerator backends donate via donate_params — see _resolve_donate)
+                                  aux_names=tuple(state_nd), flags=flags,
+                                  name="train_step")  # mxmem: nodonate(donate='auto' resolves per backend at dispatch: CPU XLA cannot alias, accelerator backends donate via donate_params — see _resolve_donate)
 
     # -- trace ----------------------------------------------------------
     def _make_forward_fn(self):
@@ -354,19 +356,24 @@ class CompiledTrainStep:
                     new_carry[shard.residual_keys[pkey]] = new_r[i]
 
         def body(carry, xs):
+            # the scopes name the step's device operations in a trace:
+            # fwd and bwd (in the microstep), opt, metric
+            import jax
             import jax.numpy as jnp
             t_t, lr_t, keys_t = xs["t"], xs["lr"], xs["keys"]
             grads, updates, preds, labels, extra = microstep(
                 carry, xs["in"], keys_t)
             new_carry = dict(carry)
             new_carry.update(updates)
-            apply_optimizer(carry, new_carry, grads, lr_t, t_t)
+            with jax.named_scope("opt"):
+                apply_optimizer(carry, new_carry, grads, lr_t, t_t)
             deltas = []
-            for m, (skey, ckey) in zip(metrics, metric_keys):
-                stat, count = m.traced_update(labels, preds)
-                new_carry[skey] = carry[skey] + stat
-                new_carry[ckey] = carry[ckey] + count
-                deltas += [stat, count]
+            with jax.named_scope("metric"):
+                for m, (skey, ckey) in zip(metrics, metric_keys):
+                    stat, count = m.traced_update(labels, preds)
+                    new_carry[skey] = carry[skey] + stat
+                    new_carry[ckey] = carry[ckey] + count
+                    deltas += [stat, count]
             if extra is not None:
                 y = extra
             elif deltas:
@@ -469,20 +476,23 @@ class CompiledTrainStep:
                 len(batches_io[0]) != self._n_inputs:
             raise ValueError("batch provides %d inputs, step expects %d"
                              % (len(batches_io[0]), self._n_inputs))
-        t_nd, lr_nd = self._hyper_vectors(window)
+        with profiler.span("step.hyper"):
+            t_nd, lr_nd = self._hyper_vectors(window)
         stacked = []
-        for j in range(len(batches_io[0])):
-            vals = [b[j]._data for b in batches_io]
-            val = jnp.stack(vals)
-            if self._shard is not None:
-                # replicate the window onto the mesh (the shard_update fit
-                # path keeps the batch replicated — the sharding is of the
-                # UPDATE and optimizer state, docs/PERF.md)
-                import jax
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                val = jax.device_put(
-                    val, NamedSharding(self._shard.mesh, P()))
-            stacked.append(_wrap(val, ctx=self._ctx))
+        with profiler.span("step.stack"):
+            for j in range(len(batches_io[0])):
+                vals = [b[j]._data for b in batches_io]
+                val = jnp.stack(vals)
+                if self._shard is not None:
+                    # replicate the window onto the mesh (the shard_update
+                    # fit path keeps the batch replicated — the sharding is
+                    # of the UPDATE and optimizer state, docs/PERF.md)
+                    import jax
+                    from jax.sharding import NamedSharding, \
+                        PartitionSpec as P
+                    val = jax.device_put(
+                        val, NamedSharding(self._shard.mesh, P()))
+                stacked.append(_wrap(val, ctx=self._ctx))
         with autograd.train_mode():
             out = self.cached_op(self.state, t_nd, lr_nd, *stacked)
         self._advance_counts(window)
@@ -673,13 +683,16 @@ class CompiledTrainStep:
             def f_wrt(*wv):
                 return tuple(fn(arg_vals(wv), aux_vals, keys_t))
 
-            outs, vjp = jax.vjp(f_wrt, *[carry["p:" + n] for n in wrt_names])
+            with jax.named_scope("fwd"):
+                outs, vjp = jax.vjp(f_wrt,
+                                    *[carry["p:" + n] for n in wrt_names])
             n_graph = len(outs) - len(aux_update_names)
             # the fit loop's backward() contract: ones cotangents on every
             # graph output, zeros on the appended BN running-stat tail
             cts = tuple(jnp.ones_like(o) for o in outs[:n_graph]) + \
                 tuple(jnp.zeros_like(o) for o in outs[n_graph:])
-            grad_vals = vjp(cts)
+            with jax.named_scope("bwd"):
+                grad_vals = vjp(cts)
             grads = {"p:" + n: g for n, g in zip(wrt_names, grad_vals)}
             updates = {"a:" + n: v
                        for n, v in zip(aux_update_names, outs[n_graph:])}
@@ -793,6 +806,7 @@ class CompiledTrainStep:
 
         def microstep(carry, batch_vals, keys_t):
             import jax
+            import jax.numpy as jnp
             from ..gluon.block import functional_call
             x_vals = batch_vals[:n_inputs]
             label_vals = batch_vals[n_inputs:]
@@ -809,9 +823,12 @@ class CompiledTrainStep:
                 # mxnet reductions keep a (1,) shape; grad needs a scalar
                 return loss._data.reshape(()), (new_aux, outs)
 
-            (loss, (new_aux, outs)), grad_vals = jax.value_and_grad(
-                loss_of, has_aux=True)({n: carry["p:" + n]
-                                        for n in train_names})
+            with jax.named_scope("fwd"):
+                loss, vjp, (new_aux, outs) = jax.vjp(
+                    loss_of, {n: carry["p:" + n] for n in train_names},
+                    has_aux=True)
+            with jax.named_scope("bwd"):
+                grad_vals, = vjp(jnp.ones_like(loss))
             grads = {"p:" + n: grad_vals[n] for n in train_names}
             updates = {"p:" + n: v for n, v in new_aux.items()}
             return grads, updates, list(outs), list(label_vals), loss

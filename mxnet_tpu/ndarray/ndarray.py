@@ -19,7 +19,6 @@ jit-cached apply, wrap outputs, record on the tape when autograd is active.
 """
 from __future__ import annotations
 
-import time as _time
 
 import numpy as _np
 
@@ -537,11 +536,8 @@ def invoke(op_name, inputs, attrs, out=None):
     the sparse/FComputeEx early returns — becomes a span + aggregate row
     (ProfileOperator analog, src/profiler/profiler.h)."""
     if profiler.profiling_imperative():
-        _t0 = _time.time()
-        try:
+        with profiler.span(op_name, cat="operator"):
             return _invoke(op_name, inputs, attrs, out)
-        finally:
-            profiler.record_op_span(op_name, _t0, _time.time())
     return _invoke(op_name, inputs, attrs, out)
 
 

@@ -1,4 +1,4 @@
-"""Profiler.
+"""Profiler: the MXNet surface, and the one recorder of the program's own spans.
 
 Reference: src/profiler/ + python/mxnet/profiler.py — engine-integrated op
 profiling into chrome://tracing JSON (profiler.h:85-477, DumpProfile), aggregate
@@ -8,20 +8,54 @@ per-op stats table (aggregate_stats.cc), user Domain/Task/Counter/Marker objects
 TPU-native: wraps ``jax.profiler`` (XPlane/TensorBoard traces capture every XLA
 op on-device — richer than the reference's per-engine-op events) and keeps the
 reference's python surface: set_config/set_state/dump/dumps + Domain/Task/
-Counter/Marker built on jax.profiler.TraceAnnotation.  The aggregate table is
-produced from host-side event timings.
+Counter/Marker.  That surface records only while a session runs
+(``set_state("run")``).
+
+Under it sits the recorder that the training hot path uses all the time
+(``fit()``, ``DeviceFeed``, ``CompiledTrainStep``, ``CachedOp``):
+
+* ``with span(name, seq=None, cpu=False, **attrs):`` records one
+  ``Span(name, start_ns, end_ns, cpu_ns, thread, parent, seq, attrs)``:
+  ``time.perf_counter_ns()`` at both ends, the enclosing span's name on that
+  thread, and the step or batch number ``seq`` that it or an enclosing span
+  was given.  With ``cpu=True`` it also reads the thread's CPU clock at both
+  ends (``time.thread_time_ns()``: wall less CPU is what the thread spent
+  blocked); that is a system call of 6 us on the chip's host, so only the
+  spans whose CPU time something reads ask for it (``fit.step``, the feed's
+  stages), and ``cpu_ns`` is None in the others.  The record goes to a
+  bounded ring (the newest ``RING_SIZE`` spans of the process) and to
+  running totals per name; ``count(name, delta)`` adds to the same totals.
+* The span also enters ``jax.profiler.TraceAnnotation("mx:" + name)``, so in
+  any ``jax.profiler`` trace it lies as ``mx:<name>`` on the host thread's
+  line beside the device's operations, on the trace's clock.
+* A ``jax.monitoring`` listener charges every backend compile and every
+  persistent-cache hit or miss to the innermost span open on the thread it
+  happens on: ``totals()[name]["compile.count"]`` says which step compiled.
+
+How to read them: ``totals()`` in a live process (it survives the feed that
+``fit()`` drops at each epoch's end), ``spans()`` for the newest records, the
+``mx:`` spans in XProf or Perfetto, and there the step's device operations
+under ``jit_train_step`` with ``fwd`` / ``bwd`` / ``opt`` / ``metric`` and the
+symbol's node names in their ``op_name`` (module/compiled_step.py).  While an
+MXNet session runs, every span is also a B/E pair of ``dump()`` and a row of
+``dumps()``.  The recording path takes no lock: the ring is a ``deque``, the
+totals are per thread and merged on reading.
 """
 from __future__ import annotations
 
+import collections
 import os
 import time
 import json
 import threading
-from collections import defaultdict
+
+import jax
 
 __all__ = ["set_config", "set_state", "state", "dump", "dumps", "merge_dumps",
            "pause", "resume", "memory_summary",
-           "Domain", "Task", "Frame", "Event", "Counter", "Marker"]
+           "Domain", "Task", "Frame", "Event", "Counter", "Marker",
+           "span", "count", "totals", "spans", "reset_spans", "Span",
+           "RING_SIZE"]
 
 _config = {"profile_all": False, "profile_symbolic": True, "profile_imperative": True,
            "profile_memory": False, "profile_api": False,
@@ -29,8 +63,245 @@ _config = {"profile_all": False, "profile_symbolic": True, "profile_imperative":
 _state = {"running": False, "trace_dir": None}
 _events = []
 _lock = threading.Lock()
-_agg = defaultdict(lambda: [0, 0.0])  # name -> [count, total_ms]
+_agg = collections.defaultdict(lambda: [0, 0.0])  # name -> [count, total_ms]
 
+# ---------------------------------------------------------------------------
+# the recorder
+# ---------------------------------------------------------------------------
+
+Span = collections.namedtuple(
+    "Span", "name start_ns end_ns cpu_ns thread parent seq attrs")
+
+# fit() records about 16 spans per step on two threads: some 4,000 steps
+RING_SIZE = 1 << 16
+_ring = collections.deque(maxlen=RING_SIZE)
+_clock_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns
+_annotate = jax.profiler.TraceAnnotation
+# dump()'s events are microseconds since the epoch, taken from the monotonic
+# clock through one offset read at import: they cannot step with the wall clock
+_EPOCH_NS = time.time_ns() - _clock_ns()
+_NO_SPAN = "(no span)"
+_PEAK = 3        # a totals record is [count, wall ns, CPU ns, max]
+# guarded by _lock: [thread, totals, compiles] of every thread that recorded,
+# and the sums of the threads that have ended
+_tables = []
+_retired = ({}, {})
+
+
+def _fold(into, table, peak=None):
+    """Add ``table``'s records (name -> list of sums) to ``into``'s; the
+    entry at index ``peak`` is a maximum, not a sum."""
+    for name, rec in list(table.items()):
+        have = into.get(name)
+        if have is None:
+            into[name] = list(rec)
+            continue
+        for i, v in enumerate(rec):
+            have[i] = max(have[i], v) if i == peak else have[i] + v
+
+
+class _ThreadTables(threading.local):
+    """What one thread records into without a lock: its stack of open
+    spans, its current ``seq``, its totals (name -> [count, wall ns, CPU ns,
+    max]) and compile charges (span name -> [count, ns, hits, misses])."""
+
+    def __init__(self):
+        self.stack = []
+        self.seq = None
+        self.tid = threading.get_ident()
+        self.totals = {}
+        self.compiles = {}
+        with _lock:
+            # a thread that has ended writes no more: its sums move to
+            # _retired, so the list is as long as the live threads are many
+            for entry in [e for e in _tables if not e[0].is_alive()]:
+                _fold(_retired[0], entry[1], peak=_PEAK)
+                _fold(_retired[1], entry[2])
+                _tables.remove(entry)
+            _tables.append([threading.current_thread(), self.totals,
+                            self.compiles])
+
+
+_tls = _ThreadTables()
+
+
+def _now_us():
+    return (_EPOCH_NS + _clock_ns()) / 1e3
+
+
+class span:
+    """Context manager: one recorded span (see the module docstring).
+    ``seq`` numbers this span and, while it is open, the spans inside it;
+    ``cpu`` asks for the thread's CPU time as well; ``cat`` is the category
+    of its B/E pair in ``dump()``; the other keywords are its attributes, in
+    the record and on the trace annotation.  Once closed it has its wall
+    time as ``wall_ns``."""
+
+    __slots__ = ("name", "attrs", "wall_ns", "_seq", "_cpu", "_cat",
+                 "_annotation", "_t0", "_c0", "_parent", "_outer_seq",
+                 "_stack")
+
+    def __init__(self, name, seq=None, cpu=False, cat="span", **attrs):
+        self.name = name
+        self.attrs = attrs
+        self._seq = seq
+        self._cpu = cpu
+        self._cat = cat
+
+    def __enter__(self):
+        tls = _tls
+        stack = self._stack = tls.stack
+        self._parent = stack[-1].name if stack else None
+        stack.append(self)
+        if self._seq is not None:
+            self._outer_seq = tls.seq
+            tls.seq = self._seq
+        self._annotation = _annotate("mx:" + self.name, **self.attrs)
+        self._annotation.__enter__()
+        if self._cpu:
+            self._c0 = _cpu_ns()
+        self._t0 = t0 = _clock_ns()
+        if _state["running"]:
+            # B now and not with E: a span still open when the session stops
+            # keeps its begin in dump()
+            _record(self.name, self._cat, "B", (_EPOCH_NS + t0) / 1e3,
+                    self.attrs)
+        return self
+
+    def __exit__(self, exc_type=None, exc=None, tb=None):
+        t1 = _clock_ns()
+        cpu = _cpu_ns() - self._c0 if self._cpu else None
+        self._annotation.__exit__(exc_type, exc, tb)
+        tls = _tls
+        stack = self._stack          # the opening thread's, whoever closes
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:          # a Task stopped out of order
+            stack.remove(self)
+        seq = tls.seq
+        if self._seq is not None:
+            tls.seq = self._outer_seq
+        name, t0 = self.name, self._t0
+        self.wall_ns = wall = t1 - t0
+        _ring.append((name, t0, t1, cpu, tls.tid, self._parent, seq,  # mxlint: disable=CON102
+                      self.attrs or None))   # deque.append is atomic, and bounded
+        rec = tls.totals.get(name)
+        if rec is None:
+            tls.totals[name] = [1, wall, cpu or 0, wall]
+        else:
+            rec[0] += 1
+            rec[1] += wall
+            if cpu:
+                rec[2] += cpu
+            if wall > rec[3]:
+                rec[3] = wall
+        if _state["running"]:
+            _record(name, self._cat, "E", (_EPOCH_NS + t1) / 1e3, self.attrs)
+            with _lock:
+                a = _agg[name]
+                a[0] += 1
+                a[1] += wall / 1e6
+        return False
+
+
+def count(name, delta=1):
+    """Add ``delta`` to the counter ``name`` in the totals: its ``count`` is
+    the sum of the deltas, its ``max`` the largest of them."""
+    totals_ = _tls.totals
+    rec = totals_.get(name)
+    if rec is None:
+        totals_[name] = [delta, 0, 0, delta]
+    else:
+        rec[0] += delta
+        if delta > rec[3]:
+            rec[3] = delta
+
+
+def totals():
+    """``{name: {"count", "wall_ns", "cpu_ns", "max"}}`` of every span and
+    counter since the process started (or ``reset_spans()``), over all
+    threads; ``max`` is a span's longest wall time in ns, and ``cpu_ns`` is
+    0 for a name whose spans read no CPU clock.  A span name under
+    which something compiled also has ``compile.count``, ``compile.ns``,
+    ``compile.cache_hits`` and ``compile.cache_misses``.  Nothing is
+    cleared; a span that ends during the call may be counted in part."""
+    merged, compiled = {}, {}
+    with _lock:
+        _fold(merged, _retired[0], peak=_PEAK)
+        _fold(compiled, _retired[1])
+        for _, thread_totals, thread_compiles in _tables:
+            _fold(merged, thread_totals, peak=_PEAK)
+            _fold(compiled, thread_compiles)
+    out = {name: {"count": r[0], "wall_ns": r[1], "cpu_ns": r[2],
+                  "max": r[3]} for name, r in merged.items()}
+    for name, r in compiled.items():
+        out.setdefault(name, {"count": 0, "wall_ns": 0, "cpu_ns": 0,
+                              "max": 0}).update(
+            {"compile.count": r[0], "compile.ns": r[1],
+             "compile.cache_hits": r[2], "compile.cache_misses": r[3]})
+    return out
+
+
+def spans(since_ns=None):
+    """The ring's records as ``Span`` tuples, oldest first: all of them, or
+    those that ended at or after ``since_ns`` (``time.perf_counter_ns()``)."""
+    records = list(_ring)
+    return [Span(*r) for r in records
+            if since_ns is None or r[2] >= since_ns]
+
+
+def reset_spans():
+    """Forget every span, total and compile charge (for tests)."""
+    with _lock:
+        _ring.clear()
+        for table in _retired:
+            table.clear()
+        for _, thread_totals, thread_compiles in _tables:
+            thread_totals.clear()
+            thread_compiles.clear()
+
+
+def _event(name, cat, ph, ts_us, args):
+    return {"name": name, "cat": cat, "ph": ph, "ts": ts_us,
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "args": dict(args or {})}
+
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 2,
+                 "/jax/compilation_cache/cache_misses": 3}
+
+
+def _compile_record():
+    tls = _tls
+    name = tls.stack[-1].name if tls.stack else _NO_SPAN
+    rec = tls.compiles.get(name)
+    if rec is None:
+        rec = tls.compiles[name] = [0, 0, 0, 0]
+    return rec
+
+
+def _on_compile_duration(event, duration, **_):
+    if event == _BACKEND_COMPILE:
+        rec = _compile_record()
+        rec[0] += 1
+        rec[1] += int(duration * 1e9)
+
+
+def _on_compile_event(event, **_):
+    slot = _CACHE_EVENTS.get(event)
+    if slot is not None:
+        _compile_record()[slot] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+jax.monitoring.register_event_listener(_on_compile_event)
+
+
+# ---------------------------------------------------------------------------
+# the MXNet surface
+# ---------------------------------------------------------------------------
 
 def set_config(**kwargs):
     with _lock:
@@ -42,7 +313,6 @@ def set_state(state_="stop", profile_process="worker"):
 
 
 def _set_state(state_, fresh):
-    import jax
     if state_ == "run":
         with _lock:
             if _state["running"]:
@@ -63,7 +333,13 @@ def _set_state(state_, fresh):
         # claim above excludes a second start_trace, but a concurrent
         # stop() may land in this window — detected and honored below
         try:
-            jax.profiler.start_trace(trace_dir)
+            # spans and device operations, not every Python call: with the
+            # Python tracer on, 20 s of training took 170 s to write out
+            # (PERF.md, PR 24)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 2
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         except Exception:
             trace_dir = None
         with _lock:
@@ -120,29 +396,14 @@ def profiling_active():
     return _state["running"]
 
 
-def record_op_span(name, t0_s, t1_s, cat="operator"):
-    """One imperative op dispatch: B/E trace events + aggregate-table bump
-    (src/profiler ProfileOperator analog).  Times are ``time.time()``
-    seconds (the same timebase _record uses, so spans line up with
-    Domain/Task events in the dumped trace) and measure host dispatch
-    cost; device-side op timing is the XPlane trace captured alongside
-    (see set_state)."""
-    with _lock:
-        for ph, ts in (("B", t0_s), ("E", t1_s)):
-            _events.append({"name": name, "cat": cat, "ph": ph,
-                            "ts": ts * 1e6, "pid": os.getpid(),
-                            "tid": threading.get_ident(), "args": {}})
-        a = _agg[name]
-        a[0] += 1
-        a[1] += (t1_s - t0_s) * 1e3
-
-
 def _record(name, cat, ph, ts=None, args=None):
+    """One event of ``dump()``; nothing while no session runs, so a Counter
+    in a long-lived process cannot grow the buffer."""
+    if not _state["running"]:
+        return
     with _lock:
-        _events.append({"name": name, "cat": cat, "ph": ph,
-                        "ts": (ts if ts is not None else time.time() * 1e6),
-                        "pid": os.getpid(), "tid": threading.get_ident(),
-                        "args": args or {}})
+        _events.append(_event(name, cat, ph,
+                              ts if ts is not None else _now_us(), args))
 
 
 def dump(finished=True, profile_process="worker"):
@@ -182,8 +443,7 @@ def memory_summary(device=None):
     Device-side internals (XLA scratch, donated aliasing) are invisible by
     design — for whole-HBM accounting use TensorBoard's memory_viewer on
     an XPlane trace from ``set_state('run')``/``dump()``."""
-    import jax
-    buckets = defaultdict(lambda: [0, 0])   # (dtype, shape) -> [count, bytes]
+    buckets = collections.defaultdict(lambda: [0, 0])   # (dtype, shape) -> [count, bytes]
     total = n = 0
     for arr in jax.live_arrays():
         try:
@@ -224,8 +484,8 @@ def merge_dumps(filenames, out=None):
         with open(out, "w") as f:
             json.dump({"traceEvents": events}, f)
     # pair B/E spans per (worker pid, thread, name) to recover durations
-    open_spans = defaultdict(list)
-    agg = defaultdict(lambda: [0, 0.0])
+    open_spans = collections.defaultdict(list)
+    agg = collections.defaultdict(lambda: [0, 0.0])
     for ev in sorted(events, key=lambda e: e.get("ts", 0)):
         name = ev.get("name")
         if name is None or ev.get("ph") not in ("B", "E"):
@@ -266,33 +526,23 @@ class Domain:
 
 
 class _Span:
+    """A named ``span`` of the recorder that is started and stopped by hand;
+    its domain is the category of its B/E pair."""
+
     def __init__(self, domain, name):
         self.domain = domain
         self.name = name
-        self._start = None
-        self._annotation = None
+        self._open = None
 
     def start(self):
-        import jax
-        self._start = time.time()
-        _record(self.name, str(self.domain), "B")
-        try:
-            self._annotation = jax.profiler.TraceAnnotation(self.name)
-            self._annotation.__enter__()
-        except Exception:
-            self._annotation = None
+        self._open = span(self.name, cat=str(self.domain))
+        self._open.__enter__()
         return self
 
     def stop(self):
-        if self._annotation is not None:
-            self._annotation.__exit__(None, None, None)
-            self._annotation = None
-        _record(self.name, str(self.domain), "E")
-        if self._start is not None:
-            with _lock:
-                a = _agg[self.name]
-                a[0] += 1
-                a[1] += (time.time() - self._start) * 1e3
+        if self._open is not None:
+            self._open.__exit__()
+            self._open = None
         return self
 
     def __enter__(self):
