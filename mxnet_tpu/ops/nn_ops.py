@@ -326,6 +326,36 @@ def _bn_apply(attrs, data, gamma, beta, mean, var):
     return (data - jnp.reshape(mean, bshape)) * inv + jnp.reshape(beta, bshape)
 
 
+def _bn_batch_stats(data, axis, shift, sync=None):
+    """Biased batch (mean, var) over every axis but ``axis``, in ONE pass:
+    ``m1 = mean(x - c)`` and ``m2 = mean((x - c)^2)`` are sibling reductions
+    of one operand, which XLA folds into the epilogue of the convolution
+    that produced ``x``; ``mean = c + m1``, ``var = max(m2 - m1^2, 0)``.
+    The two-pass ``jnp.var`` cost one more full read of ``x`` forward and,
+    under autodiff, a third for the mean's gradient ``sum(x - mean)``, which
+    is identically zero.  ``c`` is ``shift`` (the running mean) held
+    constant: a vector known before ``x`` is, so it costs no pass, and once
+    it tracks the batch mean ``m2 - m1^2`` cancels nothing however far the
+    data sits from zero (with ``c = 0``, a fresh layer, it is the textbook
+    E[x^2] - E[x]^2).  Both sums accumulate in float32 whatever the data's
+    dtype (squares of bfloat16 summed in bfloat16 are no variance), and the
+    results are float32 too: the caller casts.  ``sync`` (SyncBatchNorm's
+    cross-device ``pmean``) is applied to both moments before the variance."""
+    jnp = _jnp()
+    axes = tuple(i for i in range(data.ndim) if i != axis)
+    acc = jnp.promote_types(data.dtype, jnp.float32)
+    c = _lax().stop_gradient(shift).astype(acc)
+    d = data.astype(acc) - jnp.reshape(
+        c, tuple(-1 if i == axis else 1 for i in range(data.ndim)))
+    n = data.size / data.shape[axis]
+    m1 = jnp.sum(d, axis=axes) / n
+    m2 = jnp.sum(d * d, axis=axes) / n
+    if sync is not None:
+        m1, m2 = sync(m1), sync(m2)
+    var = jnp.maximum(m2 - m1 * m1, 0)
+    return c + m1, var
+
+
 @register("BatchNorm", num_outputs=3, visible_outputs=1, mode_dependent=True)
 def _batch_norm(attrs, data, gamma, beta, moving_mean, moving_var):
     """Batch normalization (src/operator/nn/batch_norm.cc).
@@ -336,7 +366,12 @@ def _batch_norm(attrs, data, gamma, beta, moving_mean, moving_var):
     the output_mean_var doc promises "data_mean and the inverse of
     data_var").  Consumers that fold running averages (gluon BatchNorm,
     the executor's functional aux update) recover the raw variance as
-    1/invstd^2 - eps."""
+    1/invstd^2 - eps.
+
+    In training mode the batch variance is one-pass, E[d^2] - E[d]^2 of
+    d = x - moving_mean with float32 sums (_bn_batch_stats): the step is
+    bound by memory traffic, and the two-pass variance read every
+    convolution output twice more."""
     jnp = _jnp()
     axis = int(attrs.get("axis", 1)) % data.ndim  # -1 = channel-last
     eps = float(attrs.get("eps", BN_EPS_DEFAULT))
@@ -344,9 +379,8 @@ def _batch_norm(attrs, data, gamma, beta, moving_mean, moving_var):
     if use_global:
         mean, var = moving_mean, moving_var
     else:
-        axes = tuple(i for i in range(data.ndim) if i != axis)
-        mean = jnp.mean(data, axis=axes)
-        var = jnp.var(data, axis=axes)
+        mean, var = (s.astype(data.dtype) for s in
+                     _bn_batch_stats(data, axis, moving_mean))
     invstd = 1.0 / jnp.sqrt(var + eps)
     return _bn_apply(attrs, data, gamma, beta, mean, var), mean, invstd
 
@@ -1093,15 +1127,13 @@ def _sync_batch_norm(attrs, data, gamma, beta, moving_mean, moving_var):
     if use_global:
         mean, var = moving_mean, moving_var
     else:
-        axes = tuple(i for i in range(data.ndim) if i != channel_axis)
-        mean = jnp.mean(data, axis=axes)
-        sq = jnp.mean(jnp.square(data), axis=axes)
-        try:  # inside shard_map/pmap with the axis bound: cross-device stats
-            mean = lax.pmean(mean, axis_name)
-            sq = lax.pmean(sq, axis_name)
-        except NameError:  # axis not bound: single-device semantics
-            pass
-        var = sq - jnp.square(mean)
+        def sync(moment):
+            try:  # inside shard_map/pmap with the axis bound: cross-device
+                return lax.pmean(moment, axis_name)
+            except NameError:  # axis not bound: single-device semantics
+                return moment
+        mean, var = (s.astype(data.dtype) for s in
+                     _bn_batch_stats(data, channel_axis, moving_mean, sync))
     # invstd third output, matching BatchNorm (batch_norm.cc:140-154)
     eps = float(attrs.get("eps", BN_EPS_DEFAULT))
     invstd = 1.0 / jnp.sqrt(var + eps)

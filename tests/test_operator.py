@@ -93,6 +93,177 @@ def test_batchnorm_training_stats():
                         rtol=1e-4, atol=1e-5)
 
 
+def _bn_data(ratio, axis, seed=0):
+    """float32 data with |mean|/std = ratio per channel, channels on
+    ``axis`` of a 4-d tensor, and the axes the statistics run over."""
+    rng = np.random.RandomState(seed)
+    shape = (16, 8, 14, 14) if axis == 1 else (16, 14, 14, 8)
+    x = rng.normal(ratio, 1.0, shape).astype(np.float32)
+    return x, tuple(i for i in range(4) if i != axis % 4)
+
+
+def _bn_two_pass(attrs, x, gamma, beta):
+    """The formula BatchNorm had before PR 26 (jnp.mean, then the two-pass
+    jnp.var), kept here as the reference for gradients and for the count."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn_ops
+    axis = int(attrs.get("axis", 1)) % x.ndim
+    axes = tuple(i for i in range(x.ndim) if i != axis)
+    mean, var = jnp.mean(x, axis=axes), jnp.var(x, axis=axes)
+    return (nn_ops._bn_apply(attrs, x, gamma, beta, mean, var), mean,
+            1.0 / jnp.sqrt(var + attrs["eps"]))
+
+
+def _bn_one_pass(attrs, x, gamma, beta):
+    """The op itself, with a running mean 0.5 std off _bn_grad_args' data."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn_ops
+    c = x.shape[int(attrs.get("axis", 1))]
+    return nn_ops._batch_norm(attrs, x, gamma, beta,
+                              jnp.full((c,), 2.5, x.dtype), jnp.ones((c,)))
+
+
+def _bn_check_stats(axis, ratio, tracked, rtol_var):
+    """(a) out, mean and invstd against a float64 two-pass reckoning."""
+    eps = 1e-3
+    x, axes = _bn_data(ratio, axis)
+    x64 = x.astype(np.float64)
+    mean64, var64 = x64.mean(axis=axes), x64.var(axis=axes)
+    gamma = np.linspace(0.5, 1.5, 8).astype(np.float32)
+    beta = np.linspace(-1, 1, 8).astype(np.float32)
+    # a running mean that tracks the batch mean to 0.3 std, or a fresh one
+    moving_mean = (mean64 + 0.3 if tracked else np.zeros(8)).astype(np.float32)
+    with autograd.record(train_mode=True):
+        out, m, inv = nd.BatchNorm(nd.array(x), nd.array(gamma),
+                                   nd.array(beta), nd.array(moving_mean),
+                                   nd.ones((8,)), fix_gamma=False, eps=eps,
+                                   axis=axis)
+    var = 1.0 / inv.asnumpy().astype(np.float64) ** 2 - eps
+    assert np.max(np.abs(var - var64) / var64) < rtol_var
+    assert_almost_equal(m.asnumpy(), mean64, rtol=1e-5, atol=1e-4)
+    bshape = [1, 1, 1, 1]
+    bshape[axis] = 8
+    want = ((x64 - mean64.reshape(bshape)) / np.sqrt(var64 + eps).reshape(bshape)
+            * gamma.reshape(bshape) + beta.reshape(bshape))
+    assert_almost_equal(out.asnumpy(), want, rtol=1e-3,
+                        atol=max(1e-4, 4 * rtol_var))
+
+
+def _bn_check_constant():
+    """(a) a constant input has variance 0, never below: the clamp."""
+    eps = 1e-3
+    x = np.full((16, 8, 14, 14), 1234.567, np.float32)
+    with autograd.record(train_mode=True):
+        out, m, inv = nd.BatchNorm(nd.array(x), nd.ones((8,)), nd.zeros((8,)),
+                                   nd.zeros((8,)), nd.ones((8,)),
+                                   fix_gamma=False, eps=eps)
+    var = 1.0 / inv.asnumpy().astype(np.float64) ** 2 - eps
+    assert np.all(np.isfinite(inv.asnumpy())) and np.all(var >= -1e-9)
+    assert np.all(np.isfinite(out.asnumpy()))
+
+
+def _bn_check_bfloat16():
+    """(b) bfloat16 data: the sums run in float32, so the variance is that
+    of the rounded input to 1e-3; a bfloat16 mean alone (8 bits, |mean|/std
+    3) would miss by some 3%.  The op's outputs keep the data's dtype."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn_ops
+    x, axes = _bn_data(3, 1)
+    xb = jnp.asarray(x).astype(jnp.bfloat16)
+    x32 = np.asarray(xb.astype(jnp.float32), np.float64)
+    mean, var = nn_ops._bn_batch_stats(xb, 1, jnp.zeros((8,), jnp.bfloat16))
+    assert mean.dtype == var.dtype == jnp.float32
+    assert_almost_equal(np.asarray(var), x32.var(axis=axes), rtol=1e-3, atol=0)
+    assert_almost_equal(np.asarray(mean), x32.mean(axis=axes), rtol=1e-5,
+                        atol=1e-5)
+    out, m, inv = nn_ops._batch_norm(
+        {"_training": True, "fix_gamma": False, "eps": 1e-3}, xb,
+        jnp.ones((8,), jnp.bfloat16), jnp.zeros((8,), jnp.bfloat16),
+        jnp.zeros((8,), jnp.bfloat16), jnp.ones((8,), jnp.bfloat16))
+    assert out.dtype == m.dtype == inv.dtype == jnp.bfloat16
+    assert_almost_equal(np.asarray(inv.astype(jnp.float32)),
+                        1 / np.sqrt(x32.var(axis=axes) + 1e-3), rtol=2 ** -7,
+                        atol=0)
+
+
+def _bn_grad_args(axis):
+    import jax.numpy as jnp
+    x, _ = _bn_data(3, axis, seed=1)
+    rng = np.random.RandomState(2)
+    args = (jnp.asarray(x), jnp.asarray(rng.uniform(0.5, 1.5, 8), jnp.float32),
+            jnp.asarray(rng.uniform(-1, 1, 8), jnp.float32))
+    # non-zero cotangents on all three outputs: out, mean and invstd
+    cts = (jnp.asarray(rng.normal(size=x.shape), jnp.float32),
+           jnp.asarray(rng.normal(size=8), jnp.float32),
+           jnp.asarray(rng.normal(size=8), jnp.float32))
+    return args, cts
+
+
+def _bn_check_grad(axis):
+    """(c) d/d(x, gamma, beta) against autodiff of the two-pass formula."""
+    import jax
+    attrs = {"_training": True, "fix_gamma": False, "eps": 1e-3, "axis": axis}
+    args, cts = _bn_grad_args(axis)
+    got = jax.vjp(lambda *a: _bn_one_pass(attrs, *a), *args)[1](cts)
+    want = jax.vjp(lambda *a: _bn_two_pass(attrs, *a), *args)[1](cts)
+    for g, w in zip(got, want):
+        scale = float(np.max(np.abs(np.asarray(w))))
+        assert_almost_equal(np.asarray(g), np.asarray(w), rtol=1e-4,
+                            atol=2e-5 * scale)
+
+
+def _bn_check_reduction_count():
+    """(d) a count, which a CPU may report: the gradient of one
+    training-mode BatchNorm with respect to its input reads the full-size
+    tensor in 4 reductions (two sums forward; sum(g*inv) and sum(g*(x-mean))
+    backward), where the two-pass formula needed 6 (mean, the mean inside
+    var, the squares; and backward one more for the mean's gradient
+    sum(x - mean), which is identically zero).  beta's gradient, sum(g),
+    is one more in both."""
+    import jax
+    attrs = {"_training": True, "fix_gamma": False, "eps": 1e-3}
+    args, cts = _bn_grad_args(1)
+
+    def count(fn, wrt):
+        """Full-size reductions in the gradient w.r.t. the first ``wrt``
+        of (x, gamma, beta)."""
+        text = jax.jit(lambda a, c: jax.vjp(
+            lambda *lead: fn(attrs, *lead, *args[wrt:]), *a)[1](c)
+        ).lower(args[:wrt], cts).as_text()
+        return sum(1 for line in text.splitlines()
+                   if "stablehlo.reduce" in line
+                   and "(tensor<16x8x14x14xf32>" in line)
+
+    assert count(_bn_two_pass, 1) == 6      # the count itself counts
+    assert count(_bn_one_pass, 1) == 4
+    assert count(_bn_two_pass, 3) == 7
+    assert count(_bn_one_pass, 3) == 5
+
+
+@pytest.mark.parametrize("check", [
+    pytest.param(lambda: _bn_check_stats(1, 0, True, 1e-4), id="stats-nchw-0"),
+    pytest.param(lambda: _bn_check_stats(1, 3, True, 1e-4), id="stats-nchw-3"),
+    pytest.param(lambda: _bn_check_stats(1, 30, True, 1e-3), id="stats-nchw-30"),
+    pytest.param(lambda: _bn_check_stats(-1, 0, True, 1e-4), id="stats-last-0"),
+    pytest.param(lambda: _bn_check_stats(-1, 3, True, 1e-4), id="stats-last-3"),
+    pytest.param(lambda: _bn_check_stats(-1, 30, True, 1e-3), id="stats-last-30"),
+    # a fresh layer's running mean is 0: the textbook formula's cancellation
+    pytest.param(lambda: _bn_check_stats(1, 3, False, 1e-3),
+                 id="stats-nchw-3-fresh"),
+    pytest.param(lambda: _bn_check_stats(1, 30, False, 2e-2),
+                 id="stats-nchw-30-fresh"),
+    pytest.param(_bn_check_constant, id="stats-constant"),
+    pytest.param(_bn_check_bfloat16, id="bfloat16"),
+    pytest.param(lambda: _bn_check_grad(1), id="grad-nchw"),
+    pytest.param(lambda: _bn_check_grad(-1), id="grad-last"),
+    pytest.param(_bn_check_reduction_count, id="reduction-count"),
+])
+def test_batchnorm_one_pass(check):
+    """Training-mode BatchNorm takes its batch statistics in one pass
+    (ops/nn_ops.py _bn_batch_stats)."""
+    check()
+
+
 def test_activation_ops():
     x = np.random.uniform(-2, 2, (3, 4)).astype(np.float32)
     a = nd.array(x)
