@@ -30,7 +30,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def main(argv):
     sys.path.insert(0, ROOT)
-    from benchmark import compare, harness
+    from benchmark import harness
+    from benchmark.comparisons import train_norms as compare
     import jax.numpy as jnp
 
     name, first, count = argv[0], int(argv[1]), int(argv[2])

@@ -78,13 +78,18 @@ def test_sound_run_is_correct(cell):
     assert list(result)[-1] == "compared"
     assert set(result["compared"]) == {"grad_norm_gap", "update_norm_gap",
                                        "bn_stats_gap"}
+    # the end-to-end values and the counts of operations are the entry's
+    assert sorted(result["metrics"]) == sorted(cell.metric_names("end_to_end"))
     assert result["metrics"]["train_images_per_s"]["value"] > 0
+    assert result["attempted"] == result["window"]["steps"] > 0
+    assert result["failed"] == 0
     assert result["window"]["window_compiles"] == 0
 
 
 def test_control_in_bfloat16_fails(cell):
     import jax.numpy as jnp
-    from benchmark import compare, traffic
+    from benchmark import traffic
+    from benchmark.comparisons import train_norms as compare
     batches = traffic.make_pool(cell.config, cell.traffic, SEED, 3)
     reference = compare.reference_readings(cell, SEED, batches)
     control = compare.reference_readings(

@@ -196,7 +196,11 @@ def test_silent_when_untraced_or_without_a_recorder(name, fake, monkeypatch):
 def test_every_new_metric_is_in_the_manifest():
     spec = harness.load_json(ROOT, "BENCHMARK.json")
     listed = {m["name"]: m for m in spec["per_layer"]}
+    fit_cells = [w["name"] for w in spec["workloads"] if harness.load_json(
+        ROOT, "benchmark", "traffic", w["traffic"] + ".json")["entry"]
+        == "module_fit"]
     for name in METRICS:
-        assert "workloads" not in listed[name]
+        # they read fit()'s spans: for the cells whose entry runs fit()
+        assert listed[name]["workloads"] == fit_cells
         assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
                                            name + ".py"))

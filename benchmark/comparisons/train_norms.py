@@ -1,11 +1,14 @@
-"""The comparison that decides ``correct`` for a training cell.
+"""The comparison that decides ``correct`` for a training cell: ``train_norms``,
+the one a traffic file gets that names no other under ``compare``.
 
 The program's readings come from the object the window then drives: the loss
 of each of its first steps, the first gradient as the optimizer got it (from
-the momentum after one step), and how far every parameter and BatchNorm
-statistic moved over those steps.  The reference (reference/) follows the same
-steps from the same seed in float32 at precision ``highest``, once the window
-has closed and the program's state is freed.
+the optimizer's state after one step), and how far every parameter and
+BatchNorm statistic moved over those steps.  The reference (reference/)
+follows the same steps from the same seed in float32 at precision
+``highest``, once the window has closed and the program's state is freed:
+the family's network and loss under the configuration's optimizer, on the
+batches the entry's ``first_batches()`` hands over, each a tuple passed whole.
 
 Norms are compared leaf by leaf, as the gap between the program's norm and the
 reference's over the reference's norm of that leaf or of the median leaf,
@@ -27,7 +30,8 @@ Held to a limit (``limits/<cell>.json``; PERF.md has the readings):
   leaf of the change is what bfloat16 weights move most, since a tenth of
   the leaves and more lose their small updates to rounding (8 to 13 times a
   sound run's, where the median leaf's change reads 3 to 8 times).
-* ``bn_stats_gap``: the worst BatchNorm statistic's gap of its change.
+* ``bn_stats_gap``: the worst BatchNorm statistic's gap of its change; 0 for
+  a family that has none.
 
 Reported beside them and held to nothing (``observed``): the worst step's
 loss gap, the worst leaf's two gaps and the median leaf's gap of the change,
@@ -125,14 +129,14 @@ def judge(held, limits):
 
 def reference_readings(cell, seed, batches, **variant):
     from benchmark.reference import common
-    return common.train_readings(cell.config, seed, batches,
-                                 cell.traffic["lr"], cell.traffic["momentum"],
+    return common.train_readings(cell.config, seed, batches, cell.traffic,
                                  **variant)
 
 
-def compare(cell, seed, program, batches):
-    """(judged numbers, observed numbers) of one run."""
-    held, observed = numbers(program,
-                             reference_readings(cell, seed, batches))
+def compare(cell, seed, program, run):
+    """(judged numbers, observed numbers) of one run: ``program`` is what the
+    entry's ``readings()`` gave before its state was freed."""
+    held, observed = numbers(
+        program, reference_readings(cell, seed, run.first_batches()))
     return judge(held, cell.limits), {
         k: v if math.isfinite(v) else 1e30 for k, (v, _) in observed.items()}

@@ -2,11 +2,14 @@
 
 The zoo network of the configuration is bound as a ``Module`` and trained
 through ``fit()`` -> ``CompiledTrainStep`` with a ``DeviceFeed``
-(``prefetch_to_device``), SGD with momentum, the cross-entropy metric, over
-this file's own ``DataIter``.  It cycles the seed's pool of batches from host
+(``prefetch_to_device``), the configuration's optimizer (as its file under
+``reference/optimizers/`` names it to the program), the cross-entropy metric,
+over this file's own ``DataIter``.  It cycles the seed's pool of batches from host
 memory: epoch 0 is the warm-up (``warmup_steps`` steps, the first three of
 which the comparison reads), epoch 1 is the window and ends on the clock.
-The run fails if ``fit()`` fell back to the eager loop.
+The run fails if ``fit()`` fell back to the eager loop.  The entry reports
+``train_images_per_s`` (samples, that is rows of a batch, completed per second
+of the window) and ``setup_s``; a step cannot fail short of the run.
 
 The window opens at the iterator's first ``next()`` of epoch 1, with the
 device idle, and closes when the state of the last step is ready.  A traced
@@ -19,13 +22,30 @@ from __future__ import annotations
 import time
 
 import jax
-import numpy as np
 
+from benchmark import trace as trace_mod
 from benchmark import traffic as traffic_mod
 from benchmark.harness import BenchmarkError
 from benchmark.reference import common as reference
 
 COMPARED_STEPS = 3
+
+
+def fetch_state(cstep, kind, prefix):
+    """Host copies of a compiled step's state entries of one kind (``p:``
+    parameters, ``a:`` BatchNorm statistics), keyed by the name without the
+    network's ``prefix``; of ``o:``, each parameter's optimizer state, the
+    list of its arrays in the program's order (``o:<name>:<i>``)."""
+    skip = len(kind) + len(prefix)
+    values = jax.device_get({k: v._data for k, v in cstep.state.items()
+                             if k.startswith(kind)})
+    if kind != "o:":
+        return {k[skip:]: v for k, v in values.items()}
+    found = {}
+    for name, i in sorted((k.rsplit(":", 1) for k in values),
+                          key=lambda ni: (ni[0], int(ni[1]))):
+        found.setdefault(name[skip:], []).append(values[name + ":" + i])
+    return found
 
 
 def build_network(config):
@@ -47,7 +67,7 @@ class Run:
         self.tracing = False
 
     # -- the harness's DataIter -----------------------------------------
-    def _make_iter(self, mx, pool, batch, size):
+    def _make_iter(self, mx, pool, batch):
         run = self
         host = mx.cpu()
         annotate = jax.profiler.TraceAnnotation
@@ -63,9 +83,9 @@ class Run:
             def __init__(self):
                 super().__init__(batch)
                 self.provide_data = [mx.io.DataDesc("data",
-                                                    (batch, 3, size, size))]
+                                                    pool[0][0].shape)]
                 self.provide_label = [mx.io.DataDesc("softmax_label",
-                                                     (batch,))]
+                                                     pool[0][1].shape)]
                 self.epoch = self.cursor = self.served = 0
                 self.t_open = None
 
@@ -91,11 +111,7 @@ class Run:
                         if run.trace_dir and not run.tracing and \
                                 now - self.t_open >= run.seconds - traced:
                             run.tracing = True
-                            options = jax.profiler.ProfileOptions()
-                            options.python_tracer_level = 0   # spans only:
-                            options.host_tracer_level = 2     # not every call
-                            jax.profiler.start_trace(
-                                run.trace_dir, profiler_options=options)
+                            trace_mod.start(run.trace_dir)
                     item = batches[self.cursor % len(batches)]
                     self.cursor += 1
                     self.served += 1
@@ -122,10 +138,12 @@ class Run:
                     param.eval_metric.reset()
                 if done == 1:
                     self._stamp("first_step")
-                    self.snapshots["moms"] = self._fetch(cstep, "o:")
+                    self.snapshots["opt"] = fetch_state(cstep, "o:",
+                                                        self.prefix)
                 if done == COMPARED_STEPS:
                     self.snapshots["end"] = {
-                        **self._fetch(cstep, "p:"), **self._fetch(cstep, "a:")}
+                        **fetch_state(cstep, "p:", self.prefix),
+                        **fetch_state(cstep, "a:", self.prefix)}
             elif param.locals["is_final"]:
                 jax.block_until_ready(
                     [v._data for v in cstep.state.values()])
@@ -134,17 +152,6 @@ class Run:
                     pass
                 self.compiles_at_close = self.meter.read()
                 self.window_steps = param.nbatch + 1
-
-    def _fetch(self, cstep, kind):
-        """Host copies of the state entries of one kind (``p:`` parameters,
-        ``a:`` BatchNorm statistics, ``o:`` momenta), keyed by the name
-        without the network's prefix."""
-        skip = len(kind) + len(self.prefix)
-        picked = {k: v._data for k, v in cstep.state.items()
-                  if k.startswith(kind)}
-        values = jax.device_get(picked)
-        return {k[skip:].rsplit(":", 1)[0] if kind == "o:" else k[skip:]: v
-                for k, v in values.items()}
 
     # -- the run ----------------------------------------------------------
     def drive(self, window=True):
@@ -168,16 +175,16 @@ class Run:
         self._stamp("weights_made")
         self.pool = traffic_mod.make_pool(config, job, self.seed)
         self._stamp("pool_made")
-        self.iter = self._make_iter(mx, self.pool, job["batch"],
-                                    config["image_size"])
+        self.iter = self._make_iter(mx, self.pool, job["batch"])
         metric = mx.metric.create("ce")
         self._stamp("inputs_made")
         self.module = mod = mx.mod.Module(sym, context=ctx)
         wrap = lambda tree: {self.prefix + k: from_jax(v, ctx=ctx)
                              for k, v in tree.items()}
-        mod.fit(self.iter, num_epoch=2 if window else 1, optimizer="sgd",
-                optimizer_params={"learning_rate": job["lr"],
-                                  "momentum": job["momentum"], "wd": 0.0},
+        optimizer = reference.optimizer(config)
+        mod.fit(self.iter, num_epoch=2 if window else 1,
+                optimizer=optimizer.MXNET,
+                optimizer_params=optimizer.mxnet_params(job),
                 eval_metric=metric, initializer=mx.init.Zero(),
                 arg_params=wrap(params), aux_params=wrap(aux),
                 batch_end_callback=self._on_batch, metric_interval=None,
@@ -194,11 +201,16 @@ class Run:
             return None
         stats = mod._compiled_step.cache_stats()
         window_s = self.t_close - self.iter.t_open
+        samples = self.window_steps * job["batch"]
+        setup_s = self.iter.t_open - self.t_start
         return {
-            "images": self.window_steps * job["batch"],
+            "end_to_end": {"train_images_per_s": samples / window_s,
+                           "setup_s": setup_s},
+            "attempted": self.window_steps, "failed": 0,
+            "samples": samples,
             "steps": self.window_steps, "window_s": window_s,
             "t_open": self.iter.t_open, "t_close": self.t_close,
-            "setup_s": self.iter.t_open - self.t_start,
+            "setup_s": setup_s,
             "window_compiles": self.compiles_at_close["compiles"]
             - self.compiles_at_open["compiles"],
             "step_signatures": stats["misses"],
@@ -209,20 +221,10 @@ class Run:
         }
 
     def readings(self):
-        """What compare.py reads, from the snapshots taken in set-up."""
-        lr = self.cell.traffic["lr"]
-        start = reference.xavier_init(self.cell.config, self.seed)
-        start = jax.device_get({**start[0], **start[1]})
-        norm = lambda v: float(np.sqrt(np.sum(np.square(
-            np.asarray(v, np.float64)))))
-        end = self.snapshots.get("end", {})
-        return {
-            "losses": self.losses,
-            "grad_norms": {k: norm(v) / lr
-                           for k, v in self.snapshots.get("moms", {}).items()},
-            "change_norms": {k: norm(end[k] - start[k])
-                             for k in end if k in start},
-        }
+        """What the comparison reads, from the snapshots taken in set-up."""
+        return reference.program_readings(
+            self.cell.config, self.cell.traffic, self.seed, self.losses,
+            self.snapshots.get("opt", {}), self.snapshots.get("end", {}))
 
     def first_batches(self):
         return self.pool[:COMPARED_STEPS]

@@ -3,8 +3,11 @@ in BENCHMARK.json, the chip check, the compile cache, the compile meter, the
 trace capture, the per-layer readers and the result line.
 
 A cell's window is driven by its entry (``entries/<entry>.py``), named in the
-cell's traffic file; whether its output is correct is decided in
-``compare.py`` against the plain reference of the configuration's family.
+cell's traffic file, which also reports the cell's end-to-end values and how
+many operations it attempted and how many failed; whether its output is
+correct is decided by the comparison the traffic file names
+(``comparisons/<compare>.py``, default ``train_norms``) against the plain
+reference of the configuration's family.
 """
 from __future__ import annotations
 
@@ -160,16 +163,24 @@ def run_cell(cell, seed, seconds, trace, t_start, devices, out=sys.stderr):
     object.  ``devices`` come from find_chips in a real run; a check under
     checks/ hands the CPU's."""
     import jax
-    from benchmark import compare, trace as trace_mod
+    from benchmark import trace as trace_mod
 
     meter = CompileMeter()
     entry = importlib.import_module("benchmark.entries."
                                     + cell.traffic["entry"])
+    comparison = importlib.import_module(
+        "benchmark.comparisons." + cell.traffic.get("compare", "train_norms"))
     trace_dir = os.path.join(os.environ.get("TMPDIR") or
                              os.path.join(HERE, os.pardir, ".bench_tmp"),
                              "bench_trace_%d" % os.getpid()) if trace else None
     run = entry.Run(cell, seed, seconds, devices, meter, t_start, trace_dir)
     window = run.drive()                     # set-up, then the timed window
+    end_to_end = window.pop("end_to_end")
+    if sorted(end_to_end) != sorted(cell.metric_names("end_to_end")):
+        raise BenchmarkError(
+            "entry %s reports the end-to-end metrics %s, the cell has %s"
+            % (cell.traffic["entry"], sorted(end_to_end),
+               sorted(cell.metric_names("end_to_end"))))
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": int(memory_peak(devices))}
@@ -178,18 +189,15 @@ def run_cell(cell, seed, seconds, trace, t_start, devices, out=sys.stderr):
     gc.collect()
 
     t_ref = time.perf_counter()
-    numbers, observed = compare.compare(cell, seed, program,
-                                        run.first_batches())
+    numbers, observed = comparison.compare(cell, seed, program, run)
     window["reference_s"] = time.perf_counter() - t_ref
     correct = all(n["ok"] for n in numbers.values())
 
-    end_to_end = {"train_images_per_s": window["images"] / window["window_s"],
-                  "setup_s": window["setup_s"]}
     context = {"cell": cell, "window": window, "end_to_end": end_to_end,
                "peaks": peaks_of(devices[0]) if devices[0].platform != "cpu"
                else None, "device": device, "trace": None}
-    result = {"correct": bool(correct), "attempted": window["steps"],
-              "failed": 0}
+    result = {"correct": bool(correct), "attempted": int(window["attempted"]),
+              "failed": int(window["failed"])}
     if trace:
         t_read = time.perf_counter()
         summary = trace_mod.load(trace_dir)

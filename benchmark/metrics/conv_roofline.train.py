@@ -2,7 +2,9 @@
 convolution and dense FLOPs of the steps in the window (flops.py, from shapes)
 over peak FLOP/s, divided by the device time of the operations that
 trace.conv_class puts among the convolutions.  Silent where the trace shows
-none: never 0."""
+none: never 0.  For convolutional networks only (BENCHMARK.json lists its
+cells): the TPU compiler roots a fusion of kind ``kOutput`` in a plain matrix
+product too, and all the model's FLOPs are taken for the convolutions'."""
 from benchmark import flops
 from benchmark.trace import conv_class, union_ns
 
@@ -22,6 +24,6 @@ def read(run):
     if not conv_ns:
         return None
     images = len(steps) * run["cell"].traffic["batch"]
-    least_s = flops.train_flops_per_image(run["cell"].config) * images \
+    least_s = flops.train_flops_per_sample(run["cell"]) * images \
         / run["peaks"]["flops_per_s"]
     return 100.0 * least_s / (conv_ns / 1e9)

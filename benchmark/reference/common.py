@@ -8,9 +8,12 @@ network computes in and the matmul precision.  The reference proper is
 float32 at precision ``highest``; the control (checks/) asks for bfloat16.
 
 Training semantics are those of the job the benchmark times, as MXNet
-defines them: mean softmax cross-entropy over the batch, BatchNorm with
-biased batch variance, eps 1e-5 and running statistics folded with momentum
-0.9, SGD with momentum in the form ``m = mu * m - lr * g; w = w + m``.
+defines them: BatchNorm with biased batch variance, eps 1e-5 and running
+statistics folded with momentum 0.9.  Three things are found by name: the
+input (``example_input``) and the loss (``loss_of``) are the family's, an
+image and the mean softmax cross-entropy over the batch where the family says
+nothing; the optimizer is the file under ``optimizers/`` that the
+configuration's ``optimizer`` key names.
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 BN_EPS = 1e-5
@@ -50,6 +54,16 @@ class Ops:
         if bias is not None:
             y = y + bias.astype(self.dtype)
         return y
+
+    def einsum(self, spec, a, b):
+        """Any other product of two operands (an attention score, an expert's
+        matrix), by its subscripts; no ellipsis, so that flops.py can count
+        its multiply-accumulates from the shapes."""
+        if "." in spec or spec.count(",") != 1:
+            raise ValueError("einsum of two operands with every axis "
+                             "lettered, not %r" % spec)
+        return jnp.einsum(spec, a.astype(self.dtype), b.astype(self.dtype),
+                          precision=self.precision)
 
     def batch_norm(self, x, gamma, beta, running, train):
         """Returns (y, (new running mean, new running variance))."""
@@ -93,6 +107,58 @@ def family(config):
     """The reference module of the configuration's family."""
     return importlib.import_module(
         "benchmark.reference." + config["reference"])
+
+
+def optimizer(config):
+    """The reference module of the configuration's optimizer."""
+    return importlib.import_module(
+        "benchmark.reference.optimizers." + config["optimizer"])
+
+
+def example_input(config, traffic):
+    """What one sample's forward pass takes after ``aux``, as
+    ``ShapeDtypeStruct``s with a batch of 1: the family's
+    ``example_input(config, traffic)`` (the traffic file has a sequence's
+    length), else one image."""
+    module = family(config)
+    if hasattr(module, "example_input"):
+        return tuple(module.example_input(config, traffic))
+    size = config["image_size"]
+    return (jax.ShapeDtypeStruct((1, 3, size, size), jnp.float32),)
+
+
+def loss_of(config):
+    """``loss(ops, params, aux, batch) -> (loss, new aux)`` of the
+    configuration's family, ``batch`` a tuple passed whole: the family's
+    ``loss(config, ops, params, aux, batch)``, else the classifier's mean
+    softmax cross-entropy on ``batch = (x, y)``."""
+    module = family(config)
+    if hasattr(module, "loss"):
+        return functools.partial(module.loss, config)
+    forward = functools.partial(module.forward, config)
+    return lambda ops, params, aux, batch: loss_and_logits(
+        ops, forward, params, aux, *batch)
+
+
+def host_norm(v):
+    """The norm of a host array, summed in float64."""
+    return float(np.sqrt(np.sum(np.square(np.asarray(v, np.float64)))))
+
+
+def program_readings(config, hyper, seed, losses, opt_state, end):
+    """What the comparison reads of the program, from an entry's snapshots:
+    each compared step's loss, the program's optimizer state after its first
+    step (``opt_state[leaf]``: the arrays in the program's order) and its
+    parameters and statistics after the last compared step (``end``), all on
+    the host and keyed as ``param_shapes`` names them."""
+    start = xavier_init(config, seed)
+    start = jax.device_get({**start[0], **start[1]})
+    return {
+        "losses": losses,
+        "grad_norms": optimizer(config).first_gradient_norms(hyper, opt_state),
+        "change_norms": {k: host_norm(end[k] - start[k])
+                         for k in end if k in start},
+    }
 
 
 def xavier_init(config, seed):
@@ -143,50 +209,45 @@ def leaf_norms(tree):
             for k, v in tree.items()}
 
 
-def make_train_step(config, lr, momentum, dtype=jnp.float32,
-                    precision="highest", keep=None):
-    """One jitted SGD-momentum step of the family's network:
-    ``step(params, moms, aux, x, y) -> (params, moms, aux, loss, norms of the
-    gradient's leaves)``.  The update is made in the dtype the weights are
-    held in (float32 for the reference; the caller of a bfloat16 control
-    decides whether it keeps float32 master weights).  ``keep`` (a slice)
-    plants the half-batch fault: the step sees only those rows of the batch
-    and takes its mean over them."""
-    forward = functools.partial(family(config).forward, config)
+def make_train_step(config, hyper, dtype=jnp.float32, precision="highest",
+                    keep=None):
+    """One jitted step of the family's network under the configuration's
+    optimizer: ``step(params, state, aux, batch) -> (params, state, aux,
+    loss, norms of the gradient's leaves)``.  ``hyper`` is the cell's traffic
+    file, which holds the optimizer's parameters.  ``keep`` (a slice) plants
+    the half-batch fault: the step sees only those rows of every array of the
+    batch and takes its mean over them."""
+    loss_fn, update = loss_of(config), optimizer(config).update
     ops = Ops(dtype, precision)
 
-    def step(params, moms, aux, x, y):
+    def step(params, state, aux, batch):
         if keep is not None:
-            x, y = x[keep], y[keep]
+            batch = tuple(a[keep] for a in batch)
         (loss, new_aux), grads = jax.value_and_grad(
-            lambda p: loss_and_logits(ops, forward, p, aux, x, y),
-            has_aux=True)(params)
-        moms = {k: (momentum * moms[k] - lr * grads[k].astype(moms[k].dtype))
-                for k in params}
-        params = {k: params[k] + moms[k] for k in params}
-        return params, moms, new_aux, loss, leaf_norms(grads)
+            lambda p: loss_fn(ops, p, aux, batch), has_aux=True)(params)
+        params, state = update(hyper, params, state, grads)
+        return params, state, new_aux, loss, leaf_norms(grads)
 
     return jax.jit(step, donate_argnums=(0, 1, 2))
 
 
-def train_readings(config, seed, batches, lr, momentum, state_dtype=None,
-                   **variant):
+def train_readings(config, seed, batches, hyper, state_dtype=None, **variant):
     """Drive ``len(batches)`` reference steps from the seed's weights and
     return what the comparison reads: each step's loss, the norm of each
     leaf of the first gradient, and the norm of each parameter's and each
     BatchNorm statistic's change over all the steps, from the seed's float32
-    weights.  ``state_dtype`` holds the weights and momenta in another dtype
-    (the control without float32 master weights)."""
+    weights.  ``state_dtype`` holds the weights and the optimizer's state in
+    another dtype (the control without float32 master weights)."""
     params, aux = xavier_init(config, seed)
     start = {k: jnp.copy(v) for k, v in {**params, **aux}.items()}
     if state_dtype is not None:
         params = {k: v.astype(state_dtype) for k, v in params.items()}
-    moms = {k: jnp.zeros_like(v) for k, v in params.items()}
-    step = make_train_step(config, lr, momentum, **variant)
+    state = optimizer(config).init(params)
+    step = make_train_step(config, hyper, **variant)
     losses, grad_norms = [], None
-    for x, y in batches:
-        params, moms, aux, loss, norms = step(params, moms, aux,
-                                              jnp.asarray(x), jnp.asarray(y))
+    for batch in batches:
+        params, state, aux, loss, norms = step(
+            params, state, aux, tuple(jnp.asarray(a) for a in batch))
         losses.append(loss)
         if grad_norms is None:
             grad_norms = norms

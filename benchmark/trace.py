@@ -210,6 +210,16 @@ def clip(events, lo, hi):
     return out
 
 
+def start(trace_dir):
+    """Start the profiler on ``trace_dir`` as an entry does inside its
+    window: the harness's and the program's spans, not every Python call."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+
+
 def xplane_path(trace_dir):
     found = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
