@@ -28,15 +28,19 @@ CELL = "tiny_resnet.fit_b16"
 SEED = 2345678901
 
 
-@pytest.fixture(scope="module")
-def cell():
+def load_cell(name):
     import sys
     sys.path.insert(0, ROOT)
     from benchmark import harness
     harness.configure_jax(os.environ.get("TMPDIR") or
                           os.path.join(ROOT, ".bench_tmp"))
-    return harness.Cell(CELL, ROOT, spec=harness.load_json(CELLS, "spec.json"),
+    return harness.Cell(name, ROOT, spec=harness.load_json(CELLS, "spec.json"),
                         base=CELLS)
+
+
+@pytest.fixture(scope="module")
+def cell():
+    return load_cell(CELL)
 
 
 def run(cell):
@@ -49,16 +53,19 @@ def run(cell):
 
 
 @contextlib.contextmanager
-def broken_step(fault):
-    """Plant ``fault`` under the timed path: in the program's compiled step."""
+def broken_step(fault, summed_loss=True):
+    """Plant ``fault`` under the timed path: in the program's compiled step.
+    ``summed_loss``: the step's loss is a sum that the optimizer divides by
+    the batch (a Module's), not a mean of its own (a block's)."""
+    import mxnet_tpu as mx
     from mxnet_tpu.module.compiled_step import CompiledTrainStep
     sound = CompiledTrainStep.run_window
 
-    def unchanged(self, batches_io):
-        return None                       # the state stays as it was
+    def unchanged(self, batches_io):      # a loss comes back, no state moves
+        return mx.nd.zeros((len(batches_io),))
 
     def half_batch(self, batches_io):
-        if not getattr(self, "_bench_fault", False):
+        if summed_loss and not getattr(self, "_bench_fault", False):
             self._bench_fault = True
             self._optimizer.rescale_grad *= 2     # the mean over the rest
         cut = [tuple(x[:x.shape[0] // 2] for x in b) for b in batches_io]

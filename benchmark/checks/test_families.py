@@ -18,9 +18,7 @@ the look for a chip is skipped and the rest of a run is driven:
 * ``step_mfu.train``'s FLOPs equal a count written out by hand;
 * an entry whose end-to-end names are not the cell's is refused.
 """
-import contextlib
 import io
-import os
 import time
 
 import pytest
@@ -32,36 +30,7 @@ CELL = "tiny_seq.seq_b8"
 
 @pytest.fixture(scope="module")
 def cell():
-    import sys
-    sys.path.insert(0, shared.ROOT)
-    from benchmark import harness
-    harness.configure_jax(os.environ.get("TMPDIR") or
-                          os.path.join(shared.ROOT, ".bench_tmp"))
-    return harness.Cell(CELL, shared.ROOT,
-                        spec=harness.load_json(shared.CELLS, "spec.json"),
-                        base=shared.CELLS)
-
-
-@contextlib.contextmanager
-def broken_step(fault):
-    """Plant ``fault`` under the timed path: in the program's compiled step."""
-    import mxnet_tpu as mx
-    from mxnet_tpu.module.compiled_step import CompiledTrainStep
-    sound = CompiledTrainStep.run_window
-
-    def unchanged(self, batches_io):      # a loss comes back, no state moves
-        return mx.nd.zeros((len(batches_io),))
-
-    def half_batch(self, batches_io):     # the masked mean is over the rest
-        return sound(self, [tuple(x[:x.shape[0] // 2] for x in b)
-                            for b in batches_io])
-
-    CompiledTrainStep.run_window = {"state_unchanged": unchanged,
-                                    "half_batch": half_batch}[fault]
-    try:
-        yield
-    finally:
-        CompiledTrainStep.run_window = sound
+    return shared.load_cell(CELL)
 
 
 def test_sound_run_is_correct(cell):
@@ -111,7 +80,7 @@ def test_control_in_bfloat16_fails(cell):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
 def test_broken_step_is_not_correct(cell, fault):
-    with broken_step(fault):
+    with shared.broken_step(fault, summed_loss=False):
         result, log = shared.run(cell)
     assert not result["correct"], log
     assert "FAILED" in log

@@ -1,5 +1,5 @@
-"""The comparison that decides ``correct`` for a training cell: ``train_norms``,
-the one a traffic file gets that names no other under ``compare``.
+"""Comparison ``train_norms``: what decides ``correct`` for a training cell,
+and the one a traffic file gets that names no other under ``compare``.
 
 The program's readings come from the object the window then drives: the loss
 of each of its first steps, the first gradient as the optimizer got it (from

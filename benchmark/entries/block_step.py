@@ -31,7 +31,8 @@ import jax
 
 from benchmark import trace as trace_mod
 from benchmark import traffic as traffic_mod
-from benchmark.entries.module_fit import COMPARED_STEPS, fetch_state
+from benchmark.entries.module_fit import (COMPARED_STEPS, fetch_state,
+                                          training_window)
 from benchmark.harness import BenchmarkError
 from benchmark.reference import common as reference
 
@@ -52,6 +53,9 @@ class Run:
 
     def _step(self, i):
         return self.cstep.step(*self.staged[i % len(self.staged)])
+
+    def _wait(self):
+        jax.block_until_ready([v._data for v in self.cstep.state.values()])
 
     def drive(self, window=True):
         """Set-up, then the window unless ``window`` is false: the readings
@@ -94,8 +98,7 @@ class Run:
                 self.snapshots["opt"] = fetch_state(cstep, "o:", self.prefix)
             if i + 1 == COMPARED_STEPS:
                 self.snapshots["end"] = fetch_state(cstep, "p:", self.prefix)
-        state = [v._data for v in cstep.state.values()]
-        jax.block_until_ready(state)
+        self._wait()
         self._stamp("warmed_up")
         if not window:
             return None
@@ -117,7 +120,7 @@ class Run:
                 trace_mod.start(self.trace_dir)
             out = self._step(job["warmup_steps"] + steps)
             steps += 1
-        jax.block_until_ready([v._data for v in cstep.state.values()])
+        self._wait()
         t_close = time.perf_counter()
         with annotate("bench:window_close"):
             pass
@@ -127,23 +130,12 @@ class Run:
             t_stop = time.perf_counter()
             jax.profiler.stop_trace()
             trace_stop_s = time.perf_counter() - t_stop
-        window_s = t_close - t_open
-        samples = steps * job["batch"]
-        setup_s = t_open - self.t_start
-        return {
-            "end_to_end": {"train_images_per_s": samples / window_s,
-                           "setup_s": setup_s},
-            "attempted": steps, "failed": 0, "samples": samples,
-            "steps": steps, "window_s": window_s,
-            "t_open": t_open, "t_close": t_close, "setup_s": setup_s,
-            "window_compiles": compiles_at_close["compiles"]
-            - compiles_at_open["compiles"],
-            "step_signatures": cstep.cache_stats()["misses"],
-            "final_loss": float(out.asnumpy()[-1]),
-            "trace_stop_s": trace_stop_s,
-            **self.stamps,
-            **{"setup_" + k: v for k, v in compiles_at_open.items()},
-        }
+        return training_window(
+            steps, job["batch"], self.t_start, t_open, t_close,
+            compiles_at_open, compiles_at_close,
+            step_signatures=cstep.cache_stats()["misses"],
+            final_loss=float(out.asnumpy()[-1]),
+            trace_stop_s=trace_stop_s, **self.stamps)
 
     def readings(self):
         """What the comparison reads, from the snapshots taken in set-up."""

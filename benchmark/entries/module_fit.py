@@ -4,9 +4,9 @@ The zoo network of the configuration is bound as a ``Module`` and trained
 through ``fit()`` -> ``CompiledTrainStep`` with a ``DeviceFeed``
 (``prefetch_to_device``), the configuration's optimizer (as its file under
 ``reference/optimizers/`` names it to the program), the cross-entropy metric,
-over this file's own ``DataIter``.  It cycles the seed's pool of batches from host
-memory: epoch 0 is the warm-up (``warmup_steps`` steps, the first three of
-which the comparison reads), epoch 1 is the window and ends on the clock.
+over this file's own ``DataIter``.  It cycles the seed's pool of batches from
+host memory: epoch 0 is the warm-up (``warmup_steps`` steps, the first three
+of which the comparison reads), epoch 1 is the window and ends on the clock.
 The run fails if ``fit()`` fell back to the eager loop.  The entry reports
 ``train_images_per_s`` (samples, that is rows of a batch, completed per second
 of the window) and ``setup_s``; a step cannot fail short of the run.
@@ -46,6 +46,26 @@ def fetch_state(cstep, kind, prefix):
                           key=lambda ni: (ni[0], int(ni[1]))):
         found.setdefault(name[skip:], []).append(values[name + ":" + i])
     return found
+
+
+def training_window(steps, batch, t_start, t_open, t_close, compiles_at_open,
+                    compiles_at_close, **counters):
+    """The window a training entry's ``drive()`` returns: the end-to-end
+    values (all samples of all steps over all the window's seconds; process
+    start to the window's opening), the counts of operations, and the
+    counters the per-layer readers and the log take."""
+    window_s, setup_s = t_close - t_open, t_open - t_start
+    return {
+        "end_to_end": {"train_images_per_s": steps * batch / window_s,
+                       "setup_s": setup_s},
+        "attempted": steps, "failed": 0, "samples": steps * batch,
+        "steps": steps, "window_s": window_s,
+        "t_open": t_open, "t_close": t_close, "setup_s": setup_s,
+        "window_compiles": compiles_at_close["compiles"]
+        - compiles_at_open["compiles"],
+        **counters,
+        **{"setup_" + k: v for k, v in compiles_at_open.items()},
+    }
 
 
 def build_network(config):
@@ -199,26 +219,12 @@ class Run:
             raise BenchmarkError("fit() fell back to the eager loop")
         if not window:
             return None
-        stats = mod._compiled_step.cache_stats()
-        window_s = self.t_close - self.iter.t_open
-        samples = self.window_steps * job["batch"]
-        setup_s = self.iter.t_open - self.t_start
-        return {
-            "end_to_end": {"train_images_per_s": samples / window_s,
-                           "setup_s": setup_s},
-            "attempted": self.window_steps, "failed": 0,
-            "samples": samples,
-            "steps": self.window_steps, "window_s": window_s,
-            "t_open": self.iter.t_open, "t_close": self.t_close,
-            "setup_s": setup_s,
-            "window_compiles": self.compiles_at_close["compiles"]
-            - self.compiles_at_open["compiles"],
-            "step_signatures": stats["misses"],
-            "final_loss": float(metric.get_name_value()[0][1]),
-            "trace_stop_s": trace_stop_s,
-            **self.stamps,
-            **{"setup_" + k: v for k, v in self.compiles_at_open.items()},
-        }
+        return training_window(
+            self.window_steps, job["batch"], self.t_start, self.iter.t_open,
+            self.t_close, self.compiles_at_open, self.compiles_at_close,
+            step_signatures=mod._compiled_step.cache_stats()["misses"],
+            final_loss=float(metric.get_name_value()[0][1]),
+            trace_stop_s=trace_stop_s, **self.stamps)
 
     def readings(self):
         """What the comparison reads, from the snapshots taken in set-up."""

@@ -2,12 +2,12 @@
 
 The family's own reference forward pass is walked once under
 ``jax.eval_shape``, over one sample of the cell's traffic (the family's
-``example_input``, else one image), with an ``Ops`` that counts every product the reference makes through
-it: a convolution or a dense layer adds its multiply-accumulates (output
-elements x kernel area x input channels per group), an ``einsum`` the product
-of the sizes of all its subscripts.  Nothing is taken from a compiled program
-or a trace, so the count is the same whatever implements the layers, and
-nothing that a compiler recomputes is counted.
+``example_input``, else one image), with an ``Ops`` that counts every product
+the reference makes through it: a convolution or a dense layer adds its
+multiply-accumulates (output elements x kernel area x input channels per
+group), an ``einsum`` the product of the sizes of all its subscripts.  Nothing
+is taken from a compiled program or a trace, so the count is the same whatever
+implements the layers, and nothing that a compiler recomputes is counted.
 
 Convention: one multiply-accumulate is 2 FLOPs, and a training step costs 3
 times the forward pass (forward, the gradient of the input, the gradient of

@@ -3,9 +3,9 @@ in BENCHMARK.json, the chip check, the compile cache, the compile meter, the
 trace capture, the per-layer readers and the result line.
 
 A cell's window is driven by its entry (``entries/<entry>.py``), named in the
-cell's traffic file, which also reports the cell's end-to-end values and how
-many operations it attempted and how many failed; whether its output is
-correct is decided by the comparison the traffic file names
+cell's traffic file; the entry also reports the cell's end-to-end values and
+how many operations the window attempted and how many failed.  Whether its
+output is correct is decided by the comparison the traffic file names
 (``comparisons/<compare>.py``, default ``train_norms``) against the plain
 reference of the configuration's family.
 """
@@ -175,12 +175,12 @@ def run_cell(cell, seed, seconds, trace, t_start, devices, out=sys.stderr):
                              "bench_trace_%d" % os.getpid()) if trace else None
     run = entry.Run(cell, seed, seconds, devices, meter, t_start, trace_dir)
     window = run.drive()                     # set-up, then the timed window
-    end_to_end = window.pop("end_to_end")
-    if sorted(end_to_end) != sorted(cell.metric_names("end_to_end")):
+    end_to_end, names = window.pop("end_to_end"), \
+        cell.metric_names("end_to_end")
+    if sorted(end_to_end) != sorted(names):
         raise BenchmarkError(
             "entry %s reports the end-to-end metrics %s, the cell has %s"
-            % (cell.traffic["entry"], sorted(end_to_end),
-               sorted(cell.metric_names("end_to_end"))))
+            % (cell.traffic["entry"], sorted(end_to_end), sorted(names)))
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": int(memory_peak(devices))}
@@ -218,8 +218,7 @@ def run_cell(cell, seed, seconds, trace, t_start, devices, out=sys.stderr):
                                  "unit": cell.unit(name)}
     else:
         metrics = {name: {"value": float(end_to_end[name]),
-                          "unit": cell.unit(name)}
-                   for name in cell.metric_names("end_to_end")}
+                          "unit": cell.unit(name)} for name in names}
     result["metrics"] = metrics
     result["device"] = device
     result["window"] = {k: v for k, v in window.items()

@@ -153,12 +153,10 @@ def program_readings(config, hyper, seed, losses, opt_state, end):
     the host and keyed as ``param_shapes`` names them."""
     start = xavier_init(config, seed)
     start = jax.device_get({**start[0], **start[1]})
-    return {
-        "losses": losses,
-        "grad_norms": optimizer(config).first_gradient_norms(hyper, opt_state),
-        "change_norms": {k: host_norm(end[k] - start[k])
-                         for k in end if k in start},
-    }
+    grad_norms = optimizer(config).first_gradient_norms(hyper, opt_state)
+    return {"losses": losses, "grad_norms": grad_norms,
+            "change_norms": {k: host_norm(end[k] - start[k])
+                             for k in end if k in start}}
 
 
 def xavier_init(config, seed):
@@ -231,7 +229,8 @@ def make_train_step(config, hyper, dtype=jnp.float32, precision="highest",
     return jax.jit(step, donate_argnums=(0, 1, 2))
 
 
-def train_readings(config, seed, batches, hyper, state_dtype=None, **variant):
+def train_readings(config, seed, batches, hyper, state_dtype=None,
+                   **variant):
     """Drive ``len(batches)`` reference steps from the seed's weights and
     return what the comparison reads: each step's loss, the norm of each
     leaf of the first gradient, and the norm of each parameter's and each
