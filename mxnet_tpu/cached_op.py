@@ -33,6 +33,33 @@ from .base import MXNetError
 __all__ = ["CachedOp"]
 
 
+def remat_policy(flags):
+    """(whether to recompute, the jax.checkpoint policy) that hybridize
+    flags ask for: ``remat`` (else MXNET_BACKWARD_DO_MIRROR) and
+    ``remat_policy`` (else MXNET_REMAT_POLICY), a jax.checkpoint_policies
+    name or 'full'.  Shared by the CachedOp and by a hybridized child block
+    that is called inside someone else's trace (gluon/block.py)."""
+    from . import env
+    remat = flags.get("remat")
+    if remat is None:
+        remat = env.get("MXNET_BACKWARD_DO_MIRROR")
+    if not remat:
+        return False, None
+    import jax
+    policy_name = flags.get("remat_policy")
+    if policy_name is None:
+        policy_name = env.get("MXNET_REMAT_POLICY")
+    policy = None
+    if policy_name and policy_name != "full":
+        try:
+            policy = getattr(jax.checkpoint_policies, policy_name)
+        except AttributeError:
+            raise MXNetError(
+                "unknown remat policy %r; see jax.checkpoint_policies"
+                % (policy_name,))
+    return True, policy
+
+
 class CachedOp:
     def __init__(self, forward_fn, param_dict, aux_names=(), flags=None,
                  name="traced"):
@@ -152,25 +179,11 @@ class CachedOp:
         the forward instead of saving residuals, with an optional named
         policy from jax.checkpoint_policies selecting what is still saved
         (e.g. "dots_saveable" keeps matmul outputs, recomputes the rest)."""
-        import jax
-        from . import env
         traced = self._make_traced(training)
-        remat = self._flags.get("remat")
-        if remat is None:
-            remat = env.get("MXNET_BACKWARD_DO_MIRROR")
+        remat, policy = remat_policy(self._flags)
         if not remat:
             return traced
-        policy_name = self._flags.get("remat_policy")
-        if policy_name is None:
-            policy_name = env.get("MXNET_REMAT_POLICY")
-        policy = None
-        if policy_name and policy_name != "full":
-            try:
-                policy = getattr(jax.checkpoint_policies, policy_name)
-            except AttributeError:
-                raise MXNetError(
-                    "unknown remat policy %r; see jax.checkpoint_policies"
-                    % (policy_name,))
+        import jax
         return jax.checkpoint(traced, policy=policy)
 
     def _get_jitted(self, training):

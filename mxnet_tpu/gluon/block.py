@@ -307,6 +307,16 @@ def _indent(s_, num_spaces):
     return "\n".join([first] + lines)
 
 
+_REMAT_REGION = threading.local()
+
+
+def _being_traced(args):
+    """Whether a block's inputs are values of an enclosing jax trace."""
+    import jax
+    return any(isinstance(getattr(a, "_data", None), jax.core.Tracer)
+               for a in args)
+
+
 class HybridBlock(Block):
     """Block with a compile-on-demand forward (reference block.py:673)."""
 
@@ -384,12 +394,56 @@ class HybridBlock(Block):
             # (reference HybridBlock.__call__ dispatches on input type)
             out = self._build_symbol(*args)
         elif self._active and not self._in_hybrid_forward:
-            out = self._call_cached_op(*args)
+            out = (self._call_traced(*args) if _being_traced(args)
+                   else self._call_cached_op(*args))
         else:
             out = self.forward(*args)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out
+
+    def _call_traced(self, *args):
+        """A hybridized block called inside someone else's trace
+        (``functional_call`` under a compiled train step, a parent's
+        CachedOp): the outer program compiles it, so no CachedOp of its
+        own; what still holds is ``hybridize(remat=...)``: the block's
+        forward is wrapped in ``jax.checkpoint`` (policy as for a CachedOp),
+        so that the backward pass recomputes it from its inputs and
+        parameters instead of keeping its activations.  The outermost such
+        block decides: inside a recomputed region nothing is wrapped
+        again."""
+        from ..cached_op import remat_policy
+        remat, policy = remat_policy(self._flags)
+        if not remat or getattr(_REMAT_REGION, "inside", False):
+            return self.forward(*args)
+        import jax
+        params = {p.name: p for p in self.collect_params().values()}
+        names = sorted(params)
+        outer = {n: params[n].data() for n in names}
+        aux_names = [n for n in names if params[n].grad_req == "null"]
+        tree = []
+
+        def pure(param_vals, input_vals):
+            nds = {n: NDArray(v) for n, v in zip(names, param_vals)}
+            _REMAT_REGION.inside = True
+            try:
+                out = _with_param_override(
+                    self, params, nds,
+                    lambda: self.forward(*[NDArray(v) for v in input_vals]))
+            finally:
+                _REMAT_REGION.inside = False
+            tree.append(isinstance(out, (list, tuple)))
+            outs = list(out) if tree[-1] else [out]
+            return (tuple(o._data for o in outs),
+                    tuple(nds[n]._data for n in aux_names))
+
+        outs, aux = jax.checkpoint(pure, policy=policy)(
+            tuple(outer[n]._data for n in names),
+            tuple(a._data for a in args))
+        for n, v in zip(aux_names, aux):
+            outer[n]._set_data(v)
+        outs = [NDArray(o) for o in outs]
+        return outs if tree[-1] else outs[0]
 
     def hybrid_call(self, *args):
         """Run the eager (unhybridized) forward regardless of _active."""

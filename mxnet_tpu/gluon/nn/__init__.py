@@ -8,3 +8,5 @@ from .conv_layers import (Conv1D, Conv2D, Conv3D, Conv1DTranspose,
                           GlobalMaxPool1D, GlobalMaxPool2D, GlobalMaxPool3D,
                           GlobalAvgPool1D, GlobalAvgPool2D, GlobalAvgPool3D,
                           ReflectionPad2D, channels_last)
+from .decoder_layers import (RMSNorm, BlockDiffusionAttention, HeldExpertsMoE,
+                             BlockDiffusionDecoderLayer)

@@ -15,5 +15,6 @@ from . import linalg_ops      # noqa: F401
 from . import contrib_ops     # noqa: F401
 from . import quantization_ops  # noqa: F401
 from . import pallas_ops      # noqa: F401
+from . import decoder_ops     # noqa: F401
 from . import sparse_ops      # noqa: F401
 from . import misc_ops       # noqa: F401

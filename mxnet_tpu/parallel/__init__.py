@@ -30,6 +30,7 @@ from .zero import (init_shard_update_state, make_sharded_update_step,
 from .ring_attention import ring_attention, sequence_parallel_attention
 from .pipeline import pipeline_apply, make_pipeline_step
 from .ulysses import ulysses_attention_local, ulysses_parallel_attention
-from .moe import moe_apply, make_expert_parallel_moe
+from .moe import (moe_apply, make_expert_parallel_moe, moe_held_apply,
+                  route_top_k)
 from .checkpoint import (save_sharded, restore_sharded,
                          SlicedCheckpointManager)

@@ -1,4 +1,20 @@
-"""Expert parallelism: a mixture-of-experts layer sharded over an 'ep' axis.
+"""Expert parallelism: mixture-of-experts layers for an 'ep' axis.
+
+Two layers share the router's top-k and its renormalisation
+(``route_top_k``):
+
+* ``moe_apply`` (below, first): the capacity layer.  Static shapes from a
+  fixed per-expert capacity, tokens over capacity dropped, the exchange by
+  ``all_to_all`` inside ``shard_map``.
+* ``moe_held_apply`` (end of the file): the dropless layer for the experts
+  one chip holds.  The router scores all experts; every held expert is
+  computed for every token and weighted by the router's weight for that
+  token, 0 where the token did not choose it: nothing to drop, whatever the
+  load.  What the experts held elsewhere would add is their chips' to
+  compute; on one chip the layer runs with no exchange and nothing stands in
+  for the absent chips.
+
+The capacity layer:
 
 The reference's closest capability is row-sparse embedding sharding across
 parameter servers (SURVEY §2.5.6); it has no MoE.  This module supplies the
@@ -22,6 +38,17 @@ from __future__ import annotations
 import functools
 
 
+def route_top_k(probs, k):
+    """The router's choice, shared by both layers: the ``k`` largest of each
+    token's probabilities ``(T, E)`` with their weights renormalised to sum
+    to 1 (Switch/GShard; ``norm_topk_prob``).  Returns (weights, expert ids),
+    each ``(T, k)``."""
+    import jax
+    import jax.numpy as jnp
+    vals, idx = jax.lax.top_k(probs, k)
+    return vals / jnp.sum(vals, axis=-1, keepdims=True), idx
+
+
 def _one_hot_dispatch(gates, k, capacity):
     """Build dispatch/combine tensors from gate probs (T, E).
 
@@ -31,9 +58,7 @@ def _one_hot_dispatch(gates, k, capacity):
     import jax.numpy as jnp
 
     T, E = gates.shape
-    topk_vals, topk_idx = jax.lax.top_k(gates, k)        # (T, k)
-    # renormalize the selected gates (Switch/GShard convention)
-    topk_vals = topk_vals / jnp.sum(topk_vals, axis=-1, keepdims=True)
+    topk_vals, topk_idx = route_top_k(gates, k)          # (T, k)
 
     dispatch = jnp.zeros((T, E, capacity), dtype=gates.dtype)
     combine = jnp.zeros((T, E, capacity), dtype=gates.dtype)
@@ -134,3 +159,68 @@ def make_expert_parallel_moe(mesh, expert_fn, axis_name="ep", k=2,
         return fn(expert_params, gate_w, x)
 
     return jax.jit(run)
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer for the experts held here
+# ---------------------------------------------------------------------------
+
+def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0):
+    """The held experts' part of a mixture-of-experts layer, no token
+    dropped.
+
+    x: (T, d) tokens.  router_w: (E, d), the router over ALL experts.
+    gate_w, up_w: (E_held, f, d); down_w: (E_held, d, f): the matrices of
+    experts ``first_expert .. first_expert + E_held - 1``, which live here.
+    Expert e computes ``down(silu(gate y) * up y)``.
+
+    Every held expert's hidden units are computed for every token, as one
+    feed-forward of width ``E_held * f`` (three plain products on the MXU),
+    and a token's hidden units of an expert it did not choose are multiplied
+    by 0, of one it chose by the router's weight.  The work is the same
+    whatever the router does: no sort, no gather or scatter, no buffer that
+    a load can overflow, and a step's time does not depend on the data.
+    That is ``E / k`` times the products an even load needs (16 times with 8
+    of 128 experts per token), so it is the slower form wherever the load is
+    near even: sorted pairs through grouped products were probed at 2.8 ms a
+    layer against this form's 40 at 8,192 tokens on one v5e (PERF.md, PR 29).
+    It is here because a load-following layer's time follows the router, and
+    from seeded weights the router collapses (one expert takes most rows, by
+    the seed), where this form's time is the same for every seed; PERF.md §7
+    has what has to change before the sorted form can be measured.
+
+    Returns ``(out, load)``: ``out`` (T, d) is
+    ``sum_e w_e expert_e(x)`` over each token's chosen experts that are held
+    here (``w`` the renormalised top-k weights of the float32 softmax over
+    all E); ``load`` is float32 ``[pairs routed here, the largest held
+    expert's load]``."""
+    import jax
+    import jax.numpy as jnp
+    from .. import profiler
+
+    T, d = x.shape
+    E, (held, f, _) = router_w.shape[0], gate_w.shape
+    profiler.count("moe.layers")
+    profiler.count("moe.experts_held", held)
+    profiler.count("moe.experts_total", E)
+
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.T.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        weights, experts = route_top_k(jax.nn.softmax(logits, axis=-1), k)
+        # (T, held): the weight of each held expert for each token, 0 where
+        # the token did not choose it
+        chosen = (experts - first_expert)[:, :, None] == jnp.arange(held)
+        gates = jnp.sum(jnp.where(chosen, weights[:, :, None], 0.0), axis=1)
+        per_expert = jnp.sum(chosen, axis=(0, 1))
+        load = jnp.stack([jnp.sum(per_expert),
+                          jnp.max(per_expert)]).astype(jnp.float32)
+
+    with jax.named_scope("moe.experts"):
+        gate = jnp.dot(x, gate_w.reshape(held * f, d).T)       # (T, held*f)
+        up = jnp.dot(x, up_w.reshape(held * f, d).T)
+        hidden = (jax.nn.silu(gate) * up).reshape(T, held, f)
+    with jax.named_scope("moe.combine"):
+        hidden = hidden * gates.astype(x.dtype)[:, :, None]
+        return jnp.einsum("tef,edf->td", hidden, down_w), load
+

@@ -218,9 +218,22 @@ def count(name, delta=1):
             rec[3] = delta
 
 
+_gauges = {}
+
+
+def gauge(name, read):
+    """Register ``read() -> (count, max)`` under ``name``: a value that lives
+    elsewhere (state on the device, written by the compiled step) and is
+    fetched only when ``totals()`` is asked, never on the timed path.  A
+    later registration under the same name replaces the earlier one; a
+    ``read`` that raises leaves its name out."""
+    with _lock:
+        _gauges[name] = read
+
+
 def totals():
-    """``{name: {"count", "wall_ns", "cpu_ns", "max"}}`` of every span and
-    counter since the process started (or ``reset_spans()``), over all
+    """``{name: {"count", "wall_ns", "cpu_ns", "max"}}`` of every span,
+    counter and gauge since the process started (or ``reset_spans()``), over all
     threads; ``max`` is a span's longest wall time in ns, and ``cpu_ns`` is
     0 for a name whose spans read no CPU clock.  A span name under
     which something compiled also has ``compile.count``, ``compile.ns``,
@@ -240,6 +253,14 @@ def totals():
                               "max": 0}).update(
             {"compile.count": r[0], "compile.ns": r[1],
              "compile.cache_hits": r[2], "compile.cache_misses": r[3]})
+    with _lock:
+        gauges = list(_gauges.items())
+    for name, read in gauges:
+        try:
+            count_, max_ = read()
+        except Exception:       # its state is gone: nothing to report
+            continue
+        out[name] = {"count": count_, "wall_ns": 0, "cpu_ns": 0, "max": max_}
     return out
 
 
@@ -252,9 +273,10 @@ def spans(since_ns=None):
 
 
 def reset_spans():
-    """Forget every span, total and compile charge (for tests)."""
+    """Forget every span, total, gauge and compile charge (for tests)."""
     with _lock:
         _ring.clear()
+        _gauges.clear()
         for table in _retired:
             table.clear()
         for _, thread_totals, thread_compiles in _tables:

@@ -144,3 +144,42 @@ def test_sharded_decode_step_compiles_for_four_chips(topo, monkeypatch):
     weights = 4 * sum(int(np.prod(v)) for v in shapes.values())
     pools = 2 * 4 * int(np.prod(POOL))
     assert per_device < (weights + 2 * pools) / 4 * 1.25
+
+
+# -- the block-diffusion cell's kernels at its published widths --------------
+
+def test_block_mask_attention_compiles_for_v5e_forward_and_backward(one_chip):
+    """32 query heads to 4 of 128 over 8,192 [noised; clean] rows, float32
+    in: the forward kernel and both backward kernels, tiles of 512 x 512."""
+    from mxnet_tpu.ops.pallas_ops import block_mask_attention
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.float32,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(block_mask_attention(
+            q, k, v, 4096, 4, interpret=False)), (0, 1, 2))
+    ).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    for kernel in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
+        assert kernel in text
+    _fits(compiled)
+
+
+def test_held_experts_layer_compiles_for_v5e(one_chip):
+    """16 of 128 experts of 768 x 2048, 8 per token, 8,192 tokens, forward
+    and backward: three plain products over all 16 experts' hidden units."""
+    from mxnet_tpu.parallel.moe import moe_held_apply
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(x, router_w, gate_w, up_w, down_w):
+        out, load = moe_held_apply(x, router_w, gate_w, up_w, down_w, 8)
+        return jnp.sum(out) + load[1]
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        s(8192, 2048), s(128, 2048), s(16, 768, 2048), s(16, 768, 2048),
+        s(16, 2048, 768)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    _fits(compiled)
