@@ -36,9 +36,12 @@ __all__ = ["CachedOp"]
 def remat_policy(flags):
     """(whether to recompute, the jax.checkpoint policy) that hybridize
     flags ask for: ``remat`` (else MXNET_BACKWARD_DO_MIRROR) and
-    ``remat_policy`` (else MXNET_REMAT_POLICY), a jax.checkpoint_policies
-    name or 'full'.  Shared by the CachedOp and by a hybridized child block
-    that is called inside someone else's trace (gluon/block.py)."""
+    ``remat_policy`` (else MXNET_REMAT_POLICY): a jax.checkpoint_policies
+    name, 'full', or (the flag alone) a tuple of the names of values to keep
+    (``jax.ad_checkpoint.checkpoint_name``; the attention call names
+    ``ops.pallas_ops.ATTENTION_RESIDUALS``) while the rest is recomputed.
+    Shared by the CachedOp and by a hybridized child block that is called
+    inside someone else's trace (gluon/block.py)."""
     from . import env
     remat = flags.get("remat")
     if remat is None:
@@ -46,17 +49,19 @@ def remat_policy(flags):
     if not remat:
         return False, None
     import jax
-    policy_name = flags.get("remat_policy")
-    if policy_name is None:
-        policy_name = env.get("MXNET_REMAT_POLICY")
-    policy = None
-    if policy_name and policy_name != "full":
-        try:
-            policy = getattr(jax.checkpoint_policies, policy_name)
-        except AttributeError:
-            raise MXNetError(
-                "unknown remat policy %r; see jax.checkpoint_policies"
-                % (policy_name,))
+    asked = flags.get("remat_policy")
+    if asked is None:
+        asked = env.get("MXNET_REMAT_POLICY")
+    if not asked or asked == "full":
+        return True, None
+    if isinstance(asked, tuple) and all(isinstance(n, str) for n in asked):
+        return True, jax.checkpoint_policies.save_only_these_names(*asked)
+    policy = getattr(jax.checkpoint_policies, asked, None) \
+        if isinstance(asked, str) else None
+    if policy is None:
+        raise MXNetError(
+            "unknown remat policy %r: a name in jax.checkpoint_policies, "
+            "'full', or a tuple of checkpoint_name names to keep" % (asked,))
     return True, policy
 
 

@@ -340,6 +340,21 @@ class HybridBlock(Block):
         self._clear_cached_op()
 
     def hybridize(self, active=True, **kwargs):
+        """Compile this block's forward as one program (a CachedOp, or part
+        of the program of whoever traces it).  Flags, for the children too:
+        ``remat=True`` recomputes the forward in the backward pass in place
+        of keeping its activations (else MXNET_BACKWARD_DO_MIRROR);
+        ``remat_policy`` says what is kept all the same: a
+        jax.checkpoint_policies name ('dots_saveable', ...; else
+        MXNET_REMAT_POLICY), 'full' for nothing, or a tuple of names, which
+        keeps the values that the forward passes through
+        ``jax.ad_checkpoint.checkpoint_name`` under one of them and nothing
+        else.  A kept value costs its bytes from the forward pass to this
+        block's backward and saves the operations that only it needs: the
+        attention call names its kernel's output and log-sum-exp
+        (``ops.pallas_ops.ATTENTION_RESIDUALS``), ``batch * heads * rows *
+        (head_dim + 1)`` float32 values for the forward kernel's second run.
+        ``donate_params``: see CachedOp."""
         self._active = active
         self._flags = kwargs
         self._clear_cached_op()

@@ -32,6 +32,9 @@ from .. import profiler
 from .registry import register
 
 _NEG = -1e30
+# what the attention call names for jax.checkpoint policies: the forward
+# kernel's output and log-sum-exp, the two residuals only it can produce
+ATTENTION_RESIDUALS = ("attn.out", "attn.lse")
 
 
 # ---------------------------------------------------------------------------
@@ -470,6 +473,7 @@ def _attention(q, k, v, mask, scale, precision, interpret, block_q, block_k,
     ``scope`` names the backward kernels' operations as the caller named the
     forward's."""
     import jax
+    from jax.ad_checkpoint import checkpoint_name
 
     use_pallas = interpret is not None or jax.default_backend() == "tpu"
     if not use_pallas:
@@ -483,11 +487,20 @@ def _attention(q, k, v, mask, scale, precision, interpret, block_q, block_k,
 
     def f_fwd(q_, k_, v_):
         out, lse = _attention_fwd_pallas(plan, q_, k_, v_)
+        # named, so that a recomputed block can keep the two by name
+        # (hybridize(remat_policy=ATTENTION_RESIDUALS)) and the kernel does
+        # not run again in the backward pass; an identity anywhere else.
+        # The log-sum-exp as (B * H, T): held with a last dimension of 1
+        # it takes tiles of (8, 128), 128 times its size.
+        out = checkpoint_name(out, ATTENTION_RESIDUALS[0])
+        lse = checkpoint_name(lse[..., 0], ATTENTION_RESIDUALS[1])
         return _trim(plan, out), (q_, k_, v_, out, lse)
 
     def f_bwd(res, g):
+        q_, k_, v_, out, lse = res
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
-            return _attention_bwd_pallas(plan, *res, g)
+            return _attention_bwd_pallas(plan, q_, k_, v_, out,
+                                         lse[..., None], g)
 
     f.defvjp(f_fwd, f_bwd)
     return f(q, k, v)

@@ -4,6 +4,8 @@ benchmark's plain reference (benchmark/reference/sdar_moe.py: float32,
 ``highest``, nothing of the program) and against the XLA attention reference
 (the Pallas kernels run interpreted)."""
 import copy
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -440,6 +442,103 @@ def test_remat_of_a_child_block_changes_no_gradient():
     for name in want:
         if not name.endswith("moe_load"):
             assert float(jnp.max(jnp.abs(got[name] - want[name]))) < 1e-5
+
+
+# -- a recomputed layer keeps its attention kernel's out and lse -----------
+
+NAMES = pallas_ops.ATTENTION_RESIDUALS
+
+
+@pytest.fixture
+def kernel_net(monkeypatch):
+    """``loss(flags)``: (a loss through the two-layer network as a function
+    of its parameters, the parameters), the attention by the Pallas kernels
+    (interpreted) and every layer hybridized with ``flags`` (None: not)."""
+    monkeypatch.setattr(pallas_ops, "block_mask_attention", functools.partial(
+        pallas_ops.block_mask_attention, interpret=True, block_q=32,
+        block_k=32))
+    net = block_diffusion.BlockDiffusionMoEDecoder(CONFIG, prefix="kept_")
+    net.initialize(mx.init.Xavier(), ctx=mx.current_context())
+    values = {k: p.data()._data for k, p in net.collect_params().items()}
+    tokens = jnp.asarray(_batch()[0])
+
+    def loss(flags):
+        for layer in net.layers:
+            layer.hybridize(flags is not None, **(flags or {}))
+
+        def f(values):  # a new function each time: jax caches traces by it
+            outs, _ = functional_call(net, values, tokens, training=True)
+            return jnp.sum(jnp.tanh(outs[0]))
+        return f, values
+    return loss
+
+
+def _kernels(jaxpr, name):
+    """How many ``pallas_call``s of that name the jaxpr holds, at any depth."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found += eqn.params["name"] == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernels(sub, name)
+    return found
+
+
+def test_kept_out_and_lse_change_no_bit_of_the_gradient(kernel_net):
+    kept, plain, eager = (jax.grad(f)(values) for f, values in (
+        kernel_net(dict(remat=True, remat_policy=NAMES)),
+        kernel_net(dict(remat=True)), kernel_net(None)))
+    for name in plain:
+        assert bool(jnp.all(kept[name] == plain[name])), name
+        if not name.endswith("moe_load"):
+            assert float(jnp.max(jnp.abs(kept[name] - eager[name]))) < 1e-5
+
+
+@pytest.mark.parametrize("flags,forward_kernels", [
+    (None, 2), (dict(remat=True), 4), (dict(remat=True, remat_policy=NAMES), 2),
+    (dict(remat=True, remat_policy=NAMES[:1]), 4),
+    (dict(remat=True, remat_policy=("attn.other",)), 4)],
+    ids=["no_recomputation", "plain", "both_kept", "out_alone", "other_names"])
+def test_forward_kernel_runs_once_a_layer_with_both_results_kept(
+        kernel_net, flags, forward_kernels):
+    """Two layers: with ``out`` and ``lse`` kept the recomputed layer has no
+    use for the forward kernel; with one of them missing it runs again.  The
+    backward kernels run once a layer whatever is kept."""
+    f, values = kernel_net(flags)
+    jaxpr = jax.make_jaxpr(jax.grad(f))(values).jaxpr
+    assert _kernels(jaxpr, "attention_fwd") == forward_kernels
+    assert _kernels(jaxpr, "attention_bwd_dq") == 2
+    assert _kernels(jaxpr, "attention_bwd_dkv") == 2
+
+
+def test_build_keeps_the_attention_residuals():
+    net = block_diffusion.build(CONFIG)
+    assert [layer._flags for layer in net.layers] == \
+        [dict(remat=True, remat_policy=("attn.out", "attn.lse"))] * 2
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_naming_is_an_identity_outside_a_recomputed_region(monkeypatch,
+                                                           causal):
+    """``flash_attention`` shares ``_attention``: forward and gradient lower
+    to the same text whether or not ``out`` and ``lse`` are named."""
+    q, k, v = _qkv(np.random.default_rng(3), 4, 2, 64)
+
+    def lowered():
+        def f(q, k, v):     # a new function each time: see above
+            return jnp.sum(jnp.tanh(pallas_ops.flash_attention(
+                q, k, v, causal=causal, interpret=True)))
+        # a private function's symbol ends in a counter that tracing the
+        # names advances: @_where_52 for @_where_51
+        return [re.sub(r"(@\w+?)_\d+\b", r"\1", jax.jit(g).lower(
+            q, k, v).as_text()) for g in (f, jax.grad(f, (0, 1, 2)))]
+
+    named = lowered()
+    seen = []
+    monkeypatch.setattr(jax.ad_checkpoint, "checkpoint_name",
+                        lambda x, name: (seen.append(name), x)[1])
+    assert lowered() == named
+    assert sorted(set(seen)) == sorted(NAMES)
 
 
 def test_gauge_is_read_when_totals_are_asked():

@@ -37,6 +37,16 @@ def _grads(net, x_np):
             for n, p in net.collect_params().items()}
 
 
+def _forward_jaxpr(net, x):
+    """The jaxpr (as text) of the CachedOp's forward as it is lowered, the
+    remat policy applied."""
+    import jax
+    co = net._cached_op
+    vals = tuple(net._cached_params[n].data()._data
+                 for n in co._param_names) + (x._data, jax.random.PRNGKey(0))
+    return str(jax.make_jaxpr(co._make_lowerable(training=True))(*vals))
+
+
 def test_remat_grads_match():
     rng = np.random.RandomState(0)
     x = rng.uniform(-1, 1, (8, 16)).astype(np.float32)
@@ -51,39 +61,24 @@ def test_remat_grads_match():
 
 
 def test_remat_appears_in_jaxpr():
-    import jax
     net = _make_net(remat=True)
     x = nd.zeros((2, 16))
     net(x)  # builds the CachedOp
-    co = net._cached_op
-    fn = co._make_lowerable(training=True)
-    params = {n: p.data()._data for n, p in net._cached_params.items()}
-    vals = tuple(params[n] for n in co._param_names) + (x._data,
-                                                        jax.random.PRNGKey(0))
-    jaxpr = jax.make_jaxpr(fn)(*vals)
-    assert "remat" in str(jaxpr), "jax.checkpoint not applied to the forward"
+    assert "remat" in _forward_jaxpr(net, x), \
+        "jax.checkpoint not applied to the forward"
     # and the plain build must NOT carry it
     net2 = _make_net(remat=None)
     net2(x)
-    fn2 = net2._cached_op._make_lowerable(training=True)
-    vals2 = tuple(net2._cached_params[n].data()._data
-                  for n in net2._cached_op._param_names) \
-        + (x._data, jax.random.PRNGKey(0))
-    assert "remat" not in str(jax.make_jaxpr(fn2)(*vals2))
+    assert "remat" not in _forward_jaxpr(net2, x)
 
 
 def test_remat_env_knob(monkeypatch):
     """MXNET_BACKWARD_DO_MIRROR=1 turns remat on without a per-block flag."""
-    import jax
     monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
     net = _make_net(remat=None)
     x = nd.zeros((2, 16))
     net(x)
-    fn = net._cached_op._make_lowerable(training=True)
-    vals = tuple(net._cached_params[n].data()._data
-                 for n in net._cached_op._param_names) \
-        + (x._data, jax.random.PRNGKey(0))
-    assert "remat" in str(jax.make_jaxpr(fn)(*vals))
+    assert "remat" in _forward_jaxpr(net, x)
 
 
 def test_remat_policy_knob():
@@ -98,6 +93,96 @@ def test_remat_policy_knob():
     net.hybridize(remat=True, remat_policy="not_a_policy")
     with pytest.raises(MXNetError):
         net(x)
+
+
+@pytest.mark.parametrize("asked", [("attn.out", 3), ["attn.out"], 3,
+                                   ("attn.out", ("attn.lse",))])
+def test_remat_policy_neither_a_name_nor_a_tuple_of_names(asked):
+    from mxnet_tpu.base import MXNetError
+    net = _make_net(remat=True)
+    x = nd.zeros((2, 16))
+    net.hybridize(remat=True, remat_policy=asked)
+    with pytest.raises(MXNetError, match="unknown remat policy"):
+        net(x)
+
+
+def test_remat_policy_tuple_of_names_for_a_cached_op():
+    """A tuple of names is save_only_these_names: the CachedOp's forward is
+    still wrapped, and with no value under such a name the gradients are
+    those of plain recomputation, to the bit."""
+    rng = np.random.RandomState(0)
+    x_np = rng.uniform(-1, 1, (8, 16)).astype(np.float32)
+    net = _make_net(remat=True)
+    net.hybridize(remat=True, remat_policy=("attn.out", "attn.lse"))
+    g_names = _grads(net, x_np)
+    g_remat = _grads(_make_net(remat=True), x_np)
+    for name in g_remat:
+        np.testing.assert_array_equal(g_names[name], g_remat[name], name)
+    jaxpr = _forward_jaxpr(net, nd.array(x_np))
+    assert "remat" in jaxpr and "save_only_these_names" in jaxpr
+
+
+class _NamesItsHidden(mx.gluon.HybridBlock):
+    """Two dense layers; the first one's output goes through
+    ``checkpoint_name`` as "hidden"."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.first = nn.Dense(32, activation="relu", in_units=16)
+            self.second = nn.Dense(32, activation="relu", in_units=32)
+
+    def hybrid_forward(self, F, x):
+        from jax.ad_checkpoint import checkpoint_name
+        hidden = self.first(x)
+        return self.second(nd.NDArray(checkpoint_name(hidden._data,
+                                                      "hidden")))
+
+
+def _eqns(jaxpr):
+    import jax
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_remat_policy_tuple_of_names_for_a_child_block_inside_a_trace():
+    """A hybridized child called inside someone else's trace keeps the value
+    its forward named and recomputes the rest: one matrix product fewer in
+    the gradient's jaxpr than with a name nothing carries, and the same
+    gradients to the bit."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.gluon.block import functional_call
+    net = nn.HybridSequential()
+    with net.name_scope():
+        child = _NamesItsHidden()
+        net.add(child)
+        net.add(nn.Dense(4, in_units=32))
+    mx.random.seed(5)
+    net.initialize(mx.init.Xavier(), force_reinit=True)
+    values = {k: p.data()._data for k, p in net.collect_params().items()}
+    x = jnp.asarray(np.random.RandomState(2).uniform(-1, 1, (8, 16)),
+                    jnp.float32)
+
+    def gradient(names):
+        child.hybridize(remat=True, remat_policy=names)
+
+        def f(v):       # a new function each time: jax caches traces by it
+            return jnp.sum(functional_call(net, v, x, training=True)[0][0]
+                           ** 2)
+        jaxpr = jax.make_jaxpr(jax.grad(f))(values).jaxpr
+        return jax.grad(f)(values), sum(
+            eqn.primitive.name == "dot_general" for eqn in _eqns(jaxpr))
+
+    kept, kept_products = gradient(("hidden",))
+    plain, plain_products = gradient(("nothing_has_this_name",))
+    assert kept_products == plain_products - 1
+    assert gradient(None)[1] == plain_products
+    for name in plain:
+        np.testing.assert_array_equal(np.asarray(kept[name]),
+                                      np.asarray(plain[name]), name)
 
 
 def test_remat_convnet_bitwise():
