@@ -1,5 +1,4 @@
-"""Causal transformer language model (the BASELINE.json "Transformer
-(sequence ops)" config).
+"""Causal transformer language model (the sequence-ops configuration).
 
 A GPT-style decoder built from gluon blocks whose attention runs through
 the framework's fused kernel (``_contrib_flash_attention`` — the Pallas

@@ -1,7 +1,6 @@
 #!/usr/bin/env python
 """Transformer language model (reference: example/gluon/word_language_model +
-the transformer attention ops in src/operator/contrib/transformer.cc —
-BASELINE.json config 3).
+the transformer attention ops in src/operator/contrib/transformer.cc).
 
 TPU-native: attention runs through the fused flash-attention op (Pallas kernel
 on TPU, ops/pallas_ops.py); for sequences sharded over an 'sp' mesh axis the

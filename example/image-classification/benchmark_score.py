@@ -1,7 +1,6 @@
 #!/usr/bin/env python
 """Inference throughput benchmark (reference:
-example/image-classification/benchmark_score.py — the source of the
-BASELINE.md inference table)."""
+example/image-classification/benchmark_score.py)."""
 from __future__ import annotations
 
 import argparse

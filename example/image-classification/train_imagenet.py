@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Train an ImageNet model (reference: example/image-classification/
-train_imagenet.py — the BASELINE.json north-star config:
+train_imagenet.py — the north-star configuration:
 ``train_imagenet.py --kv-store dist_tpu_sync`` trains ResNet-50 end-to-end on
 a TPU pod).
 

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Train LeNet/MLP on MNIST via the Module API (reference:
-example/image-classification/train_mnist.py — BASELINE.json config 1)."""
+example/image-classification/train_mnist.py)."""
 from __future__ import annotations
 
 import argparse

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """LSTM language model with bucketing (reference: example/rnn/bucketing/
-lstm_bucketing.py — BASELINE.json config 3; bucketing per
+lstm_bucketing.py; bucketing per
 docs/faq/bucketing.md; each bucket is one XLA compilation)."""
 from __future__ import annotations
 

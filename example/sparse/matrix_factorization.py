@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Matrix factorization with embedding tables (reference:
-example/sparse/matrix_factorization/train.py — BASELINE.json config 4).
+example/sparse/matrix_factorization/train.py).
 
 The reference pulls row_sparse weights on demand from the parameter server
 (kvstore PullRowSparse); on TPU the embedding tables live in HBM and XLA's

@@ -39,8 +39,8 @@ Abstract-memory model
   region allocates is live until the region ends, so a region's peak is the
   sum of its sites.  That is exactly the runtime accountant's
   ``track_region`` model, which is what makes the two sides comparable with
-  ``==`` (``predict_decode_step_peak_bytes`` vs the measured peak in
-  BENCH_SHARDED_DECODE.json).
+  ``==`` (``predict_decode_step_peak_bytes`` vs the metered peak, in
+  tests/test_mxmem.py).
 
 Rules (empty baseline; fix or tag, never suppress)
 --------------------------------------------------
@@ -1158,7 +1158,7 @@ def render_mem_map(entries):
         "peak their closure is held to; `reserve` allocations ride the",
         "admission-time worst-case reservation (the no-mid-stream-OOM",
         "contract).  The runtime twin is mxnet_tpu/memory_accounting.py",
-        "(BENCH_SHARDED_DECODE.json pins static == runtime peak bytes).",
+        "(tests/test_mxmem.py pins static == runtime peak bytes).",
         "",
     ]
     cur = None

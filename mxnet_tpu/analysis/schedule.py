@@ -158,7 +158,6 @@ class ChaosScheduler(object):
     """Seeded preemption source shared by every wrapped lock."""
 
     def __init__(self, seed=0, p_preempt=0.25, max_sleep_ms=0.5):
-        self._rng_lock = _REAL_LOCK()
         self._rng = random.Random(seed)
         self.p_preempt = float(p_preempt)
         self.max_sleep_s = float(max_sleep_ms) / 1e3
@@ -166,18 +165,23 @@ class ChaosScheduler(object):
         self.preemptions = 0
 
     def reseed(self, seed):
-        with self._rng_lock:
-            self._rng.seed(seed)
+        self._rng.seed(seed)
 
     def maybe_preempt(self):
+        # No lock is held here, by design: the collector can run a
+        # finalizer on this thread between two bytecodes, and a finalizer
+        # that takes a wrapped lock (DeviceFeed.__del__ -> close())
+        # re-enters this method.  Under a lock of the scheduler's own that
+        # re-entry deadlocked the thread against itself and, behind it,
+        # every thread of the run.  A draw and a reseed are each atomic
+        # under the interpreter lock; ``preemptions`` is a statistic (the
+        # gates ask that it is well above zero), so an increment lost
+        # between two threads only undercounts.
         if not self.enabled:
             return
-        with self._rng_lock:
-            fire = self._rng.random() < self.p_preempt
-            dur = self._rng.random() * self.max_sleep_s if fire else 0.0
-            if fire:
-                self.preemptions += 1
-        if fire:
+        if self._rng.random() < self.p_preempt:
+            dur = self._rng.random() * self.max_sleep_s
+            self.preemptions += 1
             time.sleep(dur)   # dur==0 still yields the GIL
 
 

@@ -854,7 +854,7 @@ def _analyze_graph(graph, repo_mode=True):
             continue                        # already a breach finding
         if site.kind == "all_gather":
             why = ("feeds a contraction/kernel on replicated operands — "
-                   "the measured gather tax (BENCH_SHARDED_DECODE.json); a "
+                   "the gather tax the decode step no longer pays; a "
                    "sharded contraction + psum would serve"
                    if site.feeds_compute else
                    "moves a full operand copy to every shard")
@@ -1007,8 +1007,8 @@ def render_collective_map(entries):
         "budget(psum=4) covering its four allclose-sanctioned psum sites",
         "— embedding assembly (order-free, exact), the per-block",
         "row-parallel reduction, its opt-in 2-bit quantized wire, and",
-        "the tied-unembed reduction (BENCH_SHARDED_DECODE.json,",
-        "docs/PERF.md measure the resulting 2L+2-psum/zero-gather bill).",
+        "the tied-unembed reduction (docs/PERF.md has the resulting",
+        "2L+2-psum/zero-gather bill; tests/test_mxshard.py counts it).",
         "",
     ]
     cur = None

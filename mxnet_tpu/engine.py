@@ -12,11 +12,11 @@ superseded by jit: the ``bulk`` context is kept as API but XLA fusion already
 bulk-compiles any jitted region.  ``set_bulk_size`` is accepted and recorded
 for compatibility.
 
-Measured decision (round 4, ``tools/eager_overhead.py`` on the 1-core CPU
-container; recorded in EAGER_OVERHEAD.json): a 100-step LSTMCell unroll
-runs 1,981 cell-steps/s eager vs 40,254 hybridized — a 20x gap, ~48 us/op
-eager dispatch overhead, of which ~15-20 us is jax.jit's own per-call
-floor.  So for small-op chains the
+Every eager op is a dispatch of its own: a chain of small ops (an unrolled
+LSTM cell, say) pays one dispatch per op, where a hybridized block pays one
+for the whole chain.  What a dispatch costs on the chip's host is PERF.md's
+to say; no number from a CPU is kept here.
+So for small-op chains the
 bulking question is real, and the framework's answer is ``hybridize()``:
 the whole region traces into ONE cached XLA module, which is strictly
 stronger than the reference's engine bulking (segments still launch one

@@ -75,23 +75,6 @@ _V = [
            "Start the jax.profiler trace at import (profiler.py)."),
     EnvVar("MXNET_TEST_SEED", int, None,
            "Fixed RNG seed for test reproduction (conftest logs it)."),
-    # --- benchmarks -------------------------------------------------------
-    EnvVar("BENCH_BATCH", int, 32, "bench.py batch size."),
-    EnvVar("BENCH_IMG", int, 224, "bench.py image edge length."),
-    EnvVar("BENCH_ITERS", int, 20,
-           "bench.py timed iterations (mode-dependent default: 20 for "
-           "train/transformer, 50 for inference)."),
-    EnvVar("BENCH_MODE", str, "train",
-           "bench.py measurement: train (headline), inference, or "
-           "transformer (decoder-LM tokens/sec with flash attention)."),
-    EnvVar("BENCH_TFM_BATCH", int, 8, "transformer bench batch size."),
-    EnvVar("BENCH_TFM_SEQ", int, 1024, "transformer bench sequence length."),
-    EnvVar("BENCH_TFM_DIM", int, 768, "transformer bench model width."),
-    EnvVar("BENCH_TFM_DEPTH", int, 12, "transformer bench layer count."),
-    EnvVar("BENCH_TFM_VOCAB", int, 32768, "transformer bench vocabulary."),
-    EnvVar("BENCH_LAYOUT", str, "auto",
-           "bench.py conv data layout: auto (measure NCHW and NHWC, report "
-           "the faster), NCHW, or NHWC."),
 ]
 
 VARIABLES = {v.name: v for v in _V}

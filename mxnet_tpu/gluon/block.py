@@ -633,8 +633,8 @@ def functional_call(block, param_vals, *input_vals, training=False, rng_key=None
 
     param_vals: dict name -> jax array;  input_vals: jax arrays.
     Returns (output jax values tuple, updated aux values dict).  Jittable —
-    this is the building block bench.py / __graft_entry__ use to compile whole
-    gluon models as single XLA modules."""
+    this is the building block __graft_entry__ and the benchmark's family
+    comparisons use to run whole gluon models as single XLA modules."""
     import jax
     from .. import random as _random
     from ..ndarray import NDArray
@@ -660,7 +660,7 @@ def split_param_names(block):
 
     ``frozen`` is every ``grad_req == 'null'`` parameter (BatchNorm running
     stats and explicitly frozen weights): whole-program train steps
-    (module.compiled_step, bench.py) thread those through the trace
+    (module.compiled_step) thread those through the trace
     unchanged/functionally while differentiating only the trainable set.
     Both lists are sorted for a stable trace signature."""
     params = block.collect_params()

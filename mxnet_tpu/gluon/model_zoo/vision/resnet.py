@@ -1,6 +1,6 @@
 """ResNet V1/V2 (reference: python/mxnet/gluon/model_zoo/vision/resnet.py).
 
-The flagship benchmark model (BASELINE.md ResNet-50).  Structure matches the
+The flagship benchmark model (cell ``resnet50_v1.fit_b128``).  Structure matches the
 reference exactly; on TPU the whole hybridized network compiles to one XLA
 module with convs on the MXU in bf16 when cast.
 """

@@ -4,7 +4,7 @@ Reference: ``src/io/iter_prefetcher.h`` keeps decoded batches one step ahead
 of the consumer; both the MXNet paper (arXiv 1512.01274 §4) and TensorFlow's
 (arXiv 1605.08695) name overlapping input preprocessing/transfer with compute
 as a first-class throughput lever.  The compute side of this repro is one
-fused XLA module per step (BENCH_LIVE.json); this module is the matching
+fused XLA module per step (module/compiled_step.py); this module is the matching
 host side: without it every training loop pays decode + batchify + host→
 device transfer *inside* the step and is data-bound no matter how fast the
 chip is.

@@ -6,7 +6,7 @@ Reference: src/kvstore/ — factory (kvstore.cc:40-77) creating ``local``/
 (ps-lite parameter server, kvstore_dist.h; server side kvstore_dist_server.h
 with sync aggregation + server-run optimizer).  Python client kvstore.py:97-635.
 
-TPU-native redesign (the BASELINE.json north star): there are no parameter
+TPU-native redesign: there are no parameter
 servers — gradient aggregation is an XLA collective:
 
   * ``local`` / ``device``: single-process multi-device reduce.  Push with a

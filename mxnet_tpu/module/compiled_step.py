@@ -2,10 +2,10 @@
 
 The eager ``fit()`` loop dispatches forward, backward, one optimizer-update
 kernel per parameter, and a metric fetch — with a host sync on every batch.
-The live TPU capture (BENCH_LIVE.json) shows what that costs: ResNet-50 at
-MFU 0.178, the hardware ~5x underused.  This module promotes the fused step
-that tools/input_bench.py proved in miniature (one XLA module per iteration,
-1.56x end-to-end) to a first-class citizen of the module layer — the
+This module makes the fused step, one XLA module per iteration, a
+first-class citizen of the module layer.  The benchmark's cells run it
+(``Module.fit()`` and ``from_block``), and PERF.md §5 says where their
+time goes on the chip.  It follows the
 "compile the whole program, not ops" thesis of the Julia->TPU paper
 (arxiv 1810.09868), with the dataflow-step discipline of TensorFlow
 (arxiv 1605.08695):
@@ -38,9 +38,9 @@ Two frontends share the machinery:
   the optimizer's own ``update_multi_precision`` traced through NDArray
   tracer handles, so the compiled and eager paths run the SAME update
   kernels.  This is what ``BaseModule.fit(compiled=True)`` uses.
-* :meth:`CompiledTrainStep.from_block` — a gluon block + explicit loss;
-  used by tools/input_bench.py and bench.py so the benches and ``fit()``
-  exercise one code path.
+* :meth:`CompiledTrainStep.from_block` — a gluon block + explicit loss:
+  a user's Gluon loop and the benchmark's ``block_step`` entry, so both
+  kinds of cell exercise one code path.
 
 Limitations become :class:`CompiledStepUnsupported` (the caller falls back
 to the eager loop with a one-line warning): multi-context binds, kvstore

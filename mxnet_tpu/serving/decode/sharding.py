@@ -768,8 +768,8 @@ class ShardedDecodeModel:
         # sites back those calls (assembly / block / 2bit-wire / unembed).
         # The declared worst case under the accountant's reuse-free model
         # is the psum outputs live at once — predict_decode_step_peak_bytes()
-        # is the exact symbolic form, pinned == the runtime peak in
-        # BENCH_SHARDED_DECODE.json.
+        # is the exact symbolic form, pinned == the runtime peak by
+        # tests/test_mxmem.py and the sharded-decode smoke.
         # mxmem: budget(hbm=64MB)
         # mxshard: budget(psum=4)
         def body(p_local, small, k_local, v_local):

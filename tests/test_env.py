@@ -7,7 +7,7 @@ from mxnet_tpu import env
 def test_env_defaults(monkeypatch):
     monkeypatch.delenv("DMLC_NUM_WORKER", raising=False)
     assert env.get("DMLC_NUM_WORKER") == 1
-    assert env.get("BENCH_BATCH") == 32
+    assert env.get("MX_KV_INIT_TIMEOUT") == 120.0
 
 
 def test_env_override(monkeypatch):

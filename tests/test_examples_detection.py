@@ -1,4 +1,4 @@
-"""The BASELINE.json detection configs (example/ssd, example/rcnn) stay
+"""The detection examples (example/ssd, example/rcnn) stay
 runnable AND learn: each example trains on synthetic data through the
 contrib detection op stack end-to-end, and detection quality is asserted
 via the VOC mAP metric (not just loss decrease)."""

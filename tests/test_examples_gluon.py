@@ -1,4 +1,4 @@
-"""The BASELINE.json sequence config (example/gluon transformer LM) stays
+"""The sequence example (example/gluon transformer LM) stays
 runnable: trains the causal flash-attention decoder on synthetic patterns."""
 import os
 import subprocess

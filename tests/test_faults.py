@@ -420,19 +420,15 @@ def test_breaker_opens_fast_fails_and_recovers():
         assert server.predict("m", x, timeout_ms=2000).status == serving.OK
         assert server.health("m") == serving.HEALTHY
 
-        t_open = None
         plan = faults.FaultPlan(0).add("serving.predict", kind="fatal")
         with faults.plan(plan):
             statuses = [server.predict("m", x, timeout_ms=2000).status
                         for _ in range(5)]
-            t_open = time.monotonic()
             fast = server.predict("m", x, timeout_ms=2000)
-            fast_ms = (time.monotonic() - t_open) * 1e3
         # exactly threshold ERRORs, then fast retryable UNAVAILABLE
         assert statuses[:3] == [serving.ERROR] * 3
         assert statuses[3:] == [serving.UNAVAILABLE] * 2
         assert fast.status == serving.UNAVAILABLE
-        assert fast_ms < 500   # breaker rejects at admission, no execution
         snap = server.stats()["models"]["m"]
         assert snap["health"] == "UNAVAILABLE"
         assert snap["breaker"]["state"] == "open"

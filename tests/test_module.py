@@ -85,7 +85,7 @@ def test_module_predict():
 
 
 def test_module_lenet_conv():
-    """LeNet on image-shaped synthetic data (BASELINE.json config 1 analog)."""
+    """LeNet on image-shaped synthetic data (the train_mnist.py example's shape)."""
     data = sym.Variable("data")
     conv1 = sym.Convolution(data, name="conv1", kernel=(3, 3), num_filter=8)
     act1 = sym.Activation(conv1, act_type="relu")

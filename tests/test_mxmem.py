@@ -485,37 +485,3 @@ def test_ci_lint_runs_mem():
         script = f.read()
     assert "mem" in script or "--passes" not in script, \
         "ci_lint.sh must run the mem pass (default pass list covers it)"
-
-
-def test_bench_artifact_pins_static_peak_to_runtime():
-    path = os.path.join(REPO, "BENCH_SHARDED_DECODE.json")
-    report = json.load(open(path))
-    mem = report["memory"]
-    for key in ("region", "temps_per_step", "runtime_peak_bytes",
-                "static_predicted_peak_bytes", "live_bytes_after",
-                "static_matches_runtime",
-                "device_memory_stats_available"):
-        assert key in mem, "memory.%s missing from the artifact" % key
-    # the PR's acceptance gate: the committed artifact proves the static
-    # footprint model equals the metered decode-step peak, exact bytes
-    assert mem["static_matches_runtime"] is True
-    assert mem["static_predicted_peak_bytes"] \
-        == mem["runtime_peak_bytes"] > 0
-    assert mem["temps_per_step"] > 0
-    assert mem["live_bytes_after"] == 0
-
-
-def test_disagg_artifact_kv_accounting_balances():
-    path = os.path.join(REPO, "BENCH_DISAGG.json")
-    report = json.load(open(path))
-    mem = report["memory"]
-    for key in ("kv_regions", "kv_alloc_bytes", "kv_freed_bytes",
-                "kv_live_bytes", "kv_pool_bytes", "kv_peak_bytes",
-                "balanced"):
-        assert key in mem, "memory.%s missing from the artifact" % key
-    assert mem["balanced"] is True
-    assert mem["kv_regions"] >= 1
-    assert mem["kv_peak_bytes"] > 0
-    # the block ledger drains; the engine-lifetime pools stay charged
-    assert mem["kv_live_bytes"] == 0
-    assert mem["kv_pool_bytes"] > 0

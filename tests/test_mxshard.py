@@ -447,25 +447,3 @@ def test_ci_lint_runs_spd():
     assert "spd" in script or "mxlint.py\n" in script or \
         "--passes" not in script, \
         "ci_lint.sh must run the spd pass (default pass list covers it)"
-
-
-def test_bench_artifact_carries_collective_bill():
-    path = os.path.join(REPO, "BENCH_SHARDED_DECODE.json")
-    report = json.load(open(path))
-    coll = report["collectives"]
-    for key in ("gathers_per_step", "psums_per_step",
-                "collective_bytes_per_step", "per_kind", "per_axis",
-                "static_predicted", "static_matches_runtime"):
-        assert key in coll, "collectives.%s missing from the artifact" % key
-    assert coll["static_matches_runtime"] is True
-    # the compute-parallel bill: zero gathers, 2L+2 psums per step
-    layers = report["workload"]["model"]["num_layers"]
-    assert coll["gathers_per_step"] == 0
-    assert coll["psums_per_step"] == 2 * layers + 2
-    assert coll["collective_bytes_per_step"] > 0
-    assert coll["per_axis"]["psum"]["tp"]["calls"] \
-        == coll["psums_per_step"]
-    assert coll["static_predicted"]["psum"]["calls"] \
-        == coll["psums_per_step"]
-    assert coll["static_predicted"]["all_gather"] == \
-        {"calls": 0, "bytes": 0}

@@ -151,7 +151,7 @@ def test_quantize_no_bias_path():
 
 
 def test_quantize_model_zoo_resnet_agreement(tmp_path):
-    """Model-zoo-scale int8: export resnet18_v1 (the bench.py int8 path),
+    """Model-zoo-scale int8: export resnet18_v1,
     quantize with minmax calibration, and require near-total top-1
     agreement plus bounded logit drift vs the fp32 executor — the
     example/quantization accuracy-parity check at real-model depth."""

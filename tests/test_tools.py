@@ -303,3 +303,70 @@ def test_chip_smoke_failed_phase_is_reported_and_fails_the_run(capsys):
     assert "refused the kernel" in bad["error"]
     assert bad["shape"] == [1, 16, 8192, 128]   # what it found, it keeps
     assert good["ok"] is True and good["x"] == 1
+
+
+# ---------------------------------------------------------------------------
+# serve_bench exit gates: counts decide, a clock never does
+# ---------------------------------------------------------------------------
+
+def _engine_leg(streams, **extra):
+    return dict({"statuses": {"OK": streams}, "steady_state_recompiles": 0,
+                 "kv_leaked_blocks": 0}, **extra)
+
+
+def _sound_deploy_report():
+    # a swap window 100 times slower than steady state
+    return "_deploy_bench_ok", {
+        "workload": {"arrivals": 8, "fired": 8},
+        "statuses": {"OK": 8}, "conserved": True, "pools_whole": True,
+        "torn_streams": 0, "ok_by_generation": {1: 3, 2: 5},
+        "probes": {"bitwise": True, "generation": 2},
+        "swap": {"status": "deployed", "error": None, "generation": 2,
+                 "streams_during_swap": 2,
+                 "ttft_p99_during_swap_ms": 640.0, "ttft_p99_steady_ms": 6.4},
+        "engines": {"r0": _engine_leg(8, generation=2)},
+        "retired_engines": {"old": {"steady_state_recompiles": 0}},
+        "memory": {"balanced": True}}, ("engines", "r0")
+
+
+def _sound_prefix_spec_report():
+    return "_prefix_spec_ok", {
+        "workload": {"streams": 8},
+        "baseline": _engine_leg(8, prefill_chunks=32),
+        "optimized": _engine_leg(8, prefill_chunks=10, prefix_hits=7,
+                                 full_prompt_prefills=1, spec_proposed=40,
+                                 spec_accepted=30),
+        "speedup_tokens_per_s": 0.1}, ("optimized",)
+
+
+def _sound_sharded_decode_report():
+    leg = dict(token_equal_reference=True, devices=2, tp_degree=2)
+    return "_sharded_decode_ok", {
+        "workload": {"tp": 2},
+        "tp1": _engine_leg(8, **leg), "tp2": _engine_leg(8, **leg),
+        "collectives": {"static_matches_runtime": True,
+                        "gathers_per_step": 0},
+        "memory": {"static_matches_runtime": True,
+                   "runtime_peak_bytes": 4096, "live_bytes_after": 0},
+        "relative_tokens_per_s": 0.1}, ("tp2",)
+
+
+@pytest.mark.parametrize("sound", [_sound_deploy_report,
+                                   _sound_prefix_spec_report,
+                                   _sound_sharded_decode_report],
+                         ids=["deploy", "prefix-spec", "sharded-decode"])
+def test_serve_bench_exit_gate_reads_counts_and_no_clock(sound):
+    """Sound counts with absurd times pass; the same report with one
+    leaked KV block, or one steady-state recompile, fails."""
+    import copy
+    import serve_bench
+    gate, report, engine_at = sound()
+    gate = getattr(serve_bench, gate)
+    assert gate(report) is True
+    for count in ("kv_leaked_blocks", "steady_state_recompiles"):
+        broken = copy.deepcopy(report)
+        leg = broken
+        for key in engine_at:
+            leg = leg[key]
+        leg[count] = 1
+        assert gate(broken) is False, count

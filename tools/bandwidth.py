@@ -1,5 +1,5 @@
 """Measure kvstore allreduce bandwidth (reference: tools/bandwidth/
-measure.py — the GB/s of gradient aggregation, BASELINE.json metric 2).
+measure.py — the GB/s of gradient aggregation).
 
 Single process: measures the tpu_sync jitted add-tree over N simulated
 device buffers (one chip: HBM-bound adds).  Under a multi-device mesh

@@ -1,37 +1,39 @@
 #!/usr/bin/env python
 """serve_bench — load generator for mxnet_tpu.serving.
 
-Two load profiles:
+Every profile reports its times and rates (``ttft_ms``, ``tokens_per_s``,
+``speedup_*``) for whoever runs it on a chip, and gates its exit code on
+counts alone: statuses, recompiles, leaked blocks, conservation, bitwise
+streams.  The JSON report goes where ``--out`` says, else to stdout.
+
+Seven load profiles:
 
 * ``--profile batch`` (default) — the one-shot inference path: a small
   shape-polymorphic Gluon MLP under concurrent closed-loop clients firing
   a mixed-shape workload; reports throughput, latency percentiles, status
   counts, batching efficiency, and the compile-cache delta (which must be
-  zero after warmup) to a BENCH_SERVE.json-style artifact.
+  zero after warmup).
 * ``--profile decode`` — the autoregressive path: hundreds of concurrent
   token streams with mixed prompt/output lengths through the continuous-
   batching DecodeEngine (serving/decode/), then the SAME workload through
   run-to-completion ("static") batching at equal slot count; reports token
   throughput, p50/p99 time-to-first-token, KV pool peak/leak, the
-  steady-state recompile count, and the continuous-vs-static speedup to a
-  BENCH_DECODE.json artifact.
+  steady-state recompile count, and the continuous-vs-static speedup.
 * ``--profile fleet-decode`` — the stateful decode fleet: the same stream
   workload through ``FleetRouter.submit_stream`` across two replicas with
   one replica DRAINED mid-run, so every one of its live streams hands off
   (prefix + KV pages, lease-fenced) to the survivor; reports token
   throughput and TTFT p50/p99 measured ACROSS the handoff, the handoff
-  count, and per-engine recompile/KV-leak gates to a
-  BENCH_FLEET_DECODE.json artifact.  The exit gate requires every stream
-  to finish OK despite the drain.
+  count, and per-engine recompile/KV-leak gates.  The exit gate requires
+  every stream to finish OK despite the drain.
 * ``--profile prefix-spec`` — the stacked decode multipliers: a shared-
   prefix storm (one seeded system prompt, per-stream suffixes, a seeded-
   sampling minority) through a chunked-prefill baseline engine and then
   through the SAME workload with copy-on-write prefix caching +
   speculative decoding; reports tok/s, TTFT p50/p99, prefix hit-rate,
-  CoW forks, speculative acceptance rate, and recompile/KV-leak gates to
-  a BENCH_PREFIX_SPEC.json artifact.  The full-size exit gate requires
-  >= 1.5x tok/s over the no-prefix-cache path and fewer full-prompt
-  prefills than streams.
+  CoW forks, speculative acceptance rate, and recompile/KV-leak gates.
+  The exit gate requires fewer full-prompt prefills than streams, fewer
+  prefill chunks than the baseline, and accepted speculated tokens.
 * ``--profile sharded-decode`` — tensor-parallel serving at an EQUAL
   device budget: the same mixed prompt/output-length stream workload
   (with a seeded-sampling minority) through tp (default 2) unsharded
@@ -42,14 +44,14 @@ Two load profiles:
   per-leg device counts, the per-decode-step collective bill
   (gathers/step == 0, psums/step == 2L+2, bytes/step from the runtime
   counters in ``parallel.collectives``, cross-checked against the
-  mxshard static prediction — docs/COLLECTIVE_MAP.md), and the hard
-  correctness gates to a BENCH_SHARDED_DECODE.json artifact: every
-  stream OK, zero steady-state recompiles, zero leaked KV blocks,
-  static collective/memory predictions == runtime counters, every OK
-  stream (greedy AND sampled) token-identical to the single-device
-  reference on both legs (tp1 bitwise outright; the sharded leg allclose
-  in logits under the psum reduction-order relaxation), and sharded
-  per-device throughput >= 0.8x of tp1.
+  mxshard static prediction — docs/COLLECTIVE_MAP.md), the sharded
+  leg's per-device throughput relative to tp1, and the hard correctness
+  gates: every stream OK, zero steady-state recompiles, zero leaked KV
+  blocks, static collective/memory predictions == runtime counters,
+  every OK stream (greedy AND sampled) token-identical to the
+  single-device reference on both legs (tp1 bitwise outright; the
+  sharded leg allclose in logits under the psum reduction-order
+  relaxation).
 * ``--profile disagg`` — disaggregated prefill/decode tiers vs a
   colocated fleet at an EQUAL device budget, under OPEN-loop load: both
   legs replay the identical seeded Poisson arrival trace
@@ -60,7 +62,7 @@ Two load profiles:
   latency, and the hard gates — arrival-count conservation, cross-tier
   stream conservation, zero steady-state recompiles / leaked KV blocks
   on every engine of both tiers, every OK stream bitwise-equal to the
-  single-engine reference — to a BENCH_DISAGG.json artifact.
+  single-engine reference.
 * ``--profile deploy`` — zero-downtime weight hot-swap under OPEN-loop
   load: a two-replica decode fleet replays a seeded Poisson arrival
   trace while a ``DeploymentController`` (serving/deploy.py) rolls the
@@ -73,12 +75,10 @@ Two load profiles:
   (every arrival terminates OK and the ledger conserves), every OK
   stream bitwise-equal to exactly ONE generation's reference (none
   torn, both generations observed), zero steady-state recompiles on
-  the new AND the retired engines, zero leaked KV blocks fleet-wide,
-  and swap-window TTFT p99 within ``--swap-ttft-x`` of steady state —
-  to a BENCH_DEPLOY.json artifact.
+  the new AND the retired engines, zero leaked KV blocks fleet-wide.
 
-Profiles live in the ``PROFILES`` table (one row each: artifact path,
-environment, runner); adding a profile is one entry plus its runner.
+Profiles live in the ``PROFILES`` table (one row each: runner and
+pre-import environment); adding a profile is one entry plus its runner.
 
 Usage:
   python tools/serve_bench.py                        # full batch run
@@ -89,7 +89,7 @@ Usage:
   python tools/serve_bench.py --profile disagg       # open-loop tiers
   python tools/serve_bench.py --profile deploy       # live weight swap
   python tools/serve_bench.py --smoke [--profile decode]  # tier-1 smokes
-  python tools/serve_bench.py --clients 16 --requests 64 --out bench.json
+  python tools/serve_bench.py --clients 16 --requests 64 --out report.json
 """
 from __future__ import annotations
 
@@ -169,7 +169,7 @@ def run_bench(clients, requests_per_client, shapes, max_batch, linger_ms,
 
     total = clients * requests_per_client
     # same nearest-rank estimator the server's stats() reports, so bench
-    # artifacts and server snapshots agree on what "p99" means
+    # reports and server snapshots agree on what "p99" means
     from mxnet_tpu.serving.stats import LatencyWindow
     window = LatencyWindow(capacity=max(1, len(latencies)))
     for ms in latencies:
@@ -256,7 +256,7 @@ def run_decode_bench(streams, slots, block_size, max_prompt, max_new, seed,
         kv = engine.kv_stats()
         engine.stop()
         # same nearest-rank estimator the engine's stats_snapshot()
-        # reports, so artifact and snapshot agree on what "p99" means
+        # reports, so report and snapshot agree on what "p99" means
         from mxnet_tpu.serving.stats import LatencyWindow
         window = LatencyWindow(capacity=max(1, len(ttfts)))
         for ms in ttfts:
@@ -464,7 +464,7 @@ def run_prefix_spec_bench(streams, slots, block_size, chunk, max_prompt,
 
     Every ``sampled_every``-th stream runs seeded sampling instead of
     greedy (spec falls back to one verified token per round for those),
-    so the artifact also witnesses sampled-stream replay under the full
+    so the report also witnesses sampled-stream replay under the full
     stack.  The first stream is submitted alone as the donor: its
     completed prefill registers the shared prefix the storm then hits."""
     from mxnet_tpu.serving.decode import DecodeEngine, TinyCausalLM
@@ -586,12 +586,13 @@ def run_prefix_spec_bench(streams, slots, block_size, chunk, max_prompt,
     }
 
 
-def _prefix_spec_ok(report, require_speedup=True):
+def _prefix_spec_ok(report):
     """Exit gate for the prefix-spec profile: every stream OK, zero
     steady-state recompiles and zero leaked KV blocks on both legs;
     the optimized leg must actually hit the prefix cache (fewer full
-    prompt prefills than streams) and, on full-size runs, clear the
-    1.5x token-throughput bar over the no-prefix-cache baseline."""
+    prompt prefills than streams, fewer prefill chunks than the
+    baseline) and accept speculated tokens.  ``speedup_tokens_per_s``
+    is reported, not gated: a rate means something only on the chip."""
     for leg in (report["baseline"], report["optimized"]):
         if set(leg["statuses"]) != {"OK"}:
             return False
@@ -604,8 +605,6 @@ def _prefix_spec_ok(report, require_speedup=True):
     if opt["prefill_chunks"] >= report["baseline"]["prefill_chunks"]:
         return False
     if opt["spec_proposed"] < 1 or opt["spec_accepted"] < 1:
-        return False
-    if require_speedup and report["speedup_tokens_per_s"] < 1.5:
         return False
     return True
 
@@ -864,7 +863,7 @@ def run_sharded_decode_bench(streams, slots, block_size, max_prompt,
     }
 
 
-def _sharded_decode_ok(report, smoke=False):
+def _sharded_decode_ok(report):
     """Exit gate for the sharded-decode profile: on BOTH equal-device
     legs every stream finishes OK, every OK stream (greedy and sampled)
     is token-identical to the single-device reference, and zero
@@ -872,17 +871,10 @@ def _sharded_decode_ok(report, smoke=False):
     consume the same device count and the sharded leg must report the
     declared tp_degree.  The static collective AND memory models must
     both match the measured per-step reality exactly (calls, bytes, and
-    peak-bytes), the decode step must pay ZERO gathers, the decode-step
-    accounting region must drain, and the compute-parallel leg must hold
-    >= 0.8x the per-device throughput of tp1 (the gather-tax deletion
-    gate; the PR 15 gather-at-use wrapper measured 0.494x-0.825x).
-
-    The throughput ratio is waived under ``--smoke``: the smoke model is
-    a handful of microseconds of math per step, so the ratio there
-    measures host-process scheduling noise, not the collective bill.
-    Committed artifacts are produced by a full run and carry the gate
-    (test_committed_bench_sharded_decode_artifact_meets_gates re-checks
-    it on the committed JSON)."""
+    peak-bytes), the decode step must pay ZERO gathers (the count that
+    says the gather-at-use wrapper is gone), and the decode-step
+    accounting region must drain.  ``relative_tokens_per_s`` is
+    reported, not gated: a rate means something only on the chip."""
     for leg in (report["tp1"], report["tp2"]):
         if set(leg["statuses"]) != {"OK"}:
             return False
@@ -902,8 +894,6 @@ def _sharded_decode_ok(report, smoke=False):
     if not mem["static_matches_runtime"]:
         return False
     if mem["runtime_peak_bytes"] <= 0 or mem["live_bytes_after"] != 0:
-        return False
-    if not smoke and report["relative_tokens_per_s"] < 0.8:
         return False
     return True
 
@@ -1172,7 +1162,7 @@ def _disagg_ok(report):
     both legs (``memory.balanced``).  The >= 1.2x goodput bar is
     reported, not gated — on a
     shared-core CPU host the tiers contend for the same silicon (see
-    the artifact's ``speedup_goodput`` and docs/SERVING.md)."""
+    the report's ``speedup_goodput`` and docs/SERVING.md)."""
     for leg in (report["colocated"], report["disagg"]):
         if leg["fired"] != leg["arrivals"]:
             return False
@@ -1195,8 +1185,7 @@ def _disagg_ok(report):
 
 
 def run_deploy_bench(rate_hz, duration_s, slots, block_size, max_prompt,
-                     max_new, seed, model_cfg, replicas=2, swap_ttft_x=5.0,
-                     time_scale=1.0):
+                     max_new, seed, model_cfg, replicas=2, time_scale=1.0):
     """Live weight hot-swap under OPEN-loop load (serving/deploy.py).
 
     One ``FleetRouter`` (``replicas`` decode replicas) serves a seeded
@@ -1209,10 +1198,10 @@ def run_deploy_bench(rate_hz, duration_s, slots, block_size, max_prompt,
     gates: zero dropped streams (every arrival terminates OK and the
     ledger conserves), both generations observed among the OK streams
     (the swap really overlapped traffic), zero steady-state recompiles
-    on the NEW engines and on the RETIRED generation-1 engines, zero
-    leaked KV blocks fleet-wide (HBM accountant), and TTFT p99 for
-    streams submitted during the swap window within ``swap_ttft_x`` of
-    the steady-state p99."""
+    on the NEW engines and on the RETIRED generation-1 engines, and zero
+    leaked KV blocks fleet-wide (HBM accountant).  TTFT p99 for streams
+    submitted during the swap window is reported beside the steady-state
+    p99, not gated."""
     import shutil
     import tempfile
 
@@ -1459,7 +1448,6 @@ def run_deploy_bench(rate_hz, duration_s, slots, block_size, max_prompt,
                 "max_new_tokens": max_new,
                 "sampled_every": 4,
                 "swap_at_arrival": swap_at,
-                "swap_ttft_x": swap_ttft_x,
                 "seed": seed,
                 "model": dict(model_cfg),
             },
@@ -1498,9 +1486,9 @@ def _deploy_bench_ok(report):
     stream ends OK (zero dropped), the ledger conserves and pools drain,
     the swap commits generation 2 with streams observed finishing on
     BOTH generations and none torn, zero steady-state recompiles on the
-    new AND the retired engines, zero leaked KV blocks (per-engine and
-    HBM-accountant-wide), and the swap-window TTFT p99 stays within the
-    declared ``swap_ttft_x`` of steady state."""
+    new AND the retired engines, and zero leaked KV blocks (per-engine
+    and HBM-accountant-wide).  The swap-window and steady TTFT p99 are
+    reported, not gated: a latency means something only on the chip."""
     wl = report["workload"]
     if wl["fired"] != wl["arrivals"]:
         return False
@@ -1531,14 +1519,7 @@ def _deploy_bench_ok(report):
     for snap in report["retired_engines"].values():
         if snap["steady_state_recompiles"] != 0:
             return False
-    if not report["memory"]["balanced"]:
-        return False
-    if swap["ttft_p99_during_swap_ms"] is not None \
-            and swap["ttft_p99_steady_ms"] is not None \
-            and swap["ttft_p99_during_swap_ms"] > (
-                wl["swap_ttft_x"] * max(swap["ttft_p99_steady_ms"], 1.0)):
-        return False
-    return True
+    return bool(report["memory"]["balanced"])
 
 
 def _main_sharded_decode(args, ap):
@@ -1559,7 +1540,7 @@ def _main_sharded_decode(args, ap):
     report = run_sharded_decode_bench(
         args.streams, args.slots, args.block_size, args.max_prompt,
         args.max_new, args.seed, model_cfg, tp=args.tp)
-    _write_artifact(report, args.out)
+    _write_report(report, args.out)
     for key in ("tp1", "tp2"):
         leg = report[key]
         print("%s: %d engine(s) x tp=%d (%d device(s))  %s tok/s  "
@@ -1578,9 +1559,8 @@ def _main_sharded_decode(args, ap):
           "static==runtime: %s"
           % (mem["temps_per_step"], mem["runtime_peak_bytes"],
              mem["static_matches_runtime"]))
-    print("relative: %sx  wrote %s"
-          % (report["relative_tokens_per_s"], args.out))
-    return 0 if _sharded_decode_ok(report, smoke=args.smoke) else 1
+    print("relative: %sx" % report["relative_tokens_per_s"])
+    return 0 if _sharded_decode_ok(report) else 1
 
 
 def _main_prefix_spec(args, ap):
@@ -1603,7 +1583,7 @@ def _main_prefix_spec(args, ap):
         streams, slots, block_size, chunk, max_prompt, max_new,
         args.seed, model_cfg, spec_k=spec_k,
         shared_chunks=shared_chunks)
-    _write_artifact(report, args.out)
+    _write_report(report, args.out)
     b, o = report["baseline"], report["optimized"]
     print("baseline:  %s tok/s  ttft p50/p99: %s/%s ms  "
           "prefill chunks: %d"
@@ -1614,10 +1594,8 @@ def _main_prefix_spec(args, ap):
           % (o["tokens_per_s"], o["ttft_ms"]["p50"], o["ttft_ms"]["p99"],
              o["prefill_chunks"], o["prefix_hit_rate"], o["cow_forks"],
              o["spec_accept_rate"]))
-    print("speedup: %sx  wrote %s"
-          % (report["speedup_tokens_per_s"], args.out))
-    return 0 if _prefix_spec_ok(report,
-                                require_speedup=not args.smoke) else 1
+    print("speedup: %sx" % report["speedup_tokens_per_s"])
+    return 0 if _prefix_spec_ok(report) else 1
 
 
 def _main_fleet_decode(args, ap):
@@ -1638,13 +1616,12 @@ def _main_fleet_decode(args, ap):
     report = run_fleet_decode_bench(
         args.streams, args.slots, args.block_size, args.max_prompt,
         args.max_new, args.seed, model_cfg, replicas=args.replicas)
-    _write_artifact(report, args.out)
+    _write_report(report, args.out)
     print("fleet-decode: %s tok/s  ttft p50/p99: %s/%s ms  "
           "handoffs: %d  fenced: %d  drained: %s"
           % (report["tokens_per_s"], report["ttft_ms"]["p50"],
              report["ttft_ms"]["p99"], report["handoffs"],
              report["fenced"], report["drained_mid_run"]))
-    print("wrote %s" % args.out)
     return 0 if _fleet_decode_ok(report) else 1
 
 
@@ -1662,7 +1639,7 @@ def _main_decode(args, ap):
     report = run_decode_bench(args.streams, args.slots, args.block_size,
                               args.max_prompt, args.max_new, args.seed,
                               model_cfg)
-    _write_artifact(report, args.out)
+    _write_report(report, args.out)
     c, s = report["continuous"], report["static"]
     print("continuous: %s tok/s  ttft p50/p99: %s/%s ms  avg_live: %s"
           % (c["tokens_per_s"], c["ttft_ms"]["p50"], c["ttft_ms"]["p99"],
@@ -1670,10 +1647,9 @@ def _main_decode(args, ap):
     print("static:     %s tok/s  ttft p50/p99: %s/%s ms  avg_live: %s"
           % (s["tokens_per_s"], s["ttft_ms"]["p50"], s["ttft_ms"]["p99"],
              s["avg_live_slots"]))
-    print("speedup: %sx  steady-state recompiles: %d/%d  wrote %s"
+    print("speedup: %sx  steady-state recompiles: %d/%d"
           % (report["speedup_tokens_per_s"],
-             c["steady_state_recompiles"], s["steady_state_recompiles"],
-             args.out))
+             c["steady_state_recompiles"], s["steady_state_recompiles"]))
     return 0 if _decode_ok(report) else 1
 
 
@@ -1700,7 +1676,7 @@ def _main_disagg(args, ap):
         prefill_replicas=args.prefill_replicas,
         slo_ttft_ms=args.slo_ttft_ms, slo_tpot_ms=args.slo_tpot_ms,
         time_scale=args.time_scale)
-    _write_artifact(report, args.out)
+    _write_report(report, args.out)
     for key in ("colocated", "disagg"):
         leg = report[key]
         g = leg["goodput"]
@@ -1713,10 +1689,10 @@ def _main_disagg(args, ap):
     mem = report["memory"]
     print("memory: %d kv region(s), %d byte(s) allocated, balanced: %s"
           % (mem["kv_regions"], mem["kv_alloc_bytes"], mem["balanced"]))
-    print("handoffs: %d (failed %d)  speedup: %sx  wrote %s"
+    print("handoffs: %d (failed %d)  speedup: %sx"
           % (report["disagg"]["handoffs"]["handoffs"],
              report["disagg"]["handoffs"]["handoff_failures"],
-             report["speedup_goodput"], args.out))
+             report["speedup_goodput"]))
     return 0 if _disagg_ok(report) else 1
 
 
@@ -1746,9 +1722,8 @@ def _main_deploy(args, ap):
     report = run_deploy_bench(
         rate_hz, duration_s, args.slots, args.block_size,
         args.max_prompt, args.max_new, args.seed, model_cfg,
-        replicas=args.replicas, swap_ttft_x=args.swap_ttft_x,
-        time_scale=args.time_scale)
-    _write_artifact(report, args.out)
+        replicas=args.replicas, time_scale=args.time_scale)
+    _write_report(report, args.out)
     swap = report["swap"]
     print("deploy: %d stream(s) all %s  by generation: %s  torn: %d"
           % (report["workload"]["arrivals"], report["statuses"],
@@ -1759,10 +1734,9 @@ def _main_deploy(args, ap):
              swap["handoffs"] or 0, swap["fenced"] or 0,
              swap["warmup_compiles"]))
     print("ttft p99: %s ms during swap (%d stream(s)) vs %s ms steady  "
-          "memory balanced: %s  wrote %s"
+          "memory balanced: %s"
           % (swap["ttft_p99_during_swap_ms"], swap["streams_during_swap"],
-             swap["ttft_p99_steady_ms"], report["memory"]["balanced"],
-             args.out))
+             swap["ttft_p99_steady_ms"], report["memory"]["balanced"]))
     return 0 if _deploy_bench_ok(report) else 1
 
 
@@ -1775,57 +1749,44 @@ def _main_batch(args, ap):
               for s in args.shapes.split(",")]
     report = run_bench(args.clients, args.requests, shapes, args.max_batch,
                        args.linger_ms, args.timeout_ms, args.max_queue)
-    _write_artifact(report, args.out)
+    _write_report(report, args.out)
     print("throughput: %s req/s  p50/p95/p99: %s/%s/%s ms  avg_batch: %s  "
           "steady-state recompiles: %d"
           % (report["throughput_rps"], report["latency_ms"]["p50"],
              report["latency_ms"]["p95"], report["latency_ms"]["p99"],
              report["avg_batch"], report["steady_state_recompiles"]))
-    print("wrote %s" % args.out)
     return 0 if report["steady_state_recompiles"] == 0 else 1
 
 
-def _write_artifact(report, out):
+def _write_report(report, out):
+    """The report goes where ``--out`` says; with no ``--out`` it is
+    printed to stdout, ahead of the summary lines."""
+    if out is None:
+        json.dump(report, sys.stdout, indent=2)
+        sys.stdout.write("\n")
+        return
     with open(out, "w") as f:
         json.dump(report, f, indent=2)
         f.write("\n")
+    print("wrote %s" % out)
 
 
-# The profile registry: ONE row per profile — argparse choices, the
-# default artifact path, pre-import environment, and the runner all
-# derive from here (tests/test_disagg.py drift-gates this table against
-# the module docstring and the committed artifacts).
+# The profile registry: ONE row per profile — argparse choices,
+# pre-import environment, and the runner all derive from here
+# (tests/test_disagg.py drift-gates this table against the module
+# docstring).
 PROFILES = {
-    "batch": {
-        "artifact": "BENCH_SERVE.json",
-        "run": _main_batch,
-    },
-    "decode": {
-        "artifact": "BENCH_DECODE.json",
-        "run": _main_decode,
-    },
-    "fleet-decode": {
-        "artifact": "BENCH_FLEET_DECODE.json",
-        "run": _main_fleet_decode,
-    },
-    "prefix-spec": {
-        "artifact": "BENCH_PREFIX_SPEC.json",
-        "run": _main_prefix_spec,
-    },
+    "batch": {"run": _main_batch},
+    "decode": {"run": _main_decode},
+    "fleet-decode": {"run": _main_fleet_decode},
+    "prefix-spec": {"run": _main_prefix_spec},
     "sharded-decode": {
-        "artifact": "BENCH_SHARDED_DECODE.json",
         "run": _main_sharded_decode,
         # the mesh needs real (virtual) devices — set before jax loads
         "env": {"XLA_FLAGS": "--xla_force_host_platform_device_count=8"},
     },
-    "disagg": {
-        "artifact": "BENCH_DISAGG.json",
-        "run": _main_disagg,
-    },
-    "deploy": {
-        "artifact": "BENCH_DEPLOY.json",
-        "run": _main_deploy,
-    },
+    "disagg": {"run": _main_disagg},
+    "deploy": {"run": _main_deploy},
 }
 
 
@@ -1873,18 +1834,12 @@ def main(argv=None):
                     help="[disagg] p99 time-to-first-token SLO")
     ap.add_argument("--slo-tpot-ms", type=float, default=150.0,
                     help="[disagg] p99 time-per-output-token SLO")
-    ap.add_argument("--swap-ttft-x", type=float, default=5.0,
-                    help="[deploy] allowed TTFT p99 multiple during the "
-                         "swap window vs steady state")
     ap.add_argument("--out", default=None,
-                    help="artifact path (default BENCH_SERVE.json / "
-                         "BENCH_DECODE.json by profile)")
+                    help="where the JSON report goes (default: stdout)")
     ap.add_argument("--smoke", action="store_true",
                     help="small fast run for tier-1 (overrides sizes)")
     args = ap.parse_args(argv)
     prof = PROFILES[args.profile]
-    if args.out is None:
-        args.out = os.path.join(REPO, prof["artifact"])
     for key, val in prof.get("env", {}).items():
         os.environ.setdefault(key, val)
     return prof["run"](args, ap)
