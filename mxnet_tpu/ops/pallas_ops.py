@@ -6,15 +6,15 @@ the accelerator kernels are Pallas: tiled flash attention with the streaming
 log-sum-exp softmax, keeping the working set in VMEM and the QK^T / PV matmuls
 on the MXU, forward and backward.
 
-One set of three kernels (forward, query gradient, key/value gradient) serves
-every static mask: none, causal ('top' / 'bottom' aligned) and the block
-diffusion mask over ``[noised; clean]`` rows.  A mask is a function of a
-row's and a column's index; from it the wrapper works out on the host, per
-query tile, which key tiles hold a visible pair (the others are never
-visited: no DMA, no MXU pass) and which are wholly visible (no masking).
-Query heads may outnumber key/value heads (grouped-query attention): the
-kernels index the shared key/value head, and the key/value gradient sums
-over the group inside the kernel.
+One pair of kernels (the forward; one backward for the query, key and value
+gradients) serves every static mask: none, causal ('top' / 'bottom' aligned)
+and the block diffusion mask over ``[noised; clean]`` rows.  A mask is a
+function of a row's and a column's index; from it the wrapper works out on
+the host, per query tile, which key tiles hold a visible pair (the others
+are never visited: no DMA, no MXU pass) and which are wholly visible (no
+masking).  Query heads may outnumber key/value heads (grouped-query
+attention): the kernels index the shared key/value head, and the key/value
+gradient sums over the group inside the kernel.
 
 On a TPU backend the entry points run the kernels, and a kernel the compiler
 refuses is an error the caller sees.  Elsewhere they run the dense XLA
@@ -165,7 +165,7 @@ class _Plan:
         self.n_k = (Tk + self.pad_k) // self.block_k
         self.mask, self.scale = mask, float(scale)
         self.mxu_dtype, self.interpret = mxu_dtype, interpret
-        self.k_of_q, self.q_of_k = _tile_tables(
+        self.k_of_q, _ = _tile_tables(
             mask, Tq, Tk, self.n_q, self.n_k, self.block_q, self.block_k)
 
     def keep(self, q_tile, k_tile, transposed=False):
@@ -298,12 +298,25 @@ def _attention_fwd_pallas(plan, q, k, v):
 
 
 def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
-    """(dq, dk, dv) blockwise, scores recomputed tile by tile from the
-    forward's log-sum-exp: one kernel per query tile over its visited key
-    tiles for dq, one per key tile over the query tiles that see it (and
-    over the query heads that share the key/value head) for dk and dv.
-    The second works on transposed tiles, so that the per-row statistics
-    enter as rows and no tile is transposed in the kernel."""
+    """(dq, dk, dv) from one kernel, scores recomputed tile by tile from the
+    forward's log-sum-exp (``lse`` as the forward call keeps it, (B * H,
+    padded T)).  The grid is the forward's: (batch * query heads, query
+    tiles, visited key tiles).  Every visited tile is computed once, key
+    rows by query columns, so that the per-row statistics enter as
+    lane-dense rows and the two key-side products take the tile as it
+    stands: scores, exponentials, mask (partly visible tiles only), dP and
+    dS, then ``dv += P^T dO``, ``dk += dS^T q`` and ``dq^T += k^T dS``: no
+    product turns a tile.
+
+    dq of a query tile adds up over its consecutive steps in scratch,
+    transposed, and is turned once a query tile.  dk and dv are revisited
+    out of order, so one key/value head's whole (padded Tk, D) float32
+    gradients are the output blocks: they stay in VMEM over the head's
+    ``G`` consecutive query heads, are zeroed at the group's first step and
+    scaled at its last, and each tile adds its (block_k, D) rows in place.
+    VMEM therefore grows with the key length (4 MiB a gradient at 8,192 x
+    128, twice for the pipeline's second buffer); nothing in HBM grows
+    with tiles x heads."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -311,6 +324,7 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
 
     p = plan
     bq, bk, D, G = p.block_q, p.block_k, p.D, p.G
+    _, _, S = p.k_of_q
     cdt = p.mxu_dtype
     BH, BHkv = p.B * p.Hq, p.B * p.Hkv
     Tq_t, Tk_t = p.n_q * bq, p.n_k * bk
@@ -318,32 +332,45 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
     qf = _pad_rows(q, p.pad_q).reshape(BH, Tq_t, D)
     kf = _pad_rows(k, p.pad_k).reshape(BHkv, Tk_t, D)
     vf = _pad_rows(v, p.pad_k).reshape(BHkv, Tk_t, D)
+    # k^T for dq: turned here once (the key/value heads are few), where the
+    # kernel would turn a (block_k, block_q) tile of dS at every step
+    kt = jnp.swapaxes(kf, 1, 2)                               # (BHkv, D, Tk)
     # delta_i = sum_j P_ij dP_ij = <dO_i, O_i>: one fused pass in XLA
     delta = jnp.sum(gf.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)                   # (BH, Tq, 1)
+                    axis=-1)[:, None]                         # (BH, 1, Tq)
+    lse = lse[:, None]
 
-    # -- dq ---------------------------------------------------------------
-    _, _, S = p.k_of_q
-
-    def dq_kernel(kidx_ref, flag_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
-                  delta_ref, dq_ref, acc_ref):
-        qi, si = pl.program_id(1), pl.program_id(2)
+    def kernel(kidx_ref, flag_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
+               kt_ref, v_ref, dq_ref, dk_ref, dv_ref, dqt_acc):
+        b, qi, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         at = qi * S + si
         flag = flag_ref[at]
 
+        @pl.when((b % G == 0) & (qi == 0) & (si == 0))
+        def _():
+            dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+            dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+
         @pl.when(si == 0)
         def _():
-            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+            dqt_acc[...] = jnp.zeros(dqt_acc.shape, jnp.float32)
 
         def accumulate(masked):
-            k_blk = k_ref[...].astype(cdt)
-            s = _nt(q_ref[...].astype(cdt), k_blk) * p.scale
-            e = jnp.exp(s - lse_ref[...])
+            kidx = kidx_ref[at]
+            rows = pl.ds(pl.multiple_of(kidx * bk, bk), bk)
+            q_blk = q_ref[...].astype(cdt)
+            g_blk = g_ref[...].astype(cdt)
+            st = _nt(k_ref[...].astype(cdt), q_blk) * p.scale    # (bk, bq)
+            et = jnp.exp(st - lse_ref[...])
             if masked:
-                e = jnp.where(p.keep(qi, kidx_ref[at]), e, 0.0)
-            dp = _nt(g_ref[...].astype(cdt), v_ref[...].astype(cdt))
-            ds = e * (dp - delta_ref[...])
-            acc_ref[...] += jnp.dot(ds.astype(cdt), k_blk,
+                et = jnp.where(p.keep(qi, kidx, transposed=True), et, 0.0)
+            dv_ref[rows, :] += jnp.dot(et.astype(cdt), g_blk,
+                                       preferred_element_type=jnp.float32)
+            dpt = _nt(v_ref[...].astype(cdt), g_blk)
+            dst = (et * (dpt - delta_ref[...])).astype(cdt)
+            dk_ref[rows, :] += jnp.dot(dst, q_blk,
+                                       preferred_element_type=jnp.float32)
+            dqt_acc[...] += jnp.dot(kt_ref[...].astype(cdt), dst,
                                     preferred_element_type=jnp.float32)
 
         pl.when(flag == 1)(lambda: accumulate(True))
@@ -351,97 +378,55 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
 
         @pl.when(si == S - 1)
         def _():
-            dq_ref[...] = (acc_ref[...] * p.scale).astype(dq_ref.dtype)
+            dq_ref[...] = (dqt_acc[...].T * p.scale).astype(dq_ref.dtype)
 
-    q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j, kidx, flag: (b, i, 0))
-    col_spec = pl.BlockSpec((None, bq, 1),
-                            lambda b, i, j, kidx, flag: (b, i, 0))
-    kv_spec = pl.BlockSpec(
-        (None, bk, D), lambda b, i, j, kidx, flag: (b // G, kidx[i * S + j], 0))
+        @pl.when((b % G == G - 1) & (qi == p.n_q - 1) & (si == S - 1))
+        def _():
+            dk_ref[...] *= p.scale
+
+    def q_side(b, i, j, kidx, flag):
+        return (b, i, 0)
+
+    def row_side(b, i, j, kidx, flag):
+        return (b, 0, i)
+
+    def k_side(b, i, j, kidx, flag):
+        return (b // G, kidx[i * S + j], 0)
+
+    def kt_side(b, i, j, kidx, flag):
+        return (b // G, 0, kidx[i * S + j])
+
+    q_spec = pl.BlockSpec((None, bq, D), q_side)
+    row_spec = pl.BlockSpec((None, 1, bq), row_side)
+    k_spec = pl.BlockSpec((None, bk, D), k_side)
+    held_spec = pl.BlockSpec((None, Tk_t, D),
+                             lambda b, i, j, kidx, flag: (b // G, 0, 0))
+    held = jax.ShapeDtypeStruct((BHkv, Tk_t, D), jnp.float32)
+    # dk and dv whole, each with the pipeline's second buffer, beside the 16
+    # MiB a kernel has by default for its tiles and temporaries; past 100 of
+    # a v5e core's 128 MiB the compiler refuses the call, and says so
+    vmem_bytes = min(16 * 2 ** 20 + 2 * 2 * Tk_t * D * 4, 100 * 2 ** 20)
     p.count_tiles()
-    dq = pl.pallas_call(
-        dq_kernel,
+    dq, dk, dv = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(BH, p.n_q, S),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq_t, D), q.dtype),
+            in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec,
+                      pl.BlockSpec((None, D, bk), kt_side), k_spec],
+            out_specs=[q_spec, held_spec, held_spec],
+            scratch_shapes=[pltpu.VMEM((D, bq), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((BH, Tq_t, D), q.dtype), held, held],
+        # every axis in order: dk and dv are added to across all three
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=p.interpret, name="attention_bwd_dq",
-    )(jnp.asarray(p.k_of_q[0]), jnp.asarray(p.k_of_q[1]), qf, kf, vf, gf,
-      lse, delta)
-
-    # -- dk, dv -----------------------------------------------------------
-    _, _, Sq = p.q_of_k
-    steps = G * Sq
-    lse_row = lse.reshape(BH, 1, Tq_t)
-    delta_row = delta.reshape(BH, 1, Tq_t)
-
-    def dkv_kernel(qidx_ref, flag_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
-                   delta_ref, dk_ref, dv_ref, dk_acc, dv_acc):
-        ki, ti = pl.program_id(1), pl.program_id(2)
-        at = ki * Sq + ti % Sq
-        flag = flag_ref[at]
-
-        @pl.when(ti == 0)
-        def _():
-            dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
-            dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
-
-        def accumulate(masked):
-            q_blk = q_ref[...].astype(cdt)
-            g_blk = g_ref[...].astype(cdt)
-            st = _nt(k_ref[...].astype(cdt), q_blk) * p.scale    # (bk, bq)
-            et = jnp.exp(st - lse_ref[...])
-            if masked:
-                et = jnp.where(p.keep(qidx_ref[at], ki, transposed=True),
-                               et, 0.0)
-            dv_acc[...] += jnp.dot(et.astype(cdt), g_blk,
-                                   preferred_element_type=jnp.float32)
-            dpt = _nt(v_ref[...].astype(cdt), g_blk)
-            dst = et * (dpt - delta_ref[...])
-            dk_acc[...] += jnp.dot(dst.astype(cdt), q_blk,
-                                   preferred_element_type=jnp.float32)
-
-        pl.when(flag == 1)(lambda: accumulate(True))
-        pl.when(flag == 2)(lambda: accumulate(False))
-
-        @pl.when(ti == steps - 1)
-        def _():
-            dk_ref[...] = (dk_acc[...] * p.scale).astype(dk_ref.dtype)
-            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-
-    def q_side(b, i, t, qidx, flag):
-        return (b * G + t // Sq, qidx[i * Sq + t % Sq], 0)
-
-    def row_side(b, i, t, qidx, flag):
-        return (b * G + t // Sq, 0, qidx[i * Sq + t % Sq])
-
-    qg_spec = pl.BlockSpec((None, bq, D), q_side)
-    row_spec = pl.BlockSpec((None, 1, bq), row_side)
-    k_spec = pl.BlockSpec((None, bk, D), lambda b, i, t, qidx, flag: (b, i, 0))
-    p.count_tiles()
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(BHkv, p.n_k, steps),
-            in_specs=[qg_spec, k_spec, k_spec, qg_spec, row_spec, row_spec],
-            out_specs=[k_spec, k_spec],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((BHkv, Tk_t, D), k.dtype),
-                   jax.ShapeDtypeStruct((BHkv, Tk_t, D), v.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=p.interpret, name="attention_bwd_dkv",
-    )(jnp.asarray(p.q_of_k[0]), jnp.asarray(p.q_of_k[1]), qf, kf, vf, gf,
-      lse_row, delta_row)
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes),
+        interpret=p.interpret, name="attention_bwd",
+    )(jnp.asarray(p.k_of_q[0]), jnp.asarray(p.k_of_q[1]), qf, gf, lse, delta,
+      kf, kt, vf)
 
     dq = dq.reshape(p.B, p.Hq, Tq_t, D)[:, :, :p.Tq]
-    dk = dk.reshape(p.B, p.Hkv, Tk_t, D)[:, :, :p.Tk]
-    dv = dv.reshape(p.B, p.Hkv, Tk_t, D)[:, :, :p.Tk]
+    dk = dk.reshape(p.B, p.Hkv, Tk_t, D)[:, :, :p.Tk].astype(k.dtype)
+    dv = dv.reshape(p.B, p.Hkv, Tk_t, D)[:, :, :p.Tk].astype(v.dtype)
     return dq, dk, dv
 
 
@@ -470,7 +455,7 @@ def _attention(q, k, v, mask, scale, precision, interpret, block_q, block_k,
                scope=None):
     """Differentiable attention under a static mask: the Pallas kernels on
     a TPU (or where ``interpret`` is given), the XLA reference elsewhere.
-    ``scope`` names the backward kernels' operations as the caller named the
+    ``scope`` names the backward kernel's operations as the caller named the
     forward's."""
     import jax
     from jax.ad_checkpoint import checkpoint_name
@@ -499,8 +484,7 @@ def _attention(q, k, v, mask, scale, precision, interpret, block_q, block_k,
     def f_bwd(res, g):
         q_, k_, v_, out, lse = res
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
-            return _attention_bwd_pallas(plan, q_, k_, v_, out,
-                                         lse[..., None], g)
+            return _attention_bwd_pallas(plan, q_, k_, v_, out, lse, g)
 
     f.defvjp(f_fwd, f_bwd)
     return f(q, k, v)
@@ -512,10 +496,11 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
     kernels, interpreted or compiled.
 
     q: (B, H, T, D); k/v: (B, Hkv, Tk, D), H a multiple of Hkv.
-    Differentiable: a custom_vjp whose backward is blockwise too (the
-    scores are recomputed tile by tile from the saved log-sum-exp; nothing
-    of size T x Tk is ever held), and skips the tiles above the diagonal as
-    the forward does.
+    Differentiable: a custom_vjp whose backward is one blockwise kernel
+    (each visited tile's scores are recomputed once from the saved
+    log-sum-exp and give dq, dk and dv; nothing of size T x Tk is ever
+    held, one key/value head's dk and dv stay in VMEM while they are added
+    to), and skips the tiles above the diagonal as the forward does.
 
     ``causal`` may be False, True, 'top', or 'bottom'.  With mismatched q/k
     lengths the diagonal's alignment is ambiguous, so bare ``True`` refuses
@@ -551,8 +536,9 @@ def block_mask_attention(q, k, v, seq_len, block_length, scale=None,
     ``k``/``v`` (B, Hkv, 2L, D) hold the noised copy of a sequence of
     ``seq_len`` = L positions followed by the clean one, under
     ``block_diffusion_mask``.  Only about a quarter of the square is
-    visible; forward and backward visit the tiles that hold a visible pair
-    and mask the partly visible ones from row and column indices.  At
+    visible; the forward kernel and the backward kernel each visit the
+    tiles that hold a visible pair once and mask the partly visible ones
+    from row and column indices.  At
     ``precision`` 'default' the matrix units take bfloat16 operands and
     accumulate in float32, as XLA does with float32 inputs."""
     import jax

@@ -143,6 +143,155 @@ def test_flash_attention_backward_kernels_8_heads_to_1(causal):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-3
 
 
+def _pallas_calls(jaxpr):
+    """The names of the ``pallas_call``s a jaxpr holds, at any depth."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_calls(sub)
+    return names
+
+
+@pytest.mark.parametrize("entry", ["block_mask_attention", "flash_attention"])
+def test_backward_is_one_kernel(entry):
+    """Both public entries: the backward pass alone (the forward's results
+    handed in) holds one ``pallas_call``, ``attention_bwd``."""
+    q, k, v = _qkv(np.random.RandomState(4), 4, 2, 64)
+    if entry == "block_mask_attention":
+        def f(q, k, v):
+            return pallas_ops.block_mask_attention(
+                q, k, v, 32, 4, interpret=True, block_q=32, block_k=32)
+    else:
+        def f(q, k, v):
+            return pallas_ops.flash_attention(q, k, v, causal=True,
+                                              interpret=True)
+    out, vjp = jax.vjp(f, q, k, v)
+    assert _pallas_calls(jax.make_jaxpr(vjp)(out).jaxpr) == ["attention_bwd"]
+    whole = _pallas_calls(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(f(q, k, v)), (0, 1, 2)))(q, k, v).jaxpr)
+    assert sorted(whole) == ["attention_bwd", "attention_fwd"]
+
+
+@pytest.mark.parametrize("heads_with_a_cotangent", [
+    (5,), (0, 1, 2, 4, 5, 6)], ids=["one_head", "all_but_a_groups_last"])
+def test_key_value_gradient_sums_over_its_query_heads(heads_with_a_cotangent):
+    """8 query heads to 2: a key/value head's dk and dv are held in the
+    kernel across its 4 query heads.  With the cotangent on one head of the
+    second group the first group's gradients are zero and the second's that
+    head's alone; with none on a group's last head they are still the sum
+    of the other three (a block zeroed or written at the wrong head)."""
+    length, dim = 64, 32
+    q, k, v = _qkv(np.random.RandomState(5), 8, 2, 2 * length, dim)
+    weight = np.zeros((1, 8, 2 * length, dim), np.float32)
+    weight[:, list(heads_with_a_cotangent)] = np.random.RandomState(6).normal(
+        0, 1, (1, len(heads_with_a_cotangent), 2 * length, dim))
+    weight = jnp.asarray(weight)
+    mask = pallas_ops.block_diffusion_mask(length, 4)
+
+    def kernels(q, k, v):
+        return jnp.sum(weight * pallas_ops.block_mask_attention(
+            q, k, v, length, 4, precision="highest", interpret=True,
+            block_q=32, block_k=64))
+
+    def oracle(q, k, v):
+        return jnp.sum(weight * pallas_ops._attention_reference(
+            q, k, v, None, dim ** -0.5, mask=mask))
+
+    got = jax.grad(kernels, (0, 1, 2))(q, k, v)
+    want = jax.grad(oracle, (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+    if heads_with_a_cotangent == (5,):
+        assert not np.any(np.asarray(got[1][:, 0])) \
+            and not np.any(np.asarray(got[2][:, 0]))
+        assert float(jnp.max(jnp.abs(got[1][:, 1]))) > 1e-2
+
+
+@pytest.mark.parametrize("case", ["block_mask_padded_both_ways",
+                                  "one_query_tile_three_key_tiles"])
+def test_ragged_lengths_with_grouped_heads(case):
+    """Padding on both axes under the block mask (80 rows in tiles of 32),
+    and a key length of three tiles (1,100 keys in 512s, padded) seen from
+    one query tile of 24 rows, 4 query heads to 2."""
+    dim = 32
+    rng = np.random.RandomState(7)
+    if case == "block_mask_padded_both_ways":
+        length = 40
+        q, k, v = _qkv(rng, 4, 2, 2 * length, dim)
+        mask = pallas_ops.block_diffusion_mask(length, 4)
+
+        def kernels(q, k, v):
+            return pallas_ops.block_mask_attention(
+                q, k, v, length, 4, precision="highest", interpret=True,
+                block_q=32, block_k=32)
+
+        def oracle(q, k, v):
+            return pallas_ops._attention_reference(q, k, v, None,
+                                                   dim ** -0.5, mask=mask)
+    else:
+        q = _qkv(rng, 4, 2, 24, dim)[0]
+        _, k, v = _qkv(rng, 4, 2, 1100, dim)
+
+        def kernels(q, k, v):
+            return pallas_ops.flash_attention(q, k, v, causal="bottom",
+                                              interpret=True)
+
+        def oracle(q, k, v):
+            return pallas_ops._attention_reference(q, k, v, "bottom",
+                                                   dim ** -0.5)
+
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(kernels(*a))), (0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(oracle(*a))), (0, 1, 2))(
+        q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
+def test_bfloat16_gradients_are_accumulated_in_float32():
+    """bfloat16 in, bfloat16 gradients out, each key/value gradient summed
+    over 8 query heads x 4 query tiles in float32 and rounded once: it lies
+    nearer the float32 reference than the same 32 contributions added up
+    in bfloat16 do."""
+    length, dim, tile = 64, 32, 32
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(
+        np.random.RandomState(8), 8, 1, 2 * length, dim))
+    mask = pallas_ops.block_diffusion_mask(length, 32)
+
+    def kernels(q, k, v):
+        return jnp.sum(pallas_ops.block_mask_attention(
+            q, k, v, length, 32, interpret=True, block_q=tile,
+            block_k=tile).astype(jnp.float32))
+
+    got = jax.grad(kernels, (0, 1, 2))(q, k, v)
+    assert all(x.dtype == jnp.bfloat16 for x in got)
+
+    wide = [x.astype(jnp.float32) for x in (q, k, v)]
+
+    @jax.jit
+    def share(head, rows):
+        """One query head's and query tile's share of the gradients."""
+        weight = jnp.zeros((1, 8, 2 * length, 1)).at[0, head].set(
+            ((jnp.arange(2 * length) // tile) == rows)[:, None] * 1.0)
+        return jax.grad(lambda q, k, v: jnp.sum(
+            weight * pallas_ops._attention_reference(
+                q, k, v, None, dim ** -0.5, mask=mask)), (1, 2))(*wide)
+
+    shares = [share(h, t) for h in range(8) for t in range(2 * length // tile)]
+    for i, mine in enumerate(got[1:]):
+        exact = sum(s[i] for s in shares)
+        narrow = jnp.zeros_like(exact, jnp.bfloat16)
+        for s in shares:
+            narrow = (narrow + s[i].astype(jnp.bfloat16)).astype(jnp.bfloat16)
+        gap = float(jnp.max(jnp.abs(mine.astype(jnp.float32) - exact)))
+        narrow_gap = float(jnp.max(jnp.abs(
+            narrow.astype(jnp.float32) - exact)))
+        assert gap < 0.5 * narrow_gap, (gap, narrow_gap)
+
+
 def test_default_precision_feeds_the_matrix_units_bfloat16():
     length = 32
     q, k, v = _qkv(np.random.RandomState(2), 2, 1, 2 * length)
@@ -167,7 +316,7 @@ def test_attention_counts_its_tiles():
     # per head 8 x 8 tiles of 32 x 32 over 256 rows; tile i of the noised
     # rows sees itself and clean tiles 0..i, of the clean rows clean 0..i:
     # sum over i < 4 of (2 i + 3) = 24 of 64
-    assert total % (4 * 64) == 0 and total // (4 * 64) >= 3  # fwd, dq, dkv
+    assert total == 2 * 4 * 64      # two grids: the forward's, the backward's
     assert visited * 64 == total * 24
 
 
@@ -475,13 +624,7 @@ def kernel_net(monkeypatch):
 
 def _kernels(jaxpr, name):
     """How many ``pallas_call``s of that name the jaxpr holds, at any depth."""
-    found = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found += eqn.params["name"] == name
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _kernels(sub, name)
-    return found
+    return _pallas_calls(jaxpr).count(name)
 
 
 def test_kept_out_and_lse_change_no_bit_of_the_gradient(kernel_net):
@@ -503,12 +646,11 @@ def test_forward_kernel_runs_once_a_layer_with_both_results_kept(
         kernel_net, flags, forward_kernels):
     """Two layers: with ``out`` and ``lse`` kept the recomputed layer has no
     use for the forward kernel; with one of them missing it runs again.  The
-    backward kernels run once a layer whatever is kept."""
+    backward kernel runs once a layer whatever is kept."""
     f, values = kernel_net(flags)
     jaxpr = jax.make_jaxpr(jax.grad(f))(values).jaxpr
     assert _kernels(jaxpr, "attention_fwd") == forward_kernels
-    assert _kernels(jaxpr, "attention_bwd_dq") == 2
-    assert _kernels(jaxpr, "attention_bwd_dkv") == 2
+    assert _kernels(jaxpr, "attention_bwd") == 2
 
 
 def test_build_keeps_the_attention_residuals():

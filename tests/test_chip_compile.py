@@ -11,6 +11,8 @@ inside a fixture of this one file and nowhere at import time: every xdist
 worker then collects the same tests and only the worker that runs this file
 loads the library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -150,7 +152,9 @@ def test_sharded_decode_step_compiles_for_four_chips(topo, monkeypatch):
 
 def test_block_mask_attention_compiles_for_v5e_forward_and_backward(one_chip):
     """32 query heads to 4 of 128 over 8,192 [noised; clean] rows, float32
-    in: the forward kernel and both backward kernels, tiles of 512 x 512."""
+    in: the forward kernel and the one backward kernel, tiles of 512 x 512,
+    with one key/value head's dk and dv (4 MiB each) held in VMEM, which
+    takes more than the 16 MiB a kernel has by default."""
     from mxnet_tpu.ops.pallas_ops import block_mask_attention
     q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32,
                              sharding=one_chip)
@@ -161,8 +165,9 @@ def test_block_mask_attention_compiles_for_v5e_forward_and_backward(one_chip):
             q, k, v, 4096, 4, interpret=False)), (0, 1, 2))
     ).lower(q, kv, kv).compile()
     text = compiled.as_text()
-    for kernel in ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv"):
-        assert kernel in text
+    # the custom calls carry the pallas_calls' names: %attention_bwd.1 = ...
+    assert set(re.findall(r"%(attention_\w+?)(?:\.\d+)? = ", text)) == {
+        "attention_fwd", "attention_bwd"}
     _fits(compiled)
 
 
