@@ -1,8 +1,9 @@
-"""Layers of a decoder language model trained by block diffusion over
-mixture-of-experts feed-forwards: weighted RMSNorm, grouped-query attention
-with query/key norm and rotary positions under the block-diffusion mask, the
-mixture-of-experts layer for the experts this chip holds, and the decoder
-layer that joins them.  gluon/model_zoo/block_diffusion.py builds a model of
+"""Layers of decoder language models over mixture-of-experts feed-forwards:
+weighted RMSNorm, grouped-query attention with query/key norm and rotary
+positions (under the block-diffusion mask, or causal over the keys a learned
+indexer picks for each query), the mixture-of-experts layer for the experts
+this chip holds, and the decoder layers that join them.
+gluon/model_zoo/block_diffusion.py and sparse_causal_lm.py build models of
 them from a configuration.
 """
 from __future__ import annotations
@@ -14,7 +15,8 @@ from ..block import HybridBlock
 from .basic_layers import Dense
 
 __all__ = ["RMSNorm", "BlockDiffusionAttention", "HeldExpertsMoE",
-           "BlockDiffusionDecoderLayer"]
+           "BlockDiffusionDecoderLayer", "IndexedSparseAttention",
+           "SparseDecoderLayer"]
 
 
 class RMSNorm(HybridBlock):
@@ -30,7 +32,50 @@ class RMSNorm(HybridBlock):
         return F._contrib_rms_norm(x, gamma, eps=self._eps)
 
 
-class BlockDiffusionAttention(HybridBlock):
+class _GroupedQueryAttention(HybridBlock):
+    """What the attention blocks share: q, k, v and o projections without
+    bias, ``heads`` query heads to ``kv_heads`` key/value heads of
+    ``head_dim``, and RMSNorm over each head's dimensions of q and of k."""
+
+    def __init__(self, hidden, heads, kv_heads, head_dim, epsilon, **kwargs):
+        super().__init__(**kwargs)
+        self._heads, self._kv_heads, self._head_dim = heads, kv_heads, head_dim
+        with self.name_scope():
+            self.q = self._proj(heads * head_dim, hidden, "q_")
+            self.k = self._proj(kv_heads * head_dim, hidden, "k_")
+            self.v = self._proj(kv_heads * head_dim, hidden, "v_")
+            self.o = self._proj(hidden, heads * head_dim, "o_")
+            self.q_norm = RMSNorm(head_dim, epsilon, prefix="q_norm_")
+            self.k_norm = RMSNorm(head_dim, epsilon, prefix="k_norm_")
+
+    @staticmethod
+    def _proj(units, in_units, prefix):
+        return Dense(units, in_units=in_units, use_bias=False, flatten=False,
+                     prefix=prefix)
+
+    @staticmethod
+    def _split(F, t, count, dim, norm=None):
+        """(B, T, count * dim) as (B, count, T, dim), each head normed."""
+        t = F.reshape(t, shape=(0, 0, count, dim))
+        if norm is not None:
+            t = norm(t)
+        return F.transpose(t, axes=(0, 2, 1, 3))
+
+    def _qkv(self, F, x, rotary):
+        """The three operands of the attention call, (B, H, T, D)."""
+        q = rotary(self._split(F, self.q(x), self._heads, self._head_dim,
+                               self.q_norm))
+        k = rotary(self._split(F, self.k(x), self._kv_heads, self._head_dim,
+                               self.k_norm))
+        return q, k, self._split(F, self.v(x), self._kv_heads, self._head_dim)
+
+    def _output(self, F, out):
+        """The attention's (B, H, T, D) through the output projection."""
+        return self.o(F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                                shape=(0, 0, -1)))
+
+
+class BlockDiffusionAttention(_GroupedQueryAttention):
     """Grouped-query attention over ``[noised; clean]`` rows: projections
     without bias, RMSNorm over each head's dimensions of q and of k, rotary
     embedding on all of them at the positions given (a noised row has its
@@ -40,38 +85,14 @@ class BlockDiffusionAttention(HybridBlock):
 
     def __init__(self, hidden, heads, kv_heads, head_dim, block_length,
                  rope_base=1e6, epsilon=1e-6, **kwargs):
-        super().__init__(**kwargs)
-        self._heads, self._kv_heads, self._head_dim = heads, kv_heads, head_dim
+        super().__init__(hidden, heads, kv_heads, head_dim, epsilon, **kwargs)
         self._block_length, self._rope_base = block_length, rope_base
-        with self.name_scope():
-            def proj(units, in_units, prefix):
-                return Dense(units, in_units=in_units, use_bias=False,
-                             flatten=False, prefix=prefix)
-            self.q = proj(heads * head_dim, hidden, "q_")
-            self.k = proj(kv_heads * head_dim, hidden, "k_")
-            self.v = proj(kv_heads * head_dim, hidden, "v_")
-            self.o = proj(hidden, heads * head_dim, "o_")
-            self.q_norm = RMSNorm(head_dim, epsilon, prefix="q_norm_")
-            self.k_norm = RMSNorm(head_dim, epsilon, prefix="k_norm_")
 
     def hybrid_forward(self, F, x, positions):
-        def heads(t, count, norm=None):
-            t = F.reshape(t, shape=(0, 0, count, self._head_dim))
-            if norm is not None:
-                t = norm(t)
-            t = F.transpose(t, axes=(0, 2, 1, 3))            # (B, H, 2L, D)
-            if norm is not None:
-                t = F._contrib_rotary_embedding(t, positions,
-                                                base=self._rope_base)
-            return t
-
-        q = heads(self.q(x), self._heads, self.q_norm)
-        k = heads(self.k(x), self._kv_heads, self.k_norm)
-        v = heads(self.v(x), self._kv_heads)
-        out = F._contrib_block_mask_attention(
-            q, k, v, seq_len=x.shape[1] // 2, block_length=self._block_length)
-        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
-        return self.o(out)
+        q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
+            t, positions, base=self._rope_base))
+        return self._output(F, F._contrib_block_mask_attention(
+            q, k, v, seq_len=x.shape[1] // 2, block_length=self._block_length))
 
 
 class HeldExpertsMoE(HybridBlock):
@@ -142,3 +163,110 @@ class BlockDiffusionDecoderLayer(HybridBlock):
     def hybrid_forward(self, F, x, positions):
         h = x + self.attn(self.attn_norm(x), positions)
         return h + self.moe(self.moe_norm(h))
+
+
+class IndexedSparseAttention(_GroupedQueryAttention):
+    """Causal grouped-query attention over the keys a learned indexer picks
+    for each query (DeepSeek Sparse Attention).  The main path is
+    ``BlockDiffusionAttention``'s (projections without bias, RMSNorm over
+    each head's dimensions of q and k, rotary, scale ``1 / sqrt(head_dim)``)
+    with the rotary frequencies in ``sections`` over three position rows.
+
+    The indexer reads the block's input detached: ``index_heads`` queries of
+    ``index_dim`` and one key of ``index_dim`` shared by them (LayerNorm, eps
+    1e-6), rotary on all of both at position row 0, the heads' weights ``W_w
+    u`` scaled by ``index_heads ** -0.5 * index_dim ** -0.5``; score
+    ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``; the ``topk`` largest
+    ``I[t, s <= t]`` are the keys query ``t`` attends, exactly
+    (``ops.decoder_ops.select_top_k``).  Inputs: ``x`` (B, L, hidden) and
+    ``positions`` (3, L).  Outputs: the block's rows; the indexer's loss
+    (1,): the KL divergence from the attention's distribution over the
+    picked keys (mean of the heads, detached) to the softmax of ``I`` over
+    them, over ``B * L``; and the picked pairs (B, L, L) int8, for a caller
+    that checks the selection (the decoder layer leaves them unused, and
+    they are then never written out).  The indexer's parameters get a
+    gradient from that loss alone, and nothing else gets one from it.
+
+    ``index_loss`` (non-trainable state, written by every training step)
+    holds the newest step's ``[1, the loss]``; ``profiler.totals()`` reads
+    it, when asked, under ``dsa.index_loss.<this block's prefix>`` (``count``
+    and ``max``): the step hands back one scalar, the language model's loss
+    and every layer's indexer loss summed, so this is where whoever trains
+    the model sees whether a layer's indexer follows its attention."""
+
+    def __init__(self, hidden, heads, kv_heads, head_dim, index_heads,
+                 index_dim, topk, rope_base=1e7, sections=None, epsilon=1e-6,
+                 **kwargs):
+        super().__init__(hidden, heads, kv_heads, head_dim, epsilon, **kwargs)
+        self._index_heads, self._index_dim = index_heads, index_dim
+        self._topk, self._rope_base = topk, rope_base
+        self._sections = tuple(sections or (head_dim // 2, 0, 0))
+        with self.name_scope():
+            self.index_q = self._proj(index_heads * index_dim, hidden,
+                                      "index_q_")
+            self.index_k = self._proj(index_dim, hidden, "index_k_")
+            self.index_w = self._proj(index_heads, hidden, "index_w_")
+            get = self.params.get
+            self.index_k_norm_gamma = get("index_k_norm_gamma",
+                                          shape=(index_dim,), init="ones")
+            self.index_k_norm_beta = get("index_k_norm_beta",
+                                         shape=(index_dim,), init="zeros")
+            self.index_loss = get("index_loss", shape=(2,), init="zeros",
+                                  grad_req="null", differentiable=False)
+        # as HeldExpertsMoE's load: the gauge keeps this leaf alive, not the
+        # block
+        state = self.index_loss
+        profiler.gauge("dsa.index_loss." + self.prefix,
+                       lambda: tuple(float(v) for v in state.data().asnumpy()))
+
+    def hybrid_forward(self, F, x, positions, index_k_norm_gamma,
+                       index_k_norm_beta, index_loss):
+        q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
+            t, positions, base=self._rope_base, sections=self._sections))
+
+        u = F.stop_gradient(x)
+        first_row = F.reshape(F.slice_axis(positions, axis=0, begin=0, end=1),
+                              shape=(-1,))
+        index_q = F._contrib_rotary_embedding(
+            self._split(F, self.index_q(u), self._index_heads,
+                        self._index_dim),
+            first_row, base=self._rope_base)
+        index_k = F._contrib_rotary_embedding(
+            F.LayerNorm(self.index_k(u), index_k_norm_gamma,
+                        index_k_norm_beta, eps=1e-6),
+            first_row, base=self._rope_base)
+        weights = self.index_w(u) * (self._index_heads ** -0.5
+                                     * self._index_dim ** -0.5)
+        scores, pairs = F._contrib_index_select(index_q, index_k, weights,
+                                                topk=self._topk)
+        out, lse = F._contrib_sparse_attention(q, k, v, pairs)
+        loss, out = F._contrib_index_loss(scores, pairs, q, k, lse, out)
+        if autograd.is_training() and isinstance(out, NDArray):
+            index_loss._set_data(F.concat(F.ones_like(loss), loss,
+                                          dim=0)._data)
+        return self._output(F, out), loss, pairs
+
+
+class SparseDecoderLayer(HybridBlock):
+    """``h = x + Attn(RMSNorm(x)); x' = h + MoE(RMSNorm(h))`` with
+    ``IndexedSparseAttention``; gives ``(x', the indexer's loss)``."""
+
+    def __init__(self, hidden, heads, kv_heads, head_dim, index_heads,
+                 index_dim, topk, width, experts_total, experts_per_token,
+                 experts_held, first_expert=0, rope_base=1e7, sections=None,
+                 epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.attn_norm = RMSNorm(hidden, epsilon, prefix="attn_norm_")
+            self.attn = IndexedSparseAttention(
+                hidden, heads, kv_heads, head_dim, index_heads, index_dim,
+                topk, rope_base, sections, epsilon, prefix="attn_")
+            self.moe_norm = RMSNorm(hidden, epsilon, prefix="moe_norm_")
+            self.moe = HeldExpertsMoE(hidden, width, experts_total,
+                                      experts_per_token, experts_held,
+                                      first_expert, prefix="moe_")
+
+    def hybrid_forward(self, F, x, positions):
+        rows, loss, _ = self.attn(self.attn_norm(x), positions)
+        h = x + rows
+        return h + self.moe(self.moe_norm(h)), loss

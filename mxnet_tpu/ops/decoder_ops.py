@@ -1,14 +1,24 @@
 """Operators of a decoder language model's layers that ``nn_ops`` lacks:
-weighted RMSNorm, rotary position embedding with the positions as an input,
-and the operator of the mixture-of-experts layer for the experts held on this
-chip (the layer itself is parallel/moe.py's, imported when the operator runs:
-``mx.nd`` installs its operators before ``parallel`` is imported, so an
-operator registered there would not be found).  Block-mask attention is in
-pallas_ops.py.
+weighted RMSNorm, rotary position embedding with the positions as an input
+(one row, or three with the frequencies in sections), the operator of the
+mixture-of-experts layer for the experts held on this chip (the layer itself
+is parallel/moe.py's, imported when the operator runs: ``mx.nd`` installs its
+operators before ``parallel`` is imported, so an operator registered there
+would not be found), and a learned indexer's two: the selection of each
+query's keys and the loss that trains it.  The attention kernels (block mask,
+picked pairs) are in pallas_ops.py.
 """
 from __future__ import annotations
 
+from .. import profiler
 from .registry import register
+
+# what the selection names for jax.checkpoint policies: each row's threshold
+# (the bits of its smallest picked score) and where its ties are cut; with
+# them a recomputed layer makes the pairs again by two comparisons, with no
+# search
+SELECTION_RESIDUALS = ("dsa.threshold", "dsa.tie_cut")
+INDEX_CHUNK = 512       # queries to a chunk of index scores
 
 
 @register("_contrib_rms_norm")
@@ -27,12 +37,28 @@ def _rms_norm(attrs, x, gamma):
 def _rotary_embedding(attrs, x, positions):
     """Rotary position embedding over all of the last axis (rotate-half
     pairing: dimension i with i + D/2).  ``x``: (..., T, D); ``positions``:
-    (T,) integers, given by the caller, so that two rows may share one."""
+    (T,) integers, given by the caller, so that two rows may share one; or
+    (3, T) with attr ``sections`` (three counts that sum to D/2): frequency
+    ``i`` takes its angle from position row 0 if ``i < sections[0]``, row 1
+    for the next ``sections[1]``, row 2 for the rest (temporal, height and
+    width of a multimodal sequence; for text the three rows are equal)."""
     import jax.numpy as jnp
     base = float(attrs.get("base", 10000.0))
     half = x.shape[-1] // 2
     inv_freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    positions = positions.astype(jnp.float32)
+    if positions.ndim == 2:
+        sections = tuple(int(n) for n in attrs["sections"])
+        if len(sections) != positions.shape[0] or sum(sections) != half:
+            raise ValueError("sections %r for %d position rows and %d "
+                             "frequencies" % (sections, positions.shape[0],
+                                              half))
+        row = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections),
+                         total_repeat_length=half)
+        positions = positions[row, :].T                       # (T, D/2)
+    else:
+        positions = positions[:, None]
+    angle = positions * inv_freq[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)                 # (T, D/2)
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
@@ -57,3 +83,170 @@ def _moe_held_experts(attrs, x, router_w, gate_w, up_w, down_w):
         int(attrs["experts_per_token"]),
         first_expert=int(attrs.get("first_expert", 0)))
     return out.reshape(x.shape), load
+
+
+def _order_bits(scores):
+    """float32 scores as uint32 whose order is the scores' (-0 as +0; no
+    real score maps to 0)."""
+    import jax
+    import jax.numpy as jnp
+    scores = jnp.where(scores == 0, 0.0, scores)
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    return jnp.where(bits >> 31 == 0, bits | jnp.uint32(1 << 31), ~bits)
+
+
+def index_scores(index_q, index_k, weights, chunk=INDEX_CHUNK):
+    """``I[b, t, s] = sum_j weights[b, t, j] * relu(index_q[b, j, t] .
+    index_k[b, s])``: (B, T, T) float32, every pair (the caller masks the
+    ones above the diagonal).  ``index_q``: (B, J, T, D); ``index_k``:
+    (B, T, D); ``weights``: (B, T, J).  By chunks of queries, each
+    recomputed in the backward pass: a chunk's (B, J, chunk, T) products
+    are the largest value alive."""
+    import jax
+    import jax.numpy as jnp
+    B, J, T, D = index_q.shape
+    chunk = chunk if T % chunk == 0 else T
+    n = T // chunk
+
+    @jax.checkpoint
+    def one(args):
+        q_c, w_c = args                   # (B, J, chunk, D), (B, chunk, J)
+        s = jnp.einsum("bjtd,bsd->bjts", q_c, index_k,
+                       preferred_element_type=jnp.float32)
+        w_c = jnp.swapaxes(w_c, 1, 2).astype(jnp.float32)[..., None]
+        return jnp.sum(jax.nn.relu(s) * w_c, axis=1)          # (B, chunk, T)
+
+    out = jax.lax.map(one, (
+        jnp.moveaxis(index_q.reshape(B, J, n, chunk, D), 2, 0),
+        jnp.moveaxis(weights.reshape(B, n, chunk, J), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, T)
+
+
+def select_top_k(scores, k, interpret=None):
+    """The pairs an indexer picks: for each query ``t`` the ``min(t + 1, k)``
+    keys ``s <= t`` of largest ``scores[b, t, s]``, ties to the lower ``s``
+    (what ``jax.lax.top_k`` gives; a zero of either sign is one value), as
+    int8 (B, T, T).  Exact, with no sort: the row's ``k``-th largest score is
+    found bit by bit (32 passes that compare and count over the scores'
+    order-preserving bits), then among the keys equal to it the cut
+    (``log2 T`` more); a key is picked if its bits lie above the row's
+    threshold, or equal it at or before the cut.  On a TPU (or where
+    ``interpret`` is given) the passes are one kernel's over rows it holds in
+    VMEM (``pallas_ops.select_thresholds``), elsewhere XLA's over the whole
+    square.  The two numbers a row are named ``SELECTION_RESIDUALS``, so a
+    recomputed layer searches nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+    from . import pallas_ops
+    B, T, _ = scores.shape
+    s_at = jnp.arange(T, dtype=jnp.int32)
+    causal = s_at[None, :] <= s_at[:, None]
+    scores = jax.lax.stop_gradient(scores)
+    bits = jnp.where(causal, _order_bits(scores), jnp.uint32(0))
+    if T % 128 == 0 and (interpret is not None
+                         or jax.default_backend() == "tpu"):
+        threshold, cut = pallas_ops.select_thresholds(scores, k,
+                                                      bool(interpret))
+        threshold = jax.lax.bitcast_convert_type(threshold, jnp.uint32)
+    else:
+        threshold, cut = _search_thresholds(bits, k)
+    threshold = checkpoint_name(threshold, SELECTION_RESIDUALS[0])
+    cut = checkpoint_name(cut, SELECTION_RESIDUALS[1])
+    picked = (bits > threshold[..., None]) | (
+        (bits == threshold[..., None]) & (s_at <= cut[..., None]))
+    return (picked & causal).astype(jnp.int8)
+
+
+def _search_thresholds(bits, k):
+    """(threshold (B, T) uint32, cut (B, T) int32) of the rows of ``bits``
+    (B, T, T) uint32, the keys after the query 0: what
+    ``pallas_ops.select_thresholds`` finds, in XLA."""
+    import jax
+    import jax.numpy as jnp
+    B, T, _ = bits.shape
+    s_at = jnp.arange(T, dtype=jnp.int32)
+
+    def count(found):
+        return jnp.sum(found, axis=-1, dtype=jnp.int32)
+
+    def raise_threshold(i, low):
+        tried = low | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(count(bits >= tried[..., None]) >= k, tried, low)
+
+    # the largest value that k scores of the row reach: the k-th largest
+    # (0 where the row has fewer than k keys: every real score is above it)
+    threshold = jax.lax.fori_loop(0, 32, raise_threshold,
+                                  jnp.zeros((B, T), jnp.uint32))
+    equal = bits == threshold[..., None]
+    wanted = k - count(bits > threshold[..., None])  # of the equal, the first
+    cut_bits = max(T - 1, 1).bit_length()
+
+    def raise_cut(i, low):
+        tried = low | (jnp.int32(1 << cut_bits - 1) >> i)
+        fewer = count(equal & (s_at < tried[..., None])) < wanted
+        return jnp.where(fewer, tried, low)
+
+    return threshold, jax.lax.fori_loop(0, cut_bits, raise_cut,
+                                        jnp.zeros((B, T), jnp.int32))
+
+
+@register("_contrib_index_select", num_outputs=2, no_jit=True,
+          shape_rule="input", dtype_rule="input")
+def _index_select(attrs, index_q, index_k, weights):
+    """A learned indexer's selection (DeepSeek Sparse Attention).
+    ``index_q``: (B, J, T, D) and ``index_k``: (B, T, D), the indexer's
+    queries and its one shared key head; ``weights``: (B, T, J), the heads'
+    weights, scaled.  attr ``topk``.  Outputs: the index scores (B, T, T)
+    float32 (differentiable; meaningful on and under the diagonal) and the
+    picked pairs (B, T, T) int8, ``min(t + 1, topk)`` a row, exactly the
+    largest (``select_top_k``)."""
+    import jax
+    k = int(attrs["topk"])
+    T = index_q.shape[2]
+    profiler.count("dsa.pairs_causal", index_q.shape[0] * T * (T + 1) // 2)
+    kept = min(k, T)
+    profiler.count("dsa.pairs_selected", index_q.shape[0] * (
+        kept * (kept + 1) // 2 + (T - kept) * kept))
+    with jax.named_scope("dsa.index"):
+        scores = index_scores(index_q, index_k, weights)
+    with jax.named_scope("dsa.select"):
+        return scores, select_top_k(scores, k)
+
+
+@register("_contrib_index_loss", num_outputs=2, no_jit=True,
+          shape_rule="input", dtype_rule="input",
+          no_grad="to the index scores and the handed-through output alone "
+                  "(q, k and lse pass stop_gradient)")
+def _index_loss(attrs, scores, pairs, q, k, lse, out):
+    """The loss that trains an indexer: the KL divergence from the
+    attention's own distribution over each query's picked keys, averaged
+    over the query heads and taken as a constant (``q``: (B, H, T, D),
+    ``k``: (B, Hkv, T, D) and ``lse``: (B, H, T) as the attention call had
+    and gave them; no gradient reaches them), to the softmax of the index
+    ``scores`` (B, T, T) over the same keys ``pairs``, summed over the rows
+    and divided by ``B * T``.  Outputs: the loss, (1,) float32, and ``out``
+    (the attention's output) as it came: handed through a barrier with the
+    loss, so that the loss is computed before the attention's rows go on;
+    nothing else needs it before the step's loss is summed, and the
+    scheduler would leave every layer's scores alive till then.  Optional
+    attr ``scale``."""
+    import jax
+    import jax.numpy as jnp
+    B, T, _ = scores.shape
+    scale = attrs.get("scale")
+    scale = 1.0 / q.shape[-1] ** 0.5 if scale is None else float(scale)
+    from .pallas_ops import head_mean_probabilities
+    with jax.named_scope("dsa.index_loss"):
+        picked = pairs != 0
+        # read under the pairs: a tile with nothing picked was never
+        # written, and whatever it holds (a NaN) would reach the scores'
+        # gradient through the product below, times 0
+        target = jax.lax.stop_gradient(jnp.where(
+            picked, head_mean_probabilities(q, k, lse, pairs, scale), 0.0))
+        log_q = jax.nn.log_softmax(
+            jnp.where(picked, scores.astype(jnp.float32), -1e30), axis=-1)
+        log_p = jnp.log(jnp.where(target > 0, target, 1.0))
+        terms = jnp.where(picked, target * (log_p - log_q), 0.0)
+        return jax.lax.optimization_barrier(
+            ((jnp.sum(terms) / (B * T)).reshape(1), out))

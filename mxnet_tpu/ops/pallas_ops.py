@@ -7,14 +7,16 @@ log-sum-exp softmax, keeping the working set in VMEM and the QK^T / PV matmuls
 on the MXU, forward and backward.
 
 One pair of kernels (the forward; one backward for the query, key and value
-gradients) serves every static mask: none, causal ('top' / 'bottom' aligned)
-and the block diffusion mask over ``[noised; clean]`` rows.  A mask is a
+gradients) serves every mask.  A static mask (none, causal 'top' / 'bottom'
+aligned, the block diffusion mask over ``[noised; clean]`` rows) is a
 function of a row's and a column's index; from it the wrapper works out on
 the host, per query tile, which key tiles hold a visible pair (the others
 are never visited: no DMA, no MXU pass) and which are wholly visible (no
-masking).  Query heads may outnumber key/value heads (grouped-query
-attention): the kernels index the shared key/value head, and the key/value
-gradient sums over the group inside the kernel.
+masking).  A data mask (``sparse_attention``) is an operand the step
+computes: the visible pairs as int8, streamed by tile beside the keys, over
+the causal mask's tables.  Query heads may outnumber key/value heads
+(grouped-query attention): the kernels index the shared key/value head, and
+the key/value gradient sums over the group inside the kernel.
 
 On a TPU backend the entry points run the kernels, and a kernel the compiler
 refuses is an error the caller sees.  Elsewhere they run the dense XLA
@@ -40,8 +42,10 @@ ATTENTION_RESIDUALS = ("attn.out", "attn.lse")
 # ---------------------------------------------------------------------------
 # static masks
 # ---------------------------------------------------------------------------
-# A mask is a hashable tuple: ("none",), ("causal", offset) or
-# ("block_diffusion", L, block_length).
+# A mask is a hashable tuple: ("none",), ("causal", offset),
+# ("block_diffusion", L, block_length) or DATA_MASK, whose visible pairs are
+# an operand of the call.
+DATA_MASK = ("data",)
 
 def _causal_offset(causal, Tq, Tk):
     """Key-position offset of the causal diagonal: query i attends keys
@@ -89,10 +93,9 @@ def mask_visible(mask, q_pos, k_pos):
 def _tile_tables(mask, Tq, Tk, n_q, n_k, block_q, block_k):
     """Which tiles the mask leaves something in, found on the host.
 
-    Returns ``(k_of_q, q_of_k)``, each ``(index, flag, slots)``: for every
-    query tile the key tiles to visit (and the other way round for the
-    key/value gradient), padded to ``slots`` a row by repeating the last
-    one with flag 0, so that a padded step fetches nothing new.  Flag 1: the
+    Returns ``(index, flag, slots)``: for every query tile the key tiles to
+    visit, padded to ``slots`` a row by repeating the last one with flag 0,
+    so that a padded step fetches nothing new.  Flag 1: the
     tile is partly visible and is masked from its indices; 2: wholly
     visible.  Rows and columns past ``Tq`` / ``Tk`` are padding: a padded
     column is never visible, a padded row is no reason to visit a tile."""
@@ -107,25 +110,25 @@ def _tile_tables(mask, Tq, Tk, n_q, n_k, block_q, block_k):
         state[qi] = _np.where(vis.all(axis=(0, 2)), 2,
                               real.any(axis=(0, 2)).astype(_np.int32))
 
-    def table(state):
-        slots = max(1, int((state > 0).sum(axis=1).max()))
-        index = _np.zeros((state.shape[0], slots), _np.int32)
-        flag = _np.zeros((state.shape[0], slots), _np.int32)
-        for row in range(state.shape[0]):
-            found = _np.nonzero(state[row])[0]
-            index[row, :len(found)] = found
-            flag[row, :len(found)] = state[row, found]
-            if len(found):
-                index[row, len(found):] = found[-1]
-        return index.reshape(-1), flag.reshape(-1), slots
-
-    return table(state), table(state.T)
+    slots = max(1, int((state > 0).sum(axis=1).max()))
+    index = _np.zeros((n_q, slots), _np.int32)
+    flag = _np.zeros((n_q, slots), _np.int32)
+    for row in range(n_q):
+        found = _np.nonzero(state[row])[0]
+        index[row, :len(found)] = found
+        flag[row, :len(found)] = state[row, found]
+        if len(found):
+            index[row, len(found):] = found[-1]
+    return index.reshape(-1), flag.reshape(-1), slots
 
 
-def _attention_reference(q, k, v, causal, scale, mask=None):
+def _attention_reference(q, k, v, causal, scale, mask=None, pairs=None):
     """Dense XLA attention: every score materialised.  ``causal`` is the
-    flash_attention argument; ``mask`` (a mask tuple) overrides it.  Query
-    heads may be a multiple of the key/value heads."""
+    flash_attention argument; ``mask`` (a mask tuple) overrides it, and
+    under ``DATA_MASK`` the visible pairs are ``pairs`` (B, Tq, Tk), nonzero
+    or true where the query sees the key; the log-sum-exp (B, Hq, Tq) is
+    then returned beside the output.  Query heads may be a multiple of the
+    key/value heads."""
     import jax
     import jax.numpy as jnp
     B, Hq, Tq, D = q.shape
@@ -134,6 +137,13 @@ def _attention_reference(q, k, v, causal, scale, mask=None):
         mask = _causal_mask(causal, Tq, Tk)
     qg = q.reshape(B, Hkv, Hq // Hkv, Tq, D)
     s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k).astype(jnp.float32) * scale
+    if mask == DATA_MASK:
+        keep = (pairs != 0)[:, None, None]
+        s = jnp.where(keep, s, _NEG)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        p = jnp.where(keep, jnp.exp(s - lse[..., None]), 0.0)
+        out = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v)
+        return out.reshape(B, Hq, Tq, D), lse.reshape(B, Hq, Tq)
     if mask[0] != "none":
         keep = mask_visible(mask, jnp.arange(Tq)[:, None],
                             jnp.arange(Tk)[None, :])
@@ -165,8 +175,20 @@ class _Plan:
         self.n_k = (Tk + self.pad_k) // self.block_k
         self.mask, self.scale = mask, float(scale)
         self.mxu_dtype, self.interpret = mxu_dtype, interpret
-        self.k_of_q, _ = _tile_tables(
-            mask, Tq, Tk, self.n_q, self.n_k, self.block_q, self.block_k)
+        # a data mask's pairs come with the call and lie on or under the
+        # diagonal: its tables are the causal mask's, every visited tile
+        # masked from the operand
+        self.data = mask == DATA_MASK
+        self.index, self.flag, self.slots = _tile_tables(
+            ("causal", 0) if self.data else mask, Tq, Tk, self.n_q, self.n_k,
+            self.block_q, self.block_k)
+        if self.data:
+            self.flag = _np.minimum(self.flag, 1)
+
+    def tables(self):
+        """(index, flag) as the kernels' scalar-prefetch operands."""
+        import jax.numpy as jnp
+        return jnp.asarray(self.index), jnp.asarray(self.flag)
 
     def keep(self, q_tile, k_tile, transposed=False):
         """The visible pairs of one tile, from its indices, as the kernels
@@ -190,7 +212,7 @@ class _Plan:
         heads = self.B * self.Hq
         profiler.count("attn.tiles_total", heads * self.n_q * self.n_k)
         profiler.count("attn.tiles_visited",
-                       heads * int((self.k_of_q[1] > 0).sum()))
+                       heads * int((self.flag > 0).sum()))
 
 
 def _nt(a, b):
@@ -212,26 +234,35 @@ def _trim(plan, out):
     return out.reshape(plan.B, plan.Hq, -1, plan.D)[:, :, :plan.Tq]
 
 
-def _attention_fwd_pallas(plan, q, k, v):
+def _padded_pairs(plan, pairs):
+    """A data mask's pairs (B, Tq, Tk) as int8 over whole tiles, the padding
+    invisible."""
+    import jax.numpy as jnp
+    return jnp.pad(pairs.astype(jnp.int8),
+                   ((0, 0), (0, plan.pad_q), (0, plan.pad_k)))
+
+
+def _attention_fwd_pallas(plan, q, k, v, pairs=None):
     """(out, lse): grid over (batch * query heads, query tiles, visited key
     tiles).  K/V stream through VMEM one ``(block_k, D)`` tile per step
     while the online-softmax state (running max, normaliser, accumulator)
     lives in VMEM scratch across the steps of one query tile, so VMEM use is
     bounded by the tile sizes, never by the sequence length.  Ragged lengths
     are padded up to the tile size; padded key columns are masked and padded
-    query rows are sliced off."""
+    query rows are sliced off.  Under a data mask ``pairs`` are the padded
+    int8 pairs, whose (block_q, block_k) tile masks a visited tile."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     p = plan
-    bq, bk, D, G = p.block_q, p.block_k, p.D, p.G
-    _, _, S = p.k_of_q
+    bq, bk, D, G, S = p.block_q, p.block_k, p.D, p.G, p.slots
     cdt = p.mxu_dtype
 
-    def kernel(kidx_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-               m_ref, l_ref, acc_ref):
+    def kernel(kidx_ref, flag_ref, q_ref, k_ref, v_ref, *refs):
+        pairs_ref = refs[0] if p.data else None
+        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[-5:]
         qi, si = pl.program_id(1), pl.program_id(2)
         at = qi * S + si
         flag = flag_ref[at]
@@ -245,7 +276,8 @@ def _attention_fwd_pallas(plan, q, k, v):
         def accumulate(masked):
             s = _nt(q_ref[...].astype(cdt), k_ref[...].astype(cdt)) * p.scale
             if masked:
-                keep = p.keep(qi, kidx_ref[at])
+                keep = pairs_ref[...].astype(jnp.int32) != 0 if p.data \
+                    else p.keep(qi, kidx_ref[at])
                 s = jnp.where(keep, s, _NEG)
             m_prev = m_ref[...]                                   # (bq, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -276,13 +308,19 @@ def _attention_fwd_pallas(plan, q, k, v):
     q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j, kidx, flag: (b, i, 0))
     kv_spec = pl.BlockSpec(
         (None, bk, D), lambda b, i, j, kidx, flag: (b // G, kidx[i * S + j], 0))
+    operands, in_specs = [qf, kf, vf], [q_spec, kv_spec, kv_spec]
+    if p.data:
+        operands.append(pairs)
+        in_specs.append(pl.BlockSpec(
+            (None, bq, bk), lambda b, i, j, kidx, flag:
+            (b // p.Hq, i, kidx[i * S + j])))
     p.count_tiles()
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(BH, p.n_q, S),
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=in_specs,
             out_specs=[q_spec, pl.BlockSpec(
                 (None, bq, 1), lambda b, i, j, kidx, flag: (b, i, 0))],
             scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
@@ -293,11 +331,11 @@ def _attention_fwd_pallas(plan, q, k, v):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret, name="attention_fwd",
-    )(jnp.asarray(p.k_of_q[0]), jnp.asarray(p.k_of_q[1]), qf, kf, vf)
+    )(*p.tables(), *operands)
     return out, lse
 
 
-def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
+def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     """(dq, dk, dv) from one kernel, scores recomputed tile by tile from the
     forward's log-sum-exp (``lse`` as the forward call keeps it, (B * H,
     padded T)).  The grid is the forward's: (batch * query heads, query
@@ -316,15 +354,15 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
     scaled at its last, and each tile adds its (block_k, D) rows in place.
     VMEM therefore grows with the key length (4 MiB a gradient at 8,192 x
     128, twice for the pipeline's second buffer); nothing in HBM grows
-    with tiles x heads."""
+    with tiles x heads.  Under a data mask ``pairs_t`` are the padded pairs,
+    key rows by query columns."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     p = plan
-    bq, bk, D, G = p.block_q, p.block_k, p.D, p.G
-    _, _, S = p.k_of_q
+    bq, bk, D, G, S = p.block_q, p.block_k, p.D, p.G, p.slots
     cdt = p.mxu_dtype
     BH, BHkv = p.B * p.Hq, p.B * p.Hkv
     Tq_t, Tk_t = p.n_q * bq, p.n_k * bk
@@ -341,7 +379,9 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
     lse = lse[:, None]
 
     def kernel(kidx_ref, flag_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
-               kt_ref, v_ref, dq_ref, dk_ref, dv_ref, dqt_acc):
+               kt_ref, v_ref, *refs):
+        pairs_ref = refs[0] if p.data else None
+        dq_ref, dk_ref, dv_ref, dqt_acc = refs[-4:]
         b, qi, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         at = qi * S + si
         flag = flag_ref[at]
@@ -363,7 +403,9 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
             st = _nt(k_ref[...].astype(cdt), q_blk) * p.scale    # (bk, bq)
             et = jnp.exp(st - lse_ref[...])
             if masked:
-                et = jnp.where(p.keep(qi, kidx, transposed=True), et, 0.0)
+                keep = pairs_ref[...].astype(jnp.int32) != 0 if p.data \
+                    else p.keep(qi, kidx, transposed=True)
+                et = jnp.where(keep, et, 0.0)
             dv_ref[rows, :] += jnp.dot(et.astype(cdt), g_blk,
                                        preferred_element_type=jnp.float32)
             dpt = _nt(v_ref[...].astype(cdt), g_blk)
@@ -406,13 +448,20 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
     # MiB a kernel has by default for its tiles and temporaries; past 100 of
     # a v5e core's 128 MiB the compiler refuses the call, and says so
     vmem_bytes = min(16 * 2 ** 20 + 2 * 2 * Tk_t * D * 4, 100 * 2 ** 20)
+    operands = [qf, gf, lse, delta, kf, kt, vf]
+    in_specs = [q_spec, q_spec, row_spec, row_spec, k_spec,
+                pl.BlockSpec((None, D, bk), kt_side), k_spec]
+    if p.data:
+        operands.append(pairs_t)
+        in_specs.append(pl.BlockSpec(
+            (None, bk, bq), lambda b, i, j, kidx, flag:
+            (b // p.Hq, kidx[i * S + j], i)))
     p.count_tiles()
     dq, dk, dv = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(BH, p.n_q, S),
-            in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec,
-                      pl.BlockSpec((None, D, bk), kt_side), k_spec],
+            in_specs=in_specs,
             out_specs=[q_spec, held_spec, held_spec],
             scratch_shapes=[pltpu.VMEM((D, bq), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((BH, Tq_t, D), q.dtype), held, held],
@@ -421,8 +470,7 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g):
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_bytes),
         interpret=p.interpret, name="attention_bwd",
-    )(jnp.asarray(p.k_of_q[0]), jnp.asarray(p.k_of_q[1]), qf, gf, lse, delta,
-      kf, kt, vf)
+    )(*p.tables(), *operands)
 
     dq = dq.reshape(p.B, p.Hq, Tq_t, D)[:, :, :p.Tq]
     dk = dk.reshape(p.B, p.Hkv, Tk_t, D)[:, :, :p.Tk].astype(k.dtype)
@@ -554,6 +602,258 @@ def block_mask_attention(q, k, v, seq_len, block_length, scale=None,
                           scope="attn.block_mask")
 
 
+def sparse_attention(q, k, v, pairs, scale=None, precision="default",
+                     interpret=None, block_q=512, block_k=512):
+    """Attention over the pairs a step picks itself: ``pairs`` (B, T, T),
+    nonzero where query ``t`` sees key ``s``, is data (an indexer's
+    selection), not a function of the indices known when the step is traced.
+    ``q``: (B, H, T, D); ``k``, ``v``: (B, Hkv, T, D).  The picked pairs lie
+    on or under the diagonal.
+
+    The kernels are the static masks' own over the causal mask's tables:
+    every tile on or under the diagonal is visited and masked from the
+    pairs' int8 (block_q, block_k) tile, streamed beside the keys (the
+    backward kernel takes the transposed array).  A tile in which nothing is
+    picked adds nothing; it is visited all the same, so that the step's time
+    does not follow what the indexer picks.  Returns ``(out, lse)``: the
+    output and the log-sum-exp over each row's picked keys (B, H, T), whose
+    cotangent is taken as 0 (it feeds an indexer's loss under
+    ``stop_gradient``).  No gradient reaches ``pairs``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+
+    B, Hq, T, D = q.shape
+    if k.shape[2] != T or pairs.shape != (B, T, T):
+        raise ValueError("sparse attention over %d queries, %d keys and "
+                         "pairs %s: one square a batch row"
+                         % (T, k.shape[2], pairs.shape))
+    if scale is None:
+        scale = 1.0 / _np.sqrt(D)
+    with jax.named_scope("dsa.attend"):
+        if interpret is None and jax.default_backend() != "tpu":
+            out, lse = _attention_reference(q, k, v, None, scale,
+                                            mask=DATA_MASK, pairs=pairs)
+            return out, jax.lax.stop_gradient(lse)
+        plan = _Plan(q.shape, k.shape, DATA_MASK, scale, block_q, block_k,
+                     _mxu_dtype(q.dtype, precision), bool(interpret))
+
+        @jax.custom_vjp
+        def f(q_, k_, v_, padded_):
+            return f_fwd(q_, k_, v_, padded_)[0]
+
+        def f_fwd(q_, k_, v_, padded_):
+            out, lse = _attention_fwd_pallas(plan, q_, k_, v_, padded_)
+            out = checkpoint_name(out, ATTENTION_RESIDUALS[0])
+            lse = checkpoint_name(lse[..., 0], ATTENTION_RESIDUALS[1])
+            return (_trim(plan, out), lse.reshape(B, Hq, -1)[:, :, :T]), \
+                (q_, k_, v_, padded_, out, lse)
+
+        def f_bwd(res, cotangents):
+            q_, k_, v_, padded_, out, lse = res
+            with jax.named_scope("dsa.attend"):
+                grads = _attention_bwd_pallas(
+                    plan, q_, k_, v_, out, lse, cotangents[0],
+                    jnp.swapaxes(padded_, 1, 2))
+            return grads + (None,)
+
+        f.defvjp(f_fwd, f_bwd)
+        out, lse = f(q, k, v, _padded_pairs(plan, pairs))
+        return out, jax.lax.stop_gradient(lse)
+
+
+def _head_mean_pallas(plan, q, k, lse, pairs):
+    """``mean_h exp(scale * q[h] k[g(h)]^T - lse[h])`` over the picked pairs
+    of every visited tile, 0 on its other pairs: (B, padded Tq, padded Tk)
+    float32.  Grid (batch, query tiles, visited key tiles, query heads), the
+    heads innermost: a tile of the result stays in VMEM while the heads'
+    probabilities are added into it, and is masked and scaled at the last.
+    A tile above the diagonal is not visited and never written: the caller
+    reads the result under the pairs alone."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    p = plan
+    bq, bk, D, G, S, H = p.block_q, p.block_k, p.D, p.G, p.slots, p.Hq
+    cdt = p.mxu_dtype
+
+    def kernel(kidx_ref, flag_ref, q_ref, k_ref, lse_ref, pairs_ref, o_ref):
+        h = pl.program_id(3)
+        at = pl.program_id(1) * S + pl.program_id(2)
+
+        @pl.when(flag_ref[at] > 0)
+        def _():
+            s = _nt(q_ref[...].astype(cdt), k_ref[...].astype(cdt)) * p.scale
+            prob = jnp.exp(s - lse_ref[...])
+
+            @pl.when(h == 0)
+            def _():
+                o_ref[...] = prob
+
+            @pl.when(h > 0)
+            def _():
+                o_ref[...] += prob
+
+            @pl.when(h == H - 1)
+            def _():
+                o_ref[...] = jnp.where(
+                    pairs_ref[...].astype(jnp.int32) != 0,
+                    o_ref[...] * (1.0 / H), 0.0)
+
+
+    qf = _pad_rows(q, p.pad_q).reshape(p.B * H, p.n_q * bq, D)
+    kf = _pad_rows(k, p.pad_k).reshape(p.B * p.Hkv, p.n_k * bk, D)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(p.B, p.n_q, S, H),
+            in_specs=[
+                pl.BlockSpec((None, bq, D), lambda b, i, j, h, kidx, flag:
+                             (b * H + h, i, 0)),
+                pl.BlockSpec((None, bk, D), lambda b, i, j, h, kidx, flag:
+                             (b * p.Hkv + h // G, kidx[i * S + j], 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, i, j, h, kidx, flag:
+                             (b * H + h, i, 0)),
+                pl.BlockSpec((None, bq, bk), lambda b, i, j, h, kidx, flag:
+                             (b, i, kidx[i * S + j]))],
+            out_specs=pl.BlockSpec(
+                (None, bq, bk), lambda b, i, j, h, kidx, flag:
+                (b, i, kidx[i * S + j]))),
+        out_shape=jax.ShapeDtypeStruct((p.B, p.n_q * bq, p.n_k * bk),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=p.interpret, name="index_target",
+    )(*p.tables(), qf, kf, lse, pairs)
+
+
+def head_mean_probabilities(q, k, lse, pairs, scale=None, precision="default",
+                            interpret=None, block_q=512, block_k=512):
+    """The attention's own distribution over each query's picked keys,
+    averaged over its query heads: ``mean_h exp(scale * q[b, h, t] . k[b,
+    g(h), s] - lse[b, h, t])`` where ``pairs[b, t, s]`` is nonzero; (B, T,
+    T) float32, **to be read under the pairs alone**: a tile above the
+    diagonal is not visited and holds whatever was there (the other pairs
+    of a visited tile, and everything on the XLA path, are 0).
+    ``q``: (B, H, T, D), ``k``: (B, Hkv, T, D), ``lse``: (B, H, T) as
+    ``sparse_attention`` gave it.  On a TPU (or where ``interpret`` is
+    given) one kernel over the attention kernels' tiles and tables, the
+    heads innermost; elsewhere (and as the kernel's oracle) XLA by chunks
+    of queries.  The result is a constant: no gradient goes back to ``q``,
+    ``k`` or ``lse``."""
+    import jax
+    import jax.numpy as jnp
+    B, H, T, D = q.shape
+    if scale is None:
+        scale = 1.0 / _np.sqrt(D)
+    q, k, lse = jax.lax.stop_gradient((q, k, lse))
+    if interpret is None and jax.default_backend() != "tpu":
+        return _head_mean_reference(q, k, lse, pairs, scale)
+    plan = _Plan(q.shape, k.shape, DATA_MASK, scale, block_q, block_k,
+                 _mxu_dtype(q.dtype, precision), bool(interpret))
+    lse = jnp.pad(lse, ((0, 0), (0, 0), (0, plan.pad_q))).reshape(
+        B * H, -1, 1)
+    return _head_mean_pallas(plan, q, k, lse,
+                             _padded_pairs(plan, pairs))[:, :T, :T]
+
+
+def _head_mean_reference(q, k, lse, pairs, scale, chunk=256):
+    """``head_mean_probabilities`` in XLA, by chunks of ``chunk`` queries
+    (a chunk's (B, H, chunk, T) scores are the largest value alive)."""
+    import jax
+    import jax.numpy as jnp
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    chunk = chunk if T % chunk == 0 else T
+    n = T // chunk
+
+    def one(args):
+        q_c, lse_c, pairs_c = args   # (B,Hkv,G,chunk,D) (B,Hkv,G,chunk) ...
+        s = jnp.einsum("bhgtd,bhsd->bhgts", q_c, k,
+                       preferred_element_type=jnp.float32) * scale
+        prob = jnp.exp(s - lse_c[..., None])
+        prob = jnp.where((pairs_c != 0)[:, None, None], prob, 0.0)
+        return jnp.sum(prob, axis=(1, 2)) / H                 # (B, chunk, T)
+
+    out = jax.lax.map(one, (
+        jnp.moveaxis(q.reshape(B, Hkv, H // Hkv, n, chunk, D), 3, 0),
+        jnp.moveaxis(lse.reshape(B, Hkv, H // Hkv, n, chunk), 3, 0),
+        jnp.moveaxis(pairs.reshape(B, n, chunk, T), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, T)
+
+
+_INT_MIN = -2 ** 31
+SELECT_ROWS = 64        # rows of scores a step of the selection kernel holds
+
+
+def select_thresholds(scores, k, interpret=False):
+    """For each row ``t`` of ``scores`` (B, T, T) float32, ``T`` a multiple
+    of 128: the order-preserving bits of its ``k``-th largest score among
+    the keys ``s <= t`` (0 where there are fewer than ``k``), as the int32
+    pattern of that uint32, and the cut among the keys equal to it (the
+    largest ``p`` such that fewer of them than are still wanted lie before
+    ``p``): two (B, T) int32 arrays, as ``ops.decoder_ops.select_top_k``
+    finds them.  One kernel: ``SELECT_ROWS`` rows of scores are read into
+    VMEM once and the 32 + ``log2 T`` passes that compare and count run
+    there, where XLA reads the square from HBM for every pass."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = scores.shape
+    R = min(SELECT_ROWS, T)
+    cut_bits = max(T - 1, 1).bit_length()
+
+    def kernel(s_ref, threshold_ref, cut_ref):
+        x = s_ref[...]
+        x = jnp.where(x == 0, 0.0, x)               # -0 as +0
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        # signed integers in the scores' order; the keys after the query last
+        key = bits ^ ((bits >> 31) & jnp.int32(0x7fffffff))
+        row = pl.program_id(1) * R + jax.lax.broadcasted_iota(
+            jnp.int32, (R, T), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+        key = jnp.where(col <= row, key, jnp.int32(_INT_MIN))
+
+        def count(found):       # exact in float32: at most T of them
+            return jnp.sum(jnp.where(found, 1.0, 0.0), axis=1, keepdims=True)
+
+        def raise_threshold(i, low):    # low: the uint32's pattern, (R, 1)
+            tried = low | jax.lax.shift_left(jnp.int32(1), 31 - i)
+            enough = count(key >= (tried ^ jnp.int32(_INT_MIN))) >= k
+            return jnp.where(enough, tried, low)
+
+        low = jax.lax.fori_loop(0, 32, raise_threshold,
+                                jnp.zeros((R, 1), jnp.int32))
+        threshold = low ^ jnp.int32(_INT_MIN)
+        wanted = k - count(key > threshold)
+        equal = key == threshold
+
+        def raise_cut(i, low):
+            tried = low | jax.lax.shift_left(jnp.int32(1), cut_bits - 1 - i)
+            fewer = count(equal & (col < tried)) < wanted
+            return jnp.where(fewer, tried, low)
+
+        threshold_ref[...] = low
+        cut_ref[...] = jax.lax.fori_loop(0, cut_bits, raise_cut,
+                                         jnp.zeros((R, 1), jnp.int32))
+
+    row_spec = pl.BlockSpec((None, R, 1), lambda b, i: (b, i, 0))
+    shape = jax.ShapeDtypeStruct((B, T, 1), jnp.int32)
+    threshold, cut = pl.pallas_call(
+        kernel, grid=(B, T // R),
+        in_specs=[pl.BlockSpec((None, R, T), lambda b, i: (b, i, 0))],
+        out_specs=[row_spec, row_spec], out_shape=[shape, shape],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret, name="index_select")(scores)
+    return threshold[..., 0], cut[..., 0]
+
+
 @register("_contrib_flash_attention")
 def _flash_attention_op(attrs, q, k, v):
     return flash_attention(q, k, v, causal=bool(attrs.get("causal", False)),
@@ -568,3 +868,12 @@ def _block_mask_attention_op(attrs, q, k, v):
     return block_mask_attention(q, k, v, int(attrs["seq_len"]),
                                 int(attrs["block_length"]),
                                 scale=attrs.get("scale"))
+
+
+@register("_contrib_sparse_attention", num_outputs=2, no_jit=True,
+          shape_rule="input", dtype_rule="input")
+def _sparse_attention_op(attrs, q, k, v, pairs):
+    """Attention over the pairs ``pairs`` (B, T, T) holds nonzero: outputs
+    the attention's output and the rows' log-sum-exp; optional attr
+    ``scale``."""
+    return sparse_attention(q, k, v, pairs, scale=attrs.get("scale"))

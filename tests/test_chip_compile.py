@@ -171,6 +171,44 @@ def test_block_mask_attention_compiles_for_v5e_forward_and_backward(one_chip):
     _fits(compiled)
 
 
+def test_sparse_attention_compiles_for_v5e_forward_and_backward(one_chip):
+    """The same two kernels under a data mask at the sparse cell's size: 32
+    query heads to 4 of 128 over 8,192 rows, float32 in, tiles of 512 x 512,
+    the picked pairs an int8 (8192, 8192) operand streamed by tile (and its
+    transpose for the backward kernel) over the causal mask's tables; with
+    them the selection's kernel over rows of 8,192 float32
+    scores (its 45 passes in VMEM) and the kernel that averages the
+    attention's distribution over the 32 heads."""
+    from mxnet_tpu.ops.decoder_ops import select_top_k
+    from mxnet_tpu.ops.pallas_ops import (head_mean_probabilities,
+                                          sparse_attention)
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.float32,
+                              sharding=one_chip)
+
+    scores = jax.ShapeDtypeStruct((1, 8192, 8192), jnp.float32,
+                                  sharding=one_chip)
+
+    def loss(q, k, v, scores):
+        # scoped as the operators scope them: a kernel's operation is named
+        # after the innermost component of its name stack
+        with jax.named_scope("dsa.select"):
+            pairs = select_top_k(scores, 2048, interpret=False)
+        out, lse = sparse_attention(q, k, v, pairs, interpret=False)
+        with jax.named_scope("dsa.index_loss"):
+            target = head_mean_probabilities(q, k, lse, pairs,
+                                             interpret=False)
+        return jnp.sum(out) + jnp.sum(jnp.where(pairs != 0, target, 0.0))
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        q, kv, kv, scores).compile()
+    assert set(re.findall(r"%((?:attention|index)_\w+?)(?:\.\d+)? = ",
+                          compiled.as_text())) == {
+        "attention_fwd", "attention_bwd", "index_select", "index_target"}
+    _fits(compiled)
+
+
 def test_held_experts_layer_compiles_for_v5e(one_chip):
     """16 of 128 experts of 768 x 2048, 8 per token, 8,192 tokens, forward
     and backward: three plain products over all 16 experts' hidden units."""
