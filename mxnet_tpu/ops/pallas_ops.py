@@ -11,10 +11,11 @@ gradients) serves every mask.  A static mask (none, causal 'top' / 'bottom'
 aligned, the block diffusion mask over ``[noised; clean]`` rows) is a
 function of a row's and a column's index; from it the wrapper works out on
 the host, per query tile, which key tiles hold a visible pair (the others
-are never visited: no DMA, no MXU pass) and which are wholly visible (no
-masking).  A data mask (``sparse_attention``) is an operand the step
-computes: the visible pairs as int8, streamed by tile beside the keys, over
-the causal mask's tables.  Query heads may outnumber key/value heads
+are never visited: no DMA, no MXU pass, no grid step) and which are wholly
+visible (no masking); the kernels' grid walks the one flat list of them.  A
+data mask (``sparse_attention``) is an operand the step computes: the
+visible pairs as int8, streamed by tile beside the keys, over the causal
+mask's list.  Query heads may outnumber key/value heads
 (grouped-query attention): the kernels index the shared key/value head, and
 the key/value gradient sums over the group inside the kernel.
 
@@ -89,16 +90,26 @@ def mask_visible(mask, q_pos, k_pos):
         | (q_clean & k_clean & (kb <= qb))
 
 
+# An entry of the tile list carries its tile's state (0: nothing visible,
+# the one entry of a query tile that sees no key tile; 1: partly visible,
+# masked from its indices or from the pairs; 2: wholly visible) and two
+# marks: the entry is its query tile's first, its last.
+_STATE, _FIRST, _LAST = 3, 4, 8
+
+
 @functools.lru_cache(maxsize=64)
 def _tile_tables(mask, Tq, Tk, n_q, n_k, block_q, block_k):
     """Which tiles the mask leaves something in, found on the host.
 
-    Returns ``(index, flag, slots)``: for every query tile the key tiles to
-    visit, padded to ``slots`` a row by repeating the last one with flag 0,
-    so that a padded step fetches nothing new.  Flag 1: the
-    tile is partly visible and is masked from its indices; 2: wholly
-    visible.  Rows and columns past ``Tq`` / ``Tk`` are padding: a padded
-    column is never visible, a padded row is no reason to visit a tile."""
+    Returns ``(q_tile, k_tile, flag)``: one flat list of the tiles to visit,
+    query tile by query tile and within one by ascending key tile, an entry
+    a grid step.  ``flag`` holds the tile's state (``flag & _STATE``: 1
+    partly visible, masked from its indices; 2 wholly visible) and the
+    marks ``_FIRST`` and ``_LAST`` on a query tile's first and last entry.
+    A query tile that sees no key tile keeps one entry of state 0, so that
+    its rows are still written.  Rows and columns past ``Tq`` / ``Tk`` are
+    padding: a padded column is never visible, a padded row is no reason
+    to visit a tile."""
     state = _np.zeros((n_q, n_k), _np.int32)
     k_pos = _np.arange(n_k * block_k)[None, :]
     for qi in range(n_q):
@@ -110,16 +121,18 @@ def _tile_tables(mask, Tq, Tk, n_q, n_k, block_q, block_k):
         state[qi] = _np.where(vis.all(axis=(0, 2)), 2,
                               real.any(axis=(0, 2)).astype(_np.int32))
 
-    slots = max(1, int((state > 0).sum(axis=1).max()))
-    index = _np.zeros((n_q, slots), _np.int32)
-    flag = _np.zeros((n_q, slots), _np.int32)
+    q_tile, k_tile, flag = [], [], []
     for row in range(n_q):
         found = _np.nonzero(state[row])[0]
-        index[row, :len(found)] = found
-        flag[row, :len(found)] = state[row, found]
-        if len(found):
-            index[row, len(found):] = found[-1]
-    return index.reshape(-1), flag.reshape(-1), slots
+        if not len(found):
+            found = _np.zeros(1, _np.intp)
+        marks = state[row, found]
+        marks[0] |= _FIRST
+        marks[-1] |= _LAST
+        q_tile.append(_np.full(len(found), row, _np.int32))
+        k_tile.append(found.astype(_np.int32))
+        flag.append(marks)
+    return tuple(_np.concatenate(part) for part in (q_tile, k_tile, flag))
 
 
 def _attention_reference(q, k, v, causal, scale, mask=None, pairs=None):
@@ -179,16 +192,18 @@ class _Plan:
         # diagonal: its tables are the causal mask's, every visited tile
         # masked from the operand
         self.data = mask == DATA_MASK
-        self.index, self.flag, self.slots = _tile_tables(
+        self.q_tile, self.k_tile, self.flag = _tile_tables(
             ("causal", 0) if self.data else mask, Tq, Tk, self.n_q, self.n_k,
             self.block_q, self.block_k)
         if self.data:
-            self.flag = _np.minimum(self.flag, 1)
+            self.flag = self.flag - ((self.flag & _STATE) == 2)
+        self.steps = len(self.flag)
 
     def tables(self):
-        """(index, flag) as the kernels' scalar-prefetch operands."""
+        """(q_tile, k_tile, flag) as the kernels' scalar-prefetch operands."""
         import jax.numpy as jnp
-        return jnp.asarray(self.index), jnp.asarray(self.flag)
+        return (jnp.asarray(self.q_tile), jnp.asarray(self.k_tile),
+                jnp.asarray(self.flag))
 
     def keep(self, q_tile, k_tile, transposed=False):
         """The visible pairs of one tile, from its indices, as the kernels
@@ -207,12 +222,13 @@ class _Plan:
         return keep
 
     def count_tiles(self):
-        """One kernel's grid in the recorder: tiles of the whole square
-        and tiles visited, over all batch rows and query heads."""
+        """One kernel's grid in the recorder: tiles of the whole square,
+        tiles visited and grid steps, over all batch rows and query heads."""
         heads = self.B * self.Hq
         profiler.count("attn.tiles_total", heads * self.n_q * self.n_k)
         profiler.count("attn.tiles_visited",
-                       heads * int((self.flag > 0).sum()))
+                       heads * int(((self.flag & _STATE) > 0).sum()))
+        profiler.count("attn.grid_steps", heads * self.steps)
 
 
 def _nt(a, b):
@@ -243,31 +259,34 @@ def _padded_pairs(plan, pairs):
 
 
 def _attention_fwd_pallas(plan, q, k, v, pairs=None):
-    """(out, lse): grid over (batch * query heads, query tiles, visited key
-    tiles).  K/V stream through VMEM one ``(block_k, D)`` tile per step
-    while the online-softmax state (running max, normaliser, accumulator)
-    lives in VMEM scratch across the steps of one query tile, so VMEM use is
-    bounded by the tile sizes, never by the sequence length.  Ragged lengths
-    are padded up to the tile size; padded key columns are masked and padded
-    query rows are sliced off.  Under a data mask ``pairs`` are the padded
-    int8 pairs, whose (block_q, block_k) tile masks a visited tile."""
+    """(out, lse): grid over (batch * query heads, the list of visited
+    tiles); a step takes its query tile and its key tile from the list.
+    K/V stream through VMEM one ``(block_k, D)`` tile per step while the
+    online-softmax state (running max, normaliser, accumulator) lives in
+    VMEM scratch across the consecutive steps of one query tile (zeroed at
+    the entry marked first, written out at the one marked last), so VMEM
+    use is bounded by the tile sizes, never by the sequence length.  Ragged
+    lengths are padded up to the tile size; padded key columns are masked
+    and padded query rows are sliced off.  Under a data mask ``pairs`` are
+    the padded int8 pairs, whose (block_q, block_k) tile masks a visited
+    tile."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     p = plan
-    bq, bk, D, G, S = p.block_q, p.block_k, p.D, p.G, p.slots
+    bq, bk, D, G = p.block_q, p.block_k, p.D, p.G
     cdt = p.mxu_dtype
 
-    def kernel(kidx_ref, flag_ref, q_ref, k_ref, v_ref, *refs):
+    def kernel(qidx_ref, kidx_ref, flag_ref, q_ref, k_ref, v_ref, *refs):
         pairs_ref = refs[0] if p.data else None
         o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[-5:]
-        qi, si = pl.program_id(1), pl.program_id(2)
-        at = qi * S + si
+        at = pl.program_id(1)
         flag = flag_ref[at]
+        state = flag & _STATE
 
-        @pl.when(si == 0)
+        @pl.when((flag & _FIRST) != 0)
         def _():
             m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
             l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -277,7 +296,7 @@ def _attention_fwd_pallas(plan, q, k, v, pairs=None):
             s = _nt(q_ref[...].astype(cdt), k_ref[...].astype(cdt)) * p.scale
             if masked:
                 keep = pairs_ref[...].astype(jnp.int32) != 0 if p.data \
-                    else p.keep(qi, kidx_ref[at])
+                    else p.keep(qidx_ref[at], kidx_ref[at])
                 s = jnp.where(keep, s, _NEG)
             m_prev = m_ref[...]                                   # (bq, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -292,10 +311,10 @@ def _attention_fwd_pallas(plan, q, k, v, pairs=None):
                 preferred_element_type=jnp.float32)
             m_ref[...] = m_new
 
-        pl.when(flag == 1)(lambda: accumulate(True))
-        pl.when(flag == 2)(lambda: accumulate(False))
+        pl.when(state == 1)(lambda: accumulate(True))
+        pl.when(state == 2)(lambda: accumulate(False))
 
-        @pl.when(si == S - 1)
+        @pl.when((flag & _LAST) != 0)
         def _():
             l = jnp.maximum(l_ref[...], 1e-30)
             o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -305,31 +324,33 @@ def _attention_fwd_pallas(plan, q, k, v, pairs=None):
     qf = _pad_rows(q, p.pad_q).reshape(BH, p.n_q * bq, D)
     kf = _pad_rows(k, p.pad_k).reshape(p.B * p.Hkv, p.n_k * bk, D)
     vf = _pad_rows(v, p.pad_k).reshape(p.B * p.Hkv, p.n_k * bk, D)
-    q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j, kidx, flag: (b, i, 0))
+    q_spec = pl.BlockSpec(
+        (None, bq, D), lambda b, t, qidx, kidx, flag: (b, qidx[t], 0))
     kv_spec = pl.BlockSpec(
-        (None, bk, D), lambda b, i, j, kidx, flag: (b // G, kidx[i * S + j], 0))
+        (None, bk, D), lambda b, t, qidx, kidx, flag: (b // G, kidx[t], 0))
     operands, in_specs = [qf, kf, vf], [q_spec, kv_spec, kv_spec]
     if p.data:
         operands.append(pairs)
         in_specs.append(pl.BlockSpec(
-            (None, bq, bk), lambda b, i, j, kidx, flag:
-            (b // p.Hq, i, kidx[i * S + j])))
+            (None, bq, bk), lambda b, t, qidx, kidx, flag:
+            (b // p.Hq, qidx[t], kidx[t])))
     p.count_tiles()
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(BH, p.n_q, S),
+            num_scalar_prefetch=3,
+            grid=(BH, p.steps),
             in_specs=in_specs,
             out_specs=[q_spec, pl.BlockSpec(
-                (None, bq, 1), lambda b, i, j, kidx, flag: (b, i, 0))],
+                (None, bq, 1), lambda b, t, qidx, kidx, flag:
+                (b, qidx[t], 0))],
             scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                             pltpu.VMEM((bq, 1), jnp.float32),
                             pltpu.VMEM((bq, D), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((BH, p.n_q * bq, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, p.n_q * bq, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=p.interpret, name="attention_fwd",
     )(*p.tables(), *operands)
     return out, lse
@@ -338,8 +359,8 @@ def _attention_fwd_pallas(plan, q, k, v, pairs=None):
 def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     """(dq, dk, dv) from one kernel, scores recomputed tile by tile from the
     forward's log-sum-exp (``lse`` as the forward call keeps it, (B * H,
-    padded T)).  The grid is the forward's: (batch * query heads, query
-    tiles, visited key tiles).  Every visited tile is computed once, key
+    padded T)).  The grid is the forward's: (batch * query heads, the list
+    of visited tiles).  Every visited tile is computed once, key
     rows by query columns, so that the per-row statistics enter as
     lane-dense rows and the two key-side products take the tile as it
     stands: scores, exponentials, mask (partly visible tiles only), dP and
@@ -350,8 +371,9 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     transposed, and is turned once a query tile.  dk and dv are revisited
     out of order, so one key/value head's whole (padded Tk, D) float32
     gradients are the output blocks: they stay in VMEM over the head's
-    ``G`` consecutive query heads, are zeroed at the group's first step and
-    scaled at its last, and each tile adds its (block_k, D) rows in place.
+    ``G`` consecutive query heads, are zeroed at the list's first entry of
+    the group's first head and scaled at the last entry of its last, and
+    each tile adds its (block_k, D) rows in place.
     VMEM therefore grows with the key length (4 MiB a gradient at 8,192 x
     128, twice for the pipeline's second buffer); nothing in HBM grows
     with tiles x heads.  Under a data mask ``pairs_t`` are the padded pairs,
@@ -362,7 +384,7 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     from jax.experimental.pallas import tpu as pltpu
 
     p = plan
-    bq, bk, D, G, S = p.block_q, p.block_k, p.D, p.G, p.slots
+    bq, bk, D, G = p.block_q, p.block_k, p.D, p.G
     cdt = p.mxu_dtype
     BH, BHkv = p.B * p.Hq, p.B * p.Hkv
     Tq_t, Tk_t = p.n_q * bq, p.n_k * bk
@@ -378,20 +400,20 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
                     axis=-1)[:, None]                         # (BH, 1, Tq)
     lse = lse[:, None]
 
-    def kernel(kidx_ref, flag_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref,
-               kt_ref, v_ref, *refs):
+    def kernel(qidx_ref, kidx_ref, flag_ref, q_ref, g_ref, lse_ref, delta_ref,
+               k_ref, kt_ref, v_ref, *refs):
         pairs_ref = refs[0] if p.data else None
         dq_ref, dk_ref, dv_ref, dqt_acc = refs[-4:]
-        b, qi, si = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        at = qi * S + si
+        b, at = pl.program_id(0), pl.program_id(1)
         flag = flag_ref[at]
+        state = flag & _STATE
 
-        @pl.when((b % G == 0) & (qi == 0) & (si == 0))
+        @pl.when((b % G == 0) & (at == 0))
         def _():
             dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
             dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
 
-        @pl.when(si == 0)
+        @pl.when((flag & _FIRST) != 0)
         def _():
             dqt_acc[...] = jnp.zeros(dqt_acc.shape, jnp.float32)
 
@@ -404,7 +426,7 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
             et = jnp.exp(st - lse_ref[...])
             if masked:
                 keep = pairs_ref[...].astype(jnp.int32) != 0 if p.data \
-                    else p.keep(qi, kidx, transposed=True)
+                    else p.keep(qidx_ref[at], kidx, transposed=True)
                 et = jnp.where(keep, et, 0.0)
             dv_ref[rows, :] += jnp.dot(et.astype(cdt), g_blk,
                                        preferred_element_type=jnp.float32)
@@ -415,34 +437,34 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
             dqt_acc[...] += jnp.dot(kt_ref[...].astype(cdt), dst,
                                     preferred_element_type=jnp.float32)
 
-        pl.when(flag == 1)(lambda: accumulate(True))
-        pl.when(flag == 2)(lambda: accumulate(False))
+        pl.when(state == 1)(lambda: accumulate(True))
+        pl.when(state == 2)(lambda: accumulate(False))
 
-        @pl.when(si == S - 1)
+        @pl.when((flag & _LAST) != 0)
         def _():
             dq_ref[...] = (dqt_acc[...].T * p.scale).astype(dq_ref.dtype)
 
-        @pl.when((b % G == G - 1) & (qi == p.n_q - 1) & (si == S - 1))
+        @pl.when((b % G == G - 1) & (at == p.steps - 1))
         def _():
             dk_ref[...] *= p.scale
 
-    def q_side(b, i, j, kidx, flag):
-        return (b, i, 0)
+    def q_side(b, t, qidx, kidx, flag):
+        return (b, qidx[t], 0)
 
-    def row_side(b, i, j, kidx, flag):
-        return (b, 0, i)
+    def row_side(b, t, qidx, kidx, flag):
+        return (b, 0, qidx[t])
 
-    def k_side(b, i, j, kidx, flag):
-        return (b // G, kidx[i * S + j], 0)
+    def k_side(b, t, qidx, kidx, flag):
+        return (b // G, kidx[t], 0)
 
-    def kt_side(b, i, j, kidx, flag):
-        return (b // G, 0, kidx[i * S + j])
+    def kt_side(b, t, qidx, kidx, flag):
+        return (b // G, 0, kidx[t])
 
     q_spec = pl.BlockSpec((None, bq, D), q_side)
     row_spec = pl.BlockSpec((None, 1, bq), row_side)
     k_spec = pl.BlockSpec((None, bk, D), k_side)
     held_spec = pl.BlockSpec((None, Tk_t, D),
-                             lambda b, i, j, kidx, flag: (b // G, 0, 0))
+                             lambda b, t, qidx, kidx, flag: (b // G, 0, 0))
     held = jax.ShapeDtypeStruct((BHkv, Tk_t, D), jnp.float32)
     # dk and dv whole, each with the pipeline's second buffer, beside the 16
     # MiB a kernel has by default for its tiles and temporaries; past 100 of
@@ -454,20 +476,20 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     if p.data:
         operands.append(pairs_t)
         in_specs.append(pl.BlockSpec(
-            (None, bk, bq), lambda b, i, j, kidx, flag:
-            (b // p.Hq, kidx[i * S + j], i)))
+            (None, bk, bq), lambda b, t, qidx, kidx, flag:
+            (b // p.Hq, kidx[t], qidx[t])))
     p.count_tiles()
     dq, dk, dv = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(BH, p.n_q, S),
+            num_scalar_prefetch=3, grid=(BH, p.steps),
             in_specs=in_specs,
             out_specs=[q_spec, held_spec, held_spec],
             scratch_shapes=[pltpu.VMEM((D, bq), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((BH, Tq_t, D), q.dtype), held, held],
-        # every axis in order: dk and dv are added to across all three
+        # both axes in order: dk and dv are added to across the two
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_bytes),
         interpret=p.interpret, name="attention_bwd",
     )(*p.tables(), *operands)
@@ -610,12 +632,13 @@ def sparse_attention(q, k, v, pairs, scale=None, precision="default",
     ``q``: (B, H, T, D); ``k``, ``v``: (B, Hkv, T, D).  The picked pairs lie
     on or under the diagonal.
 
-    The kernels are the static masks' own over the causal mask's tables:
-    every tile on or under the diagonal is visited and masked from the
-    pairs' int8 (block_q, block_k) tile, streamed beside the keys (the
-    backward kernel takes the transposed array).  A tile in which nothing is
-    picked adds nothing; it is visited all the same, so that the step's time
-    does not follow what the indexer picks.  Returns ``(out, lse)``: the
+    The kernels are the static masks' own over the causal mask's list of
+    tiles: every tile on or under the diagonal is visited, a grid step
+    each, and masked from the pairs' int8 (block_q, block_k) tile, streamed
+    beside the keys (the backward kernel takes the transposed array).  A
+    tile in which nothing is picked adds nothing; it is visited all the
+    same, so that the step's time does not follow what the indexer picks.
+    Returns ``(out, lse)``: the
     output and the log-sum-exp over each row's picked keys (B, H, T), whose
     cotangent is taken as 0 (it feeds an indexer's loss under
     ``stop_gradient``).  No gradient reaches ``pairs``."""
@@ -665,7 +688,7 @@ def sparse_attention(q, k, v, pairs, scale=None, precision="default",
 def _head_mean_pallas(plan, q, k, lse, pairs):
     """``mean_h exp(scale * q[h] k[g(h)]^T - lse[h])`` over the picked pairs
     of every visited tile, 0 on its other pairs: (B, padded Tq, padded Tk)
-    float32.  Grid (batch, query tiles, visited key tiles, query heads), the
+    float32.  Grid (batch, the list of visited tiles, query heads), the
     heads innermost: a tile of the result stays in VMEM while the heads'
     probabilities are added into it, and is masked and scaled at the last.
     A tile above the diagonal is not visited and never written: the caller
@@ -676,14 +699,14 @@ def _head_mean_pallas(plan, q, k, lse, pairs):
     from jax.experimental.pallas import tpu as pltpu
 
     p = plan
-    bq, bk, D, G, S, H = p.block_q, p.block_k, p.D, p.G, p.slots, p.Hq
+    bq, bk, D, G, H = p.block_q, p.block_k, p.D, p.G, p.Hq
     cdt = p.mxu_dtype
 
-    def kernel(kidx_ref, flag_ref, q_ref, k_ref, lse_ref, pairs_ref, o_ref):
-        h = pl.program_id(3)
-        at = pl.program_id(1) * S + pl.program_id(2)
+    def kernel(qidx_ref, kidx_ref, flag_ref, q_ref, k_ref, lse_ref, pairs_ref,
+               o_ref):
+        h = pl.program_id(2)
 
-        @pl.when(flag_ref[at] > 0)
+        @pl.when((flag_ref[pl.program_id(1)] & _STATE) > 0)
         def _():
             s = _nt(q_ref[...].astype(cdt), k_ref[...].astype(cdt)) * p.scale
             prob = jnp.exp(s - lse_ref[...])
@@ -702,29 +725,28 @@ def _head_mean_pallas(plan, q, k, lse, pairs):
                     pairs_ref[...].astype(jnp.int32) != 0,
                     o_ref[...] * (1.0 / H), 0.0)
 
-
     qf = _pad_rows(q, p.pad_q).reshape(p.B * H, p.n_q * bq, D)
     kf = _pad_rows(k, p.pad_k).reshape(p.B * p.Hkv, p.n_k * bk, D)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(p.B, p.n_q, S, H),
+            num_scalar_prefetch=3, grid=(p.B, p.steps, H),
             in_specs=[
-                pl.BlockSpec((None, bq, D), lambda b, i, j, h, kidx, flag:
-                             (b * H + h, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i, j, h, kidx, flag:
-                             (b * p.Hkv + h // G, kidx[i * S + j], 0)),
-                pl.BlockSpec((None, bq, 1), lambda b, i, j, h, kidx, flag:
-                             (b * H + h, i, 0)),
-                pl.BlockSpec((None, bq, bk), lambda b, i, j, h, kidx, flag:
-                             (b, i, kidx[i * S + j]))],
+                pl.BlockSpec((None, bq, D), lambda b, t, h, qidx, kidx, flag:
+                             (b * H + h, qidx[t], 0)),
+                pl.BlockSpec((None, bk, D), lambda b, t, h, qidx, kidx, flag:
+                             (b * p.Hkv + h // G, kidx[t], 0)),
+                pl.BlockSpec((None, bq, 1), lambda b, t, h, qidx, kidx, flag:
+                             (b * H + h, qidx[t], 0)),
+                pl.BlockSpec((None, bq, bk), lambda b, t, h, qidx, kidx, flag:
+                             (b, qidx[t], kidx[t]))],
             out_specs=pl.BlockSpec(
-                (None, bq, bk), lambda b, i, j, h, kidx, flag:
-                (b, i, kidx[i * S + j]))),
+                (None, bq, bk), lambda b, t, h, qidx, kidx, flag:
+                (b, qidx[t], kidx[t]))),
         out_shape=jax.ShapeDtypeStruct((p.B, p.n_q * bq, p.n_k * bk),
                                        jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary", "arbitrary")),
+            "parallel", "arbitrary", "arbitrary")),
         interpret=p.interpret, name="index_target",
     )(*p.tables(), qf, kf, lse, pairs)
 
@@ -739,7 +761,7 @@ def head_mean_probabilities(q, k, lse, pairs, scale=None, precision="default",
     of a visited tile, and everything on the XLA path, are 0).
     ``q``: (B, H, T, D), ``k``: (B, Hkv, T, D), ``lse``: (B, H, T) as
     ``sparse_attention`` gave it.  On a TPU (or where ``interpret`` is
-    given) one kernel over the attention kernels' tiles and tables, the
+    given) one kernel over the attention kernels' tiles and their list, the
     heads innermost; elsewhere (and as the kernel's oracle) XLA by chunks
     of queries.  The result is a constant: no gradient goes back to ``q``,
     ``k`` or ``lse``."""

@@ -71,18 +71,20 @@ def test_tiles_the_mask_empties_are_not_visited(block_length, tile):
     length = 128
     mask = pallas_ops.block_diffusion_mask(length, block_length)
     n = 2 * length // tile
-    index, flag, slots = pallas_ops._tile_tables(
+    q_tile, k_tile, flag = pallas_ops._tile_tables(
         mask, 2 * length, 2 * length, n, n, tile, tile)
     rows = np.arange(2 * length)
     seen = pallas_ops.mask_visible(mask, rows[:, None], rows[None, :])
     tiles = seen.reshape(n, tile, n, tile)
     some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
-    index, flag = index.reshape(n, slots), flag.reshape(n, slots)
+    state = flag & pallas_ops._STATE
     for qi in range(n):
-        visited = {int(k): int(f) for k, f in zip(index[qi], flag[qi]) if f}
+        mine = q_tile == qi
+        visited = dict(zip(k_tile[mine].tolist(), state[mine].tolist()))
         assert sorted(visited) == list(np.nonzero(some[qi])[0])
         assert all((f == 2) == bool(every[qi, k]) for k, f in visited.items())
-    assert (flag > 0).sum() == some.sum()
+    # an entry a visited tile and none besides: no row is padded to the longest
+    assert len(flag) == (state > 0).sum() == some.sum()
     assert some.sum() <= n * n / 2      # half of the square and more is skipped
 
 

@@ -150,24 +150,38 @@ def test_sharded_decode_step_compiles_for_four_chips(topo, monkeypatch):
 
 # -- the block-diffusion cell's kernels at its published widths --------------
 
+def _pallas_grids(jaxpr):
+    """{name: grid} of the ``pallas_call``s a jaxpr holds, at any depth."""
+    grids = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids.update(_pallas_grids(sub))
+    return grids
+
+
 def test_block_mask_attention_compiles_for_v5e_forward_and_backward(one_chip):
     """32 query heads to 4 of 128 over 8,192 [noised; clean] rows, float32
     in: the forward kernel and the one backward kernel, tiles of 512 x 512,
     with one key/value head's dk and dv (4 MiB each) held in VMEM, which
-    takes more than the 16 MiB a kernel has by default."""
+    takes more than the 16 MiB a kernel has by default.  The grid walks the
+    flat list: 80 visited tiles a head, where 16 query tiles padded to the
+    longest row's 9 were 144."""
     from mxnet_tpu.ops.pallas_ops import block_mask_attention
     q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.float32,
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.float32,
                               sharding=one_chip)
-    compiled = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(block_mask_attention(
-            q, k, v, 4096, 4, interpret=False)), (0, 1, 2))
-    ).lower(q, kv, kv).compile()
+    step = jax.grad(lambda q, k, v: jnp.sum(block_mask_attention(
+        q, k, v, 4096, 4, interpret=False)), (0, 1, 2))
+    compiled = jax.jit(step).lower(q, kv, kv).compile()
     text = compiled.as_text()
     # the custom calls carry the pallas_calls' names: %attention_bwd.1 = ...
     assert set(re.findall(r"%(attention_\w+?)(?:\.\d+)? = ", text)) == {
         "attention_fwd", "attention_bwd"}
+    assert _pallas_grids(jax.make_jaxpr(step)(q, kv, kv).jaxpr) == {
+        "attention_fwd": (32, 80), "attention_bwd": (32, 80)}
     _fits(compiled)
 
 
@@ -178,7 +192,8 @@ def test_sparse_attention_compiles_for_v5e_forward_and_backward(one_chip):
     transpose for the backward kernel) over the causal mask's tables; with
     them the selection's kernel over rows of 8,192 float32
     scores (its 45 passes in VMEM) and the kernel that averages the
-    attention's distribution over the 32 heads."""
+    attention's distribution over the 32 heads.  The three that walk the
+    causal tiles take a grid step for each of the 136 and none besides."""
     from mxnet_tpu.ops.decoder_ops import select_top_k
     from mxnet_tpu.ops.pallas_ops import (head_mean_probabilities,
                                           sparse_attention)
@@ -201,11 +216,14 @@ def test_sparse_attention_compiles_for_v5e_forward_and_backward(one_chip):
                                              interpret=False)
         return jnp.sum(out) + jnp.sum(jnp.where(pairs != 0, target, 0.0))
 
-    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
-        q, kv, kv, scores).compile()
+    step = jax.value_and_grad(loss, (0, 1, 2))
+    compiled = jax.jit(step).lower(q, kv, kv, scores).compile()
     assert set(re.findall(r"%((?:attention|index)_\w+?)(?:\.\d+)? = ",
                           compiled.as_text())) == {
         "attention_fwd", "attention_bwd", "index_select", "index_target"}
+    assert _pallas_grids(jax.make_jaxpr(step)(q, kv, kv, scores).jaxpr) == {
+        "attention_fwd": (32, 136), "attention_bwd": (32, 136),
+        "index_target": (1, 136, 32), "index_select": (1, 128)}
     _fits(compiled)
 
 

@@ -5,6 +5,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from mxnet_tpu import profiler
+from mxnet_tpu.ops import pallas_ops
 from mxnet_tpu.ops.pallas_ops import (flash_attention, _flash_attention_pallas,
                                       _attention_reference)
 
@@ -118,6 +120,111 @@ def test_flash_attention_kv_cache_decode():
     full = flash_attention(q, k, v, causal=False, interpret=True)
     assert out.shape == (B, H, 1, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full), atol=2e-5)
+
+
+# -- the list of visited tiles that every kernel's grid walks -----------------
+
+# mask, query rows, key rows, (block_q, block_k)
+TILE_LISTS = {
+    "none_ragged": (("none",), 200, 130, (64, 32)),
+    "causal_top_fewer_queries": (
+        pallas_ops._causal_mask("top", 100, 200), 100, 200, (32, 64)),
+    "causal_top_fewer_keys": (
+        pallas_ops._causal_mask("top", 200, 100), 200, 100, (64, 32)),
+    "causal_bottom_fewer_queries": (
+        pallas_ops._causal_mask("bottom", 70, 200), 70, 200, (32, 64)),
+    "block_diffusion": (
+        pallas_ops.block_diffusion_mask(96, 4), 192, 192, (64, 32)),
+    "block_diffusion_ragged": (
+        pallas_ops.block_diffusion_mask(100, 4), 200, 200, (32, 64)),
+    "data_ragged": (pallas_ops.DATA_MASK, 100, 100, (32, 64)),
+    "a_query_tile_without_keys": (("causal", -40), 128, 128, (32, 32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_LISTS))
+def test_tile_list_holds_each_visible_tile_once_query_tile_major(case):
+    """One entry for every tile with a visible pair of real rows, query tile
+    by query tile and ascending in the key tile; every query tile has an
+    entry (one of state 0 if it sees nothing) and its first and last are
+    marked; the recorder counts a grid step an entry."""
+    mask, Tq, Tk, tiles = TILE_LISTS[case]
+    heads = 2
+    plan = pallas_ops._Plan((1, heads, Tq, 16), (1, 1, Tk, 16), mask, 0.25,
+                            tiles[0], tiles[1], jnp.float32, True)
+    bq, bk, n_q, n_k = plan.block_q, plan.block_k, plan.n_q, plan.n_k
+    data = mask == pallas_ops.DATA_MASK     # the causal mask's, all masked
+    rows, cols = np.arange(n_q * bq)[:, None], np.arange(n_k * bk)[None, :]
+    seen = pallas_ops.mask_visible(("causal", 0) if data else mask, rows,
+                                   cols) & (cols < Tk)
+    some = (seen & (rows < Tq)).reshape(n_q, bq, n_k, bk).any(axis=(1, 3))
+    every = seen.reshape(n_q, bq, n_k, bk).all(axis=(1, 3)) & (not data)
+    want = []
+    for qi in range(n_q):
+        want += [(qi, int(ki), 2 if every[qi, ki] else 1)
+                 for ki in np.nonzero(some[qi])[0]] or [(qi, 0, 0)]
+    state = plan.flag & pallas_ops._STATE
+    assert list(zip(plan.q_tile.tolist(), plan.k_tile.tolist(),
+                    state.tolist())) == want
+    assert plan.steps == len(want)
+    turns = (np.diff(plan.q_tile) != 0).tolist()
+    assert ((plan.flag & pallas_ops._FIRST) != 0).tolist() == [True] + turns
+    assert ((plan.flag & pallas_ops._LAST) != 0).tolist() == turns + [True]
+
+    profiler.reset_spans()
+    plan.count_tiles()
+    totals = profiler.totals()
+    assert totals["attn.tiles_total"]["count"] == heads * n_q * n_k
+    assert totals["attn.tiles_visited"]["count"] == heads * some.sum()
+    assert totals["attn.grid_steps"]["count"] == heads * len(want) \
+        == heads * (some.sum() + (~some.any(axis=1)).sum())
+
+
+# mask, rows, (block_q, block_k): the rows' lists differ most in length
+UNEVEN_ROWS = {
+    "causal_8_query_tiles": (("causal", 0), 256, (32, 32)),
+    "block_diffusion_64x32": (
+        pallas_ops.block_diffusion_mask(64, 4), 128, (64, 32)),
+    "block_diffusion_32x64": (
+        pallas_ops.block_diffusion_mask(64, 4), 128, (32, 64)),
+    "a_query_tile_without_keys": (("causal", -40), 128, (32, 32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNEVEN_ROWS))
+def test_kernels_over_uneven_rows_match_reference_8_heads_to_1_batch_2(case):
+    """Forward and gradients over a list whose query tiles hold 1 to 8
+    entries.  A row that sees no key is written as zeros by the kernel and
+    as a uniform softmax by the reference: its cotangent is 0 here."""
+    mask, T, tiles = UNEVEN_ROWS[case]
+    rng = np.random.RandomState(7)
+    dim, scale = 16, 0.25
+    q = jnp.asarray(rng.normal(0, 1, (2, 8, T, dim)), jnp.float32)
+    k = jnp.asarray(rng.normal(0, 1, (2, 1, T, dim)), jnp.float32)
+    v = jnp.asarray(rng.normal(0, 1, (2, 1, T, dim)), jnp.float32)
+    sees = pallas_ops.mask_visible(mask, np.arange(T)[:, None],
+                                   np.arange(T)[None, :]).any(axis=1)
+    weight = jnp.asarray(rng.normal(0, 1, q.shape) * sees[:, None],
+                         jnp.float32)
+
+    def kernels(q, k, v):
+        out = pallas_ops._attention(q, k, v, mask, scale, "highest", True,
+                                    *tiles)
+        return jnp.sum(weight * out), out
+
+    def oracle(q, k, v):
+        out = _attention_reference(q, k, v, None, scale, mask=mask)
+        return jnp.sum(weight * out), out
+
+    (_, out), grads = jax.value_and_grad(kernels, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    (_, want), want_grads = jax.value_and_grad(oracle, (0, 1, 2),
+                                               has_aux=True)(q, k, v)
+    assert not np.asarray(out)[:, :, ~sees].any()
+    assert float(jnp.max(jnp.abs(out - want)[:, :, sees])) < 2e-5
+    for got, ref in zip(grads, want_grads):
+        assert got.shape == ref.shape
+        assert float(jnp.max(jnp.abs(got - ref))) < 1e-4
 
 
 def test_rtc_pallas_module_user_kernel():

@@ -151,10 +151,12 @@ def _picked(rng, batch, rows, k, empty=False):
 
 
 @pytest.mark.parametrize("rows,tiles,batch", [
-    (128, (32, 32), 1), (128, (64, 32), 2), (100, (32, 64), 2)])
+    (128, (32, 32), 1), (128, (64, 32), 2), (100, (32, 64), 2),
+    (256, (32, 32), 2)])
 def test_data_mask_kernels_match_reference_8_heads_to_1(rows, tiles, batch):
     """Forward and backward under picked pairs, grouped heads, a batch of
-    masks, and a length that is no multiple of the tiles."""
+    masks, a length that is no multiple of the tiles, and 8 query tiles
+    whose lists hold 1 to 8 entries."""
     rng = np.random.RandomState(0)
     q, k, v = _qkv(rng, 8, 1, rows, batch=batch)
     pairs = _picked(rng, batch, rows, 24)
@@ -207,19 +209,22 @@ def test_a_tile_with_nothing_picked_is_visited_and_adds_nothing():
     # forward and backward, both heads: 10 causal tiles of the 16
     assert totals["attn.tiles_visited"]["count"] == 2 * 2 * 10
     assert totals["attn.tiles_total"]["count"] == 2 * 2 * 16
+    assert totals["attn.grid_steps"]["count"] == 2 * 2 * 10
     for a, b in zip(got, jax.grad(oracle, (0, 1, 2))(q, k, v)):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4
 
 
-@pytest.mark.parametrize("rows,tiles,batch,empty", [
-    (128, (32, 32), 1, True), (100, (32, 64), 2, False)])
+@pytest.mark.parametrize("rows,tiles,batch,empty,kv_heads", [
+    (128, (32, 32), 1, True, 2), (100, (32, 64), 2, False, 2),
+    (256, (32, 32), 2, False, 1)])
 def test_head_mean_kernel_matches_the_chunks_in_xla(rows, tiles, batch,
-                                                    empty):
-    """The attention's distribution averaged over 8 heads to 2, the heads
-    innermost in the grid, against XLA's chunks; read under the pairs (a
-    tile above the diagonal is never written)."""
+                                                    empty, kv_heads):
+    """The attention's distribution averaged over 8 heads to 2 (to 1 over
+    8 query tiles of 1 to 8 entries), the heads innermost in the grid,
+    against XLA's chunks; read under the pairs (a tile above the diagonal
+    is never written)."""
     rng = np.random.RandomState(3)
-    q, k, v = _qkv(rng, 8, 2, rows, batch=batch)
+    q, k, v = _qkv(rng, 8, kv_heads, rows, batch=batch)
     pairs = _picked(rng, batch, rows, 24, empty=empty)
     _, lse = pallas_ops.sparse_attention(
         q, k, v, pairs, precision="highest", interpret=True,
@@ -264,12 +269,14 @@ def test_a_data_mask_s_tables_are_the_causal_mask_s_every_tile_masked():
                             True)
     causal = pallas_ops._Plan((1, 2, 64, 16), (1, 1, 64, 16), ("causal", 0),
                               0.25, 32, 32, jnp.float32, True)
-    assert plan.slots == causal.slots == 2
-    assert plan.index.tolist() == causal.index.tolist() == [0, 0, 0, 1]
+    assert plan.steps == causal.steps == 3
+    assert plan.q_tile.tolist() == causal.q_tile.tolist() == [0, 1, 1]
+    assert plan.k_tile.tolist() == causal.k_tile.tolist() == [0, 0, 1]
+    first, last = pallas_ops._FIRST, pallas_ops._LAST
     # the tile under the diagonal is wholly visible to the causal mask; a
     # data mask's is masked from the pairs like the others
-    assert causal.flag.tolist() == [1, 0, 2, 1]
-    assert plan.flag.tolist() == [1, 0, 1, 1]
+    assert causal.flag.tolist() == [1 | first | last, 2 | first, 1 | last]
+    assert plan.flag.tolist() == [1 | first | last, 1 | first, 1 | last]
 
 
 def test_no_gradient_reaches_the_log_sum_exp():
