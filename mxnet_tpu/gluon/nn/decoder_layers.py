@@ -1,10 +1,12 @@
-"""Layers of decoder language models over mixture-of-experts feed-forwards:
-weighted RMSNorm, grouped-query attention with query/key norm and rotary
-positions (under the block-diffusion mask, or causal over the keys a learned
-indexer picks for each query), the mixture-of-experts layer for the experts
-this chip holds, and the decoder layers that join them.
-gluon/model_zoo/block_diffusion.py and sparse_causal_lm.py build models of
-them from a configuration.
+"""Layers of decoder language models: weighted RMSNorm, grouped-query
+attention with query/key norm and rotary positions (causal, under the
+block-diffusion mask, or causal over the keys a learned indexer picks for each
+query), a gated short convolution along the sequence, a dense gated
+feed-forward, the mixture-of-experts layer for the experts this chip holds
+(softmax or sigmoid router), and the decoder layers that join them: attention
+over experts (two forms), and a layer whose operator and feed-forward are
+chosen per layer.  gluon/model_zoo/block_diffusion.py, sparse_causal_lm.py and
+short_conv_lm.py build models of them from a configuration.
 """
 from __future__ import annotations
 
@@ -16,7 +18,8 @@ from .basic_layers import Dense
 
 __all__ = ["RMSNorm", "BlockDiffusionAttention", "HeldExpertsMoE",
            "BlockDiffusionDecoderLayer", "IndexedSparseAttention",
-           "SparseDecoderLayer"]
+           "SparseDecoderLayer", "CausalAttention", "GatedShortConv",
+           "GatedMLP", "DecoderLayer"]
 
 
 class RMSNorm(HybridBlock):
@@ -98,12 +101,19 @@ class BlockDiffusionAttention(_GroupedQueryAttention):
 class HeldExpertsMoE(HybridBlock):
     """The part of a mixture-of-experts layer that the experts held on this
     chip give (parallel/moe.py ``moe_held_apply``): the router scores all
-    ``experts_total`` experts, takes ``experts_per_token`` with renormalised
-    weights, and experts ``first_expert .. first_expert + experts_held - 1``
-    compute ``down(silu(gate y) * up y)`` for the tokens routed to them.  No
-    token is dropped.  The held experts' matrices are stacked in 2-D leaves,
-    ``(experts_held * width, hidden)`` and ``(experts_held * hidden,
-    width)``.
+    ``experts_total`` experts, takes ``experts_per_token`` with their weights
+    normalised over the picked ones, and experts ``first_expert ..
+    first_expert + experts_held - 1`` compute ``down(silu(gate y) * up y)``
+    for the tokens routed to them.  No token is dropped.  The held experts'
+    matrices are stacked in 2-D leaves, ``(experts_held * width, hidden)``
+    and ``(experts_held * hidden, width)``.
+
+    The router's form is a property of the model: ``scoring`` "softmax" (the
+    largest probabilities, renormalised) or "sigmoid" (each expert's own
+    score), the weights times ``scale``, and with ``selection_bias`` a leaf
+    ``expert_bias`` (experts_total,) added to the sigmoid scores for the
+    selection alone: the weights are the unbiased scores', so its gradient
+    is exactly 0 and a training step leaves it where the checkpoint had it.
 
     ``load`` (non-trainable state, written by every training step) holds the
     newest step's ``[pairs routed here, largest held expert's load]``;
@@ -111,12 +121,17 @@ class HeldExpertsMoE(HybridBlock):
     ``moe.load.<this block's prefix>`` (``count`` and ``max``)."""
 
     def __init__(self, hidden, width, experts_total, experts_per_token,
-                 experts_held, first_expert=0, **kwargs):
+                 experts_held, first_expert=0, scoring="softmax", scale=1.0,
+                 selection_bias=False, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"experts_per_token": experts_per_token,
-                       "expert_width": width, "first_expert": first_expert}
+                       "expert_width": width, "first_expert": first_expert,
+                       "scoring": scoring, "scale": scale}
         with self.name_scope():
             get = self.params.get
+            if selection_bias:
+                self.expert_bias = get("expert_bias", shape=(experts_total,),
+                                       init="zeros")
             self.router_weight = get("router_weight",
                                      shape=(experts_total, hidden))
             self.gate_weight = get("gate_weight",
@@ -134,13 +149,20 @@ class HeldExpertsMoE(HybridBlock):
                        lambda: tuple(float(v) for v in state.data().asnumpy()))
 
     def hybrid_forward(self, F, x, router_weight, gate_weight, up_weight,
-                       down_weight, load):
+                       down_weight, load, expert_bias=None):
         out, now = F._contrib_moe_held_experts(
             x, router_weight, gate_weight, up_weight, down_weight,
-            **self._attrs)
+            expert_bias, **self._attrs)
         if autograd.is_training() and isinstance(out, NDArray):
             load._set_data(now._data)
         return out
+
+    def route(self, F, x, router_weight, expert_bias=None):
+        """What this layer's router picks for the rows ``x``, as the layer
+        itself routes them: (weights, expert ids), each (rows,
+        ``experts_per_token``); for a caller that checks the selection."""
+        return F._contrib_moe_route(x, router_weight, expert_bias,
+                                    **self._attrs)
 
 
 class BlockDiffusionDecoderLayer(HybridBlock):
@@ -270,3 +292,90 @@ class SparseDecoderLayer(HybridBlock):
         rows, loss, _ = self.attn(self.attn_norm(x), positions)
         h = x + rows
         return h + self.moe(self.moe_norm(h)), loss
+
+
+class CausalAttention(_GroupedQueryAttention):
+    """Causal grouped-query attention: ``BlockDiffusionAttention``'s block
+    (projections without bias, RMSNorm over each head's dimensions of q and
+    k, rotary on all of them, scale ``1 / sqrt(head_dim)``) under the causal
+    mask, by the attention kernels at the default matmul precision.  Inputs:
+    ``x`` (B, L, hidden) and ``positions`` (L,)."""
+
+    def __init__(self, hidden, heads, kv_heads, head_dim, rope_base=1e6,
+                 epsilon=1e-6, **kwargs):
+        super().__init__(hidden, heads, kv_heads, head_dim, epsilon, **kwargs)
+        self._rope_base = rope_base
+
+    def hybrid_forward(self, F, x, positions):
+        q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
+            t, positions, base=self._rope_base))
+        return self._output(F, F._contrib_causal_attention(q, k, v))
+
+
+class GatedShortConv(HybridBlock):
+    """A gated short convolution along the sequence (LFM2's operator): the
+    input projection gives three streams ``[Bg, Cg, X]`` of ``hidden``, a
+    causal depthwise convolution of ``taps`` taps runs over ``Bg * X`` (one
+    filter a channel, zeros before the sequence's first row), ``Cg`` gates
+    its output, and the output projection follows; no bias.  Row ``t`` reads
+    rows ``t - taps + 1 .. t`` of its own sequence.  Inputs: ``x`` (B, L,
+    hidden) and, as every operator of a ``DecoderLayer``, the positions,
+    which it does not read."""
+
+    def __init__(self, hidden, taps=3, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.in_proj = Dense(3 * hidden, in_units=hidden, use_bias=False,
+                                 flatten=False, prefix="in_")
+            self.out_proj = Dense(hidden, in_units=hidden, use_bias=False,
+                                  flatten=False, prefix="out_")
+            self.taps_weight = self.params.get("taps_weight",
+                                               shape=(hidden, taps))
+
+    def hybrid_forward(self, F, x, positions=None, taps_weight=None):
+        return self.out_proj(F._contrib_gated_short_conv(self.in_proj(x),
+                                                         taps_weight))
+
+
+class GatedMLP(HybridBlock):
+    """A dense gated feed-forward, ``down(silu(gate u) * up u)`` of width
+    ``width``, no bias."""
+
+    def __init__(self, hidden, width, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.gate, self.up, self.down = (
+                Dense(units, in_units=in_units, use_bias=False, flatten=False,
+                      prefix=prefix)
+                for units, in_units, prefix in ((width, hidden, "gate_"),
+                                                (width, hidden, "up_"),
+                                                (hidden, width, "down_")))
+
+    def hybrid_forward(self, F, x):
+        import jax
+        with jax.named_scope("mlp.dense"):
+            gate = self.gate(x)
+            return self.down(gate * F.sigmoid(gate) * self.up(x))
+
+
+class DecoderLayer(HybridBlock):
+    """``h = x + Op(RMSNorm(x)); x' = h + FF(RMSNorm(h))`` with the operator
+    and the feed-forward chosen for this layer: ``operator`` and
+    ``feed_forward`` build them (each a callable without arguments, called
+    inside this layer's name scope, so that the child's prefix says its
+    kind), the operator a block over ``(rows, positions)``, the feed-forward
+    one over the rows."""
+
+    def __init__(self, hidden, operator, feed_forward, epsilon=1e-6,
+                 **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.operator_norm = RMSNorm(hidden, epsilon,
+                                         prefix="operator_norm_")
+            self.operator = operator()
+            self.ffn_norm = RMSNorm(hidden, epsilon, prefix="ffn_norm_")
+            self.feed_forward = feed_forward()
+
+    def hybrid_forward(self, F, x, positions):
+        h = x + self.operator(self.operator_norm(x), positions)
+        return h + self.feed_forward(self.ffn_norm(h))

@@ -4,9 +4,10 @@ weighted RMSNorm, rotary position embedding with the positions as an input
 mixture-of-experts layer for the experts held on this chip (the layer itself
 is parallel/moe.py's, imported when the operator runs: ``mx.nd`` installs its
 operators before ``parallel`` is imported, so an operator registered there
-would not be found), and a learned indexer's two: the selection of each
-query's keys and the loss that trains it.  The attention kernels (block mask,
-picked pairs) are in pallas_ops.py.
+would not be found), a gated short convolution along the sequence, and a
+learned indexer's two: the selection of each query's keys and the loss that
+trains it.  The attention kernels (causal, block mask, picked pairs) are in
+pallas_ops.py.
 """
 from __future__ import annotations
 
@@ -68,21 +69,52 @@ def _rotary_embedding(attrs, x, positions):
 
 @register("_contrib_moe_held_experts", num_outputs=2, no_jit=True,
           shape_rule="input", dtype_rule="input")
-def _moe_held_experts(attrs, x, router_w, gate_w, up_w, down_w):
+def _moe_held_experts(attrs, x, router_w, gate_w, up_w, down_w, bias=None):
     """``parallel.moe.moe_held_apply`` as an operator.  ``x``: (..., d);
     ``router_w``: (E, d) over all experts; ``gate_w``, ``up_w``:
     (E_held * f, d) and ``down_w``: (E_held * d, f), the held experts'
-    matrices stacked along the first axis.  attrs: ``experts_per_token``,
-    ``expert_width`` (f), ``first_expert``.  Outputs: the layer's output,
-    shaped as ``x``, and float32 ``[pairs routed here, largest load]``."""
+    matrices stacked along the first axis; optionally ``bias``: (E,), a
+    sigmoid router's selection bias.  attrs: ``experts_per_token``,
+    ``expert_width`` (f), ``first_expert``, and the router's form:
+    ``scoring`` ("softmax", the default, or "sigmoid") and ``scale`` (the
+    weights' factor, default 1).  Outputs: the layer's output, shaped as
+    ``x``, and float32 ``[pairs routed here, largest load]``."""
     from ..parallel.moe import moe_held_apply
     d, f = x.shape[-1], int(attrs["expert_width"])
     out, load = moe_held_apply(
         x.reshape(-1, d), router_w, gate_w.reshape(-1, f, d),
         up_w.reshape(-1, f, d), down_w.reshape(-1, d, f),
         int(attrs["experts_per_token"]),
-        first_expert=int(attrs.get("first_expert", 0)))
+        first_expert=int(attrs.get("first_expert", 0)),
+        scoring=str(attrs.get("scoring", "softmax")),
+        scale=float(attrs.get("scale", 1.0)), bias=bias)
     return out.reshape(x.shape), load
+
+
+@register("_contrib_moe_route", num_outputs=2, no_jit=True,
+          shape_rule="input", dtype_rule="input")
+def _moe_route(attrs, x, router_w, bias=None):
+    """The router of ``_contrib_moe_held_experts`` alone
+    (``parallel.moe.route_tokens``), for a caller that checks the selection:
+    the same inputs and attrs (those of the experts are not read); outputs
+    the picked experts' weights, float32, and their ids, int32, each
+    (rows, ``experts_per_token``)."""
+    from ..parallel.moe import route_tokens
+    return route_tokens(
+        x.reshape(-1, x.shape[-1]), router_w, int(attrs["experts_per_token"]),
+        str(attrs.get("scoring", "softmax")), float(attrs.get("scale", 1.0)),
+        bias)
+
+
+@register("_contrib_gated_short_conv", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _gated_short_conv(attrs, streams, taps):
+    """The gated short convolution between a block's two projections
+    (``pallas_ops.gated_short_conv``): ``streams`` (B, L, 3 d), the input
+    projection's three streams ``[Bg, Cg, X]``; ``taps`` (d, K), one filter a
+    channel; output ``Cg * conv(Bg * X)``, (B, L, d), causal along L."""
+    from .pallas_ops import gated_short_conv
+    return gated_short_conv(streams, taps)
 
 
 def _order_bits(scores):

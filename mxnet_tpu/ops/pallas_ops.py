@@ -468,8 +468,10 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     held = jax.ShapeDtypeStruct((BHkv, Tk_t, D), jnp.float32)
     # dk and dv whole, each with the pipeline's second buffer, beside the 16
     # MiB a kernel has by default for its tiles and temporaries; past 100 of
-    # a v5e core's 128 MiB the compiler refuses the call, and says so
-    vmem_bytes = min(16 * 2 ** 20 + 2 * 2 * Tk_t * D * 4, 100 * 2 ** 20)
+    # a v5e core's 128 MiB the compiler refuses the call, and says so.  A
+    # row of 64 takes a whole row of 128 lanes
+    lanes = -(-D // 128) * 128
+    vmem_bytes = min(16 * 2 ** 20 + 2 * 2 * Tk_t * lanes * 4, 100 * 2 ** 20)
     operands = [qf, gf, lse, delta, kf, kt, vf]
     in_specs = [q_spec, q_spec, row_spec, row_spec, k_spec,
                 pl.BlockSpec((None, D, bk), kt_side), k_spec]
@@ -576,7 +578,12 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
     lengths the diagonal's alignment is ambiguous, so bare ``True`` refuses
     and the caller must say which convention they mean: 'top' aligns query 0
     with key 0; 'bottom' is the KV-cache decode convention (the last query
-    sees every key) — e.g. ``causal='bottom'`` for T=1, Tk=n decode."""
+    sees every key) — e.g. ``causal='bottom'`` for T=1, Tk=n decode.
+
+    The matrix units take the inputs' own dtype (float32 operands at
+    precision 'highest'); a decoder block that computes at XLA's default
+    precision calls ``causal_attention``, the same kernels over the same
+    tables with bfloat16 operands."""
     if scale is None:
         scale = 1.0 / _np.sqrt(q.shape[-1])
     # identity checks: 1/1.0 would sneak past an `in` test via 1 == True
@@ -597,6 +604,25 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None):
             % (q.shape[2], k.shape[2]))
     return _attention(q, k, v, _causal_mask(causal, q.shape[2], k.shape[2]),
                       scale, "highest", interpret, 256, 512)
+
+
+def causal_attention(q, k, v, scale=None, precision="default", interpret=None,
+                     block_q=512, block_k=512):
+    """Causal attention of a decoder block in training: ``q`` (B, H, T, D),
+    ``k``/``v`` (B, Hkv, T, D), query ``t`` sees keys ``s <= t``.  The
+    kernels of ``flash_attention`` under the mask ``("causal", 0)`` (the
+    tiles above the diagonal are never visited, those wholly under it not
+    masked), in tiles of 512 x 512 and, at ``precision`` 'default', with
+    bfloat16 operands and float32 accumulation as XLA's products have them."""
+    import jax
+    if q.shape[2] != k.shape[2]:
+        raise ValueError("causal attention over %d queries and %d keys: one "
+                         "square" % (q.shape[2], k.shape[2]))
+    if scale is None:
+        scale = 1.0 / _np.sqrt(q.shape[-1])
+    with jax.named_scope("attn.causal"):
+        return _attention(q, k, v, ("causal", 0), scale, precision, interpret,
+                          block_q, block_k, scope="attn.causal")
 
 
 def block_mask_attention(q, k, v, seq_len, block_length, scale=None,
@@ -876,10 +902,211 @@ def select_thresholds(scores, k, interpret=False):
     return threshold[..., 0], cut[..., 0]
 
 
+# ---------------------------------------------------------------------------
+# the gated short convolution
+# ---------------------------------------------------------------------------
+SHORT_CONV_ROWS = 256       # rows of the sequence to a grid step
+_SHORT_CONV_LANES = 512     # channels to a pass inside a step
+_HALO = 8                   # rows fetched of the neighbouring tile
+
+
+def _gated_short_conv_reference(streams, taps):
+    """The gated short convolution in XLA, ``K`` shifted copies in one
+    elementwise pass: the kernels' oracle, and the path off the TPU."""
+    import jax.numpy as jnp
+    K = taps.shape[1]
+    gate_in, gate_out, x = jnp.split(streams, 3, axis=-1)
+    z = gate_in * x
+    length = z.shape[1]
+    padded = jnp.pad(z, ((0, 0), (K - 1, 0), (0, 0)))
+    taps = taps.astype(z.dtype)
+    c = padded[:, :length] * taps[:, 0]
+    for j in range(1, K):
+        c = c + padded[:, j:j + length] * taps[:, j]
+    return gate_out * c
+
+
+def _rows_from_before(z, s, before, row):
+    """``z`` (T, C) moved ``s`` rows on, its first ``s`` rows the last of
+    ``before`` (8, C), the tile's rows before it; ``row``: (8, C) iota."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    moved = pltpu.roll(z, s, 0)
+    first = jnp.where(row < s, pltpu.roll(before, s, 0), moved[:_HALO])
+    return jnp.concatenate([first, moved[_HALO:]], axis=0)
+
+
+def _rows_from_after(z, s, after, row):
+    """``z`` (T, C) moved ``s`` rows back, its last ``s`` rows the first of
+    ``after`` (8, C), the tile's rows after it."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+    T = z.shape[0]
+    moved = pltpu.roll(z, T - s, 0)
+    last = jnp.where(row >= _HALO - s, pltpu.roll(after, _HALO - s, 0),
+                     moved[T - _HALO:])
+    return jnp.concatenate([moved[:T - _HALO], last], axis=0)
+
+
+def gated_short_conv(streams, taps, interpret=None, rows=SHORT_CONV_ROWS):
+    """The gated short convolution between a block's two projections (LFM2's
+    ``Lfm2ShortConv``).  ``streams``: (B, L, 3 d), the input projection's
+    three streams ``[Bg, Cg, X]``; ``taps``: (d, K), one filter a channel.
+    ``z = Bg * X``; ``c[t] = sum_j taps[:, j] * z[t - (K - 1) + j]``, ``z``
+    zero before the sequence's first row (a causal depthwise
+    cross-correlation along L: row ``t`` reads rows ``t - K + 1 .. t`` of its
+    own sequence); output ``Cg * c``, (B, L, d).  Nothing for a matrix unit:
+    the bound is memory's, 4 passes of ``B L d`` values forward (three
+    streams read, one written) and 7 backward.
+
+    On a TPU (or where ``interpret`` is given), with ``d`` a multiple of 128
+    and ``L`` of ``rows``: two kernels, ``short_conv_fwd`` and
+    ``short_conv_bwd``, each over tiles of ``rows`` rows and all channels,
+    that read every stream once, take the ``K - 1`` rows a tile needs of its
+    neighbour from an 8-row block of the same array, and move rows inside the
+    tile on the rotate unit; the backward kernel recomputes ``c``, writes the
+    three streams' gradients as one array and a tile's share of the taps'
+    gradient, which XLA then sums.  XLA's own form of the forward pass read
+    2.4 times its bound on a v5e and 3.3 times with the backward pass
+    (PERF.md, PR 35).  Elsewhere the XLA form."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    B, L, d3 = streams.shape
+    d, K = taps.shape
+    if d3 != 3 * d:
+        raise ValueError("three streams of %d channels, not %d in all"
+                         % (d, d3))
+    rows = min(rows, L)
+    use = interpret is not None or jax.default_backend() == "tpu"
+    if not use or d % 128 or L % rows or rows % _HALO or K - 1 > _HALO \
+            or rows < 2 * _HALO:
+        with jax.named_scope("conv.gated"):
+            return _gated_short_conv_reference(streams, taps)
+    from jax.experimental.pallas import tpu as pltpu
+    n, lanes = L // rows, min(_SHORT_CONV_LANES, d)
+    per_tile = rows // _HALO
+    # a tap a row, so that it lies along the lanes of its channels
+    taps_t = jnp.pad(taps.astype(streams.dtype).T, ((0, _HALO - K), (0, 0)))
+
+    def tile(width):
+        return pl.BlockSpec((None, rows, width), lambda b, i: (b, i, 0))
+
+    def before(width):      # the 8 rows before tile i (tile 0: unused)
+        return pl.BlockSpec((None, _HALO, width), lambda b, i: (
+            b, jnp.maximum(i * per_tile - 1, 0), 0))
+
+    def after(width):       # the 8 rows after tile i (the last: unused)
+        return pl.BlockSpec((None, _HALO, width), lambda b, i: (
+            b, jnp.minimum((i + 1) * per_tile, n * per_tile - 1), 0))
+
+    taps_spec = pl.BlockSpec((_HALO, d), lambda b, i: (0, 0))
+
+    chunks = [slice(c, min(c + lanes, d)) for c in range(0, d, lanes)]
+
+    def of(k, cols):        # where stream k's channels ``cols`` lie in a row
+        return slice(k * d + cols.start, k * d + cols.stop)
+
+    def conv(z, z_before, w_ref, cols, row):
+        """(c, [z moved s rows on, s = 0 .. K - 1]) of one chunk."""
+        moved = [z] + [_rows_from_before(z, s, z_before, row)
+                       for s in range(1, K)]
+        c = w_ref[K - 1:K, cols] * z
+        for s in range(1, K):
+            c = c + w_ref[K - 1 - s:K - s, cols] * moved[s]
+        return c, moved
+
+    def fwd_kernel(s_ref, b_ref, w_ref, o_ref):
+        first = pl.program_id(1) == 0
+        for cols in chunks:
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (_HALO, cols.stop - cols.start), 0)
+            z = s_ref[:, of(0, cols)] * s_ref[:, of(2, cols)]
+            z_before = jnp.where(
+                first, 0.0, b_ref[:, of(0, cols)] * b_ref[:, of(2, cols)])
+            c, _ = conv(z, z_before, w_ref, cols, row)
+            o_ref[:, cols] = s_ref[:, of(1, cols)] * c
+
+    def bwd_kernel(g_ref, s_ref, b_ref, ga_ref, a_ref, w_ref, ds_ref,
+                   dw_ref):
+        i = pl.program_id(1)
+        dw_ref[...] = jnp.zeros(dw_ref.shape, dw_ref.dtype)
+        for cols in chunks:
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (_HALO, cols.stop - cols.start), 0)
+            gate_in, gate_out, x = (s_ref[:, of(k, cols)] for k in range(3))
+            g = g_ref[:, cols]
+            z_before = jnp.where(
+                i == 0, 0.0, b_ref[:, of(0, cols)] * b_ref[:, of(2, cols)])
+            c, moved = conv(gate_in * x, z_before, w_ref, cols, row)
+            dc = g * gate_out
+            dc_after = jnp.where(i == n - 1, 0.0,
+                                 ga_ref[:, cols] * a_ref[:, of(1, cols)])
+            # c[t] reads z[t - s]: dz[t] collects dc[t + s]
+            dz = w_ref[K - 1:K, cols] * dc
+            for s in range(1, K):
+                dz = dz + w_ref[K - 1 - s:K - s, cols] * _rows_from_after(
+                    dc, s, dc_after, row)
+            ds_ref[:, of(0, cols)] = dz * x
+            ds_ref[:, of(1, cols)] = g * c
+            ds_ref[:, of(2, cols)] = dz * gate_in
+            for s in range(K):
+                dw_ref[K - 1 - s:K - s, cols] = jnp.sum(
+                    dc * moved[s], axis=0, keepdims=True)
+
+    def call(kernel, name, in_specs, out_specs, out_shape):
+        # a tile of 256 rows of three streams is 6 MiB, twice for the
+        # pipeline, beside the output's: more than a kernel's default 16
+        return pl.pallas_call(
+            kernel, grid=(B, n), in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel"),
+                vmem_limit_bytes=64 * 2 ** 20),
+            interpret=bool(interpret), name=name)
+
+    @jax.custom_vjp
+    def f(streams_, taps_t_):
+        return f_fwd(streams_, taps_t_)[0]
+
+    def f_fwd(streams_, taps_t_):
+        out = call(fwd_kernel, "short_conv_fwd",
+                   [tile(d3), before(d3), taps_spec], tile(d),
+                   jax.ShapeDtypeStruct((B, L, d), streams_.dtype))(
+                       streams_, streams_, taps_t_)
+        return out, (streams_, taps_t_)
+
+    def f_bwd(res, g):
+        streams_, taps_t_ = res
+        with jax.named_scope("conv.gated"):
+            d_streams, d_taps = call(
+                bwd_kernel, "short_conv_bwd",
+                [tile(d), tile(d3), before(d3), after(d), after(d3),
+                 taps_spec],
+                [tile(d3), pl.BlockSpec((None, None, _HALO, d),
+                                        lambda b, i: (b, i, 0, 0))],
+                [jax.ShapeDtypeStruct((B, L, d3), streams_.dtype),
+                 jax.ShapeDtypeStruct((B, n, _HALO, d), jnp.float32)])(
+                     g, streams_, streams_, g, streams_, taps_t_)
+        return d_streams, jnp.sum(d_taps, axis=(0, 1)).astype(taps_t_.dtype)
+
+    f.defvjp(f_fwd, f_bwd)
+    with jax.named_scope("conv.gated"):
+        return f(streams, taps_t)
+
+
 @register("_contrib_flash_attention")
 def _flash_attention_op(attrs, q, k, v):
     return flash_attention(q, k, v, causal=bool(attrs.get("causal", False)),
                            scale=attrs.get("scale"))
+
+
+@register("_contrib_causal_attention", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _causal_attention_op(attrs, q, k, v):
+    """Causal attention at the default matmul precision (``causal_attention``);
+    optional attr ``scale``."""
+    return causal_attention(q, k, v, scale=attrs.get("scale"))
 
 
 @register("_contrib_block_mask_attention", no_jit=True, shape_rule="input",

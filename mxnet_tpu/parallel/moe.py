@@ -1,7 +1,9 @@
 """Expert parallelism: mixture-of-experts layers for an 'ep' axis.
 
-Two layers share the router's top-k and its renormalisation
-(``route_top_k``):
+Two layers share the router's choice (``route_top_k``: the ``k`` experts
+ranked highest, their weights normalised over the picked ``k``; softmax
+probabilities that rank and weigh alike, or sigmoid scores ranked with a
+selection bias that stays out of the weights):
 
 * ``moe_apply`` (below, first): the capacity layer.  Static shapes from a
   fixed per-expert capacity, tokens over capacity dropped, the exchange by
@@ -38,15 +40,30 @@ from __future__ import annotations
 import functools
 
 
-def route_top_k(probs, k):
-    """The router's choice, shared by both layers: the ``k`` largest of each
-    token's probabilities ``(T, E)`` with their weights renormalised to sum
-    to 1 (Switch/GShard; ``norm_topk_prob``).  Returns (weights, expert ids),
-    each ``(T, k)``."""
+SIGMOID_NORM_EPS = 1e-6    # under a sigmoid router's normalisation
+
+
+def route_top_k(scores, k, rank_by=None, eps=0.0, scale=1.0):
+    """The router's choice, shared by both layers: of each token's scores
+    ``(T, E)`` the ``k`` experts that ``rank_by`` ranks highest (the scores
+    themselves where it is None; ties to the lower id), weighted by their
+    scores over the sum of the picked ``k`` (+ ``eps``), times ``scale``
+    (``norm_topk_prob``).  With softmax probabilities and the defaults that
+    is Switch/GShard's renormalised top-k; a sigmoid router ranks by ``scores
+    + bias`` and weighs by the scores alone (DeepSeek-V3's and LFM2's
+    selection bias).  Returns (weights, expert ids), each ``(T, k)``."""
     import jax
     import jax.numpy as jnp
-    vals, idx = jax.lax.top_k(probs, k)
-    return vals / jnp.sum(vals, axis=-1, keepdims=True), idx
+    if rank_by is None:
+        vals, idx = jax.lax.top_k(scores, k)
+    else:
+        _, idx = jax.lax.top_k(rank_by, k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    total = jnp.sum(vals, axis=-1, keepdims=True)
+    # an ``eps`` of 0 and a ``scale`` of 1 add no operation: the softmax
+    # router's traced program stays what it was
+    weights = vals / (total + eps if eps else total)
+    return (weights * scale if scale != 1.0 else weights), idx
 
 
 def _one_hot_dispatch(gates, k, capacity):
@@ -165,7 +182,28 @@ def make_expert_parallel_moe(mesh, expert_fn, axis_name="ep", k=2,
 # the dropless layer for the experts held here
 # ---------------------------------------------------------------------------
 
-def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0):
+def route_tokens(x, router_w, k, scoring="softmax", scale=1.0, bias=None):
+    """The router of ``moe_held_apply``: (weights, expert ids), each (T, k),
+    of tokens ``x`` (T, d) over all the experts of ``router_w`` (E, d), from
+    float32 logits at precision 'highest'; ``scoring``, ``scale`` and
+    ``bias`` as there."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.dot(x.astype(jnp.float32), router_w.T.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        return route_top_k(jax.nn.softmax(logits, axis=-1), k, scale=scale)
+    if scoring != "sigmoid":
+        raise ValueError("a router scores by softmax or sigmoid, not %r"
+                         % (scoring,))
+    scores = jax.nn.sigmoid(logits)
+    return route_top_k(scores, k, None if bias is None
+                       else scores + bias.astype(jnp.float32),
+                       SIGMOID_NORM_EPS, scale)
+
+
+def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
+                   scoring="softmax", scale=1.0, bias=None):
     """The held experts' part of a mixture-of-experts layer, no token
     dropped.
 
@@ -173,6 +211,13 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0):
     gate_w, up_w: (E_held, f, d); down_w: (E_held, d, f): the matrices of
     experts ``first_expert .. first_expert + E_held - 1``, which live here.
     Expert e computes ``down(silu(gate y) * up y)``.
+
+    The router's form is the model's: ``scoring`` "softmax" (probabilities
+    over all E, the ``k`` largest, renormalised) or "sigmoid" (each logit's
+    sigmoid; the ``k`` largest of ``score + bias`` where a ``bias`` (E,) is
+    given, weighted by the scores without it over their sum +
+    ``SIGMOID_NORM_EPS``); either times ``scale``.  The bias enters the
+    selection alone, so no gradient reaches it.
 
     Every held expert's hidden units are computed for every token, as one
     feed-forward of width ``E_held * f`` (three plain products on the MXU),
@@ -191,9 +236,9 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0):
 
     Returns ``(out, load)``: ``out`` (T, d) is
     ``sum_e w_e expert_e(x)`` over each token's chosen experts that are held
-    here (``w`` the renormalised top-k weights of the float32 softmax over
-    all E); ``load`` is float32 ``[pairs routed here, the largest held
-    expert's load]``."""
+    here (``w`` the router's weights, from float32 scores over all E);
+    ``load`` is float32 ``[pairs routed here, the largest held expert's
+    load]``."""
     import jax
     import jax.numpy as jnp
     from .. import profiler
@@ -203,11 +248,10 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0):
     profiler.count("moe.layers")
     profiler.count("moe.experts_held", held)
     profiler.count("moe.experts_total", E)
+    profiler.count("moe.rows", T)
 
     with jax.named_scope("moe.route"):
-        logits = jnp.dot(x.astype(jnp.float32), router_w.T.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        weights, experts = route_top_k(jax.nn.softmax(logits, axis=-1), k)
+        weights, experts = route_tokens(x, router_w, k, scoring, scale, bias)
         # (T, held): the weight of each held expert for each token, 0 where
         # the token did not choose it
         chosen = (experts - first_expert)[:, :, None] == jnp.arange(held)

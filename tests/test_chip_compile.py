@@ -244,3 +244,41 @@ def test_held_experts_layer_compiles_for_v5e(one_chip):
         s(16, 2048, 768)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     _fits(compiled)
+
+
+def test_short_conv_decoder_s_blocks_compile_for_v5e(one_chip):
+    """The causal kernels at head size 64 with 4 query heads a key/value
+    head (32 to 8 over 2 sequences of 8,192 rows, float32 in, tiles of
+    512 x 512: one key/value head's dk and dv are rows of 64, which take
+    whole rows of 128 lanes in VMEM), forward and backward, a grid step for
+    each of the 136 causal tiles; and the gated short convolution's two
+    kernels over the same rows: tiles of 256 rows and all 2,048 channels of
+    the three streams, 8-row blocks of the neighbouring tiles beside them."""
+    from mxnet_tpu.ops.pallas_ops import causal_attention
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.float32,
+                              sharding=one_chip)
+    step = jax.grad(lambda q, k, v: jnp.sum(causal_attention(
+        q, k, v, interpret=False)), (0, 1, 2))
+    compiled = jax.jit(step).lower(q, kv, kv).compile()
+    assert set(re.findall(r"%(attention_\w+?)(?:\.\d+)? = ",
+                          compiled.as_text())) == {
+        "attention_fwd", "attention_bwd"}
+    assert _pallas_grids(jax.make_jaxpr(step)(q, kv, kv).jaxpr) == {
+        "attention_fwd": (64, 136), "attention_bwd": (64, 136)}
+    _fits(compiled)
+
+    from mxnet_tpu.ops.pallas_ops import gated_short_conv
+    streams = jax.ShapeDtypeStruct((2, 8192, 3 * 2048), jnp.float32,
+                                   sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=one_chip)
+    step = jax.value_and_grad(lambda s, w: jnp.sum(gated_short_conv(
+        s, w, interpret=False)), (0, 1))
+    compiled = jax.jit(step).lower(streams, taps).compile()
+    assert set(re.findall(r"%(short_conv_\w+?)(?:\.\d+)? = ",
+                          compiled.as_text())) == {
+        "short_conv_fwd", "short_conv_bwd"}
+    assert _pallas_grids(jax.make_jaxpr(step)(streams, taps).jaxpr) == {
+        "short_conv_fwd": (2, 32), "short_conv_bwd": (2, 32)}
+    _fits(compiled)
