@@ -353,7 +353,9 @@ class HybridBlock(Block):
         block's backward and saves the operations that only it needs: the
         attention call names its kernel's output and log-sum-exp
         (``ops.pallas_ops.ATTENTION_RESIDUALS``), ``batch * heads * rows *
-        (head_dim + 1)`` float32 values for the forward kernel's second run.
+        (head_dim + 1)`` float32 values for the forward kernel's second run;
+        the same tuple's third name keeps the held experts' slot table
+        (``parallel.moe.moe_held_apply``: three sorts' results).
         ``donate_params``: see CachedOp."""
         self._active = active
         self._flags = kwargs

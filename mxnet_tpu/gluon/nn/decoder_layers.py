@@ -104,9 +104,14 @@ class HeldExpertsMoE(HybridBlock):
     ``experts_total`` experts, takes ``experts_per_token`` with their weights
     normalised over the picked ones, and experts ``first_expert ..
     first_expert + experts_held - 1`` compute ``down(silu(gate y) * up y)``
-    for the tokens routed to them.  No token is dropped.  The held experts'
-    matrices are stacked in 2-D leaves, ``(experts_held * width, hidden)``
-    and ``(experts_held * hidden, width)``.
+    for the tokens routed to them.  No token is dropped: a row's pairs are
+    sorted by expert into a table of ``rows x min(experts_per_token,
+    experts_held)`` slots and a tile of alignment an expert, which holds
+    every pair of every routing, and grouped products run over its tiles
+    (every slot every step, an empty one with weight 0: a step's time does
+    not follow the routing).  The held experts' matrices are stacked in 2-D
+    leaves, ``(experts_held * width, hidden)`` and ``(experts_held * hidden,
+    width)``.
 
     The router's form is a property of the model: ``scoring`` "softmax" (the
     largest probabilities, renormalised) or "sigmoid" (each expert's own

@@ -78,7 +78,10 @@ def _moe_held_experts(attrs, x, router_w, gate_w, up_w, down_w, bias=None):
     ``expert_width`` (f), ``first_expert``, and the router's form:
     ``scoring`` ("softmax", the default, or "sigmoid") and ``scale`` (the
     weights' factor, default 1).  Outputs: the layer's output, shaped as
-    ``x``, and float32 ``[pairs routed here, largest load]``."""
+    ``x``, and float32 ``[pairs routed here, largest load]``.  The rows'
+    picked experts are computed through a table of slots sorted by expert,
+    sized from the shapes alone (on a TPU by the kernels ``moe_experts_*``
+    of ``pallas_ops``, elsewhere by XLA's products over the same table)."""
     from ..parallel.moe import moe_held_apply
     d, f = x.shape[-1], int(attrs["expert_width"])
     out, load = moe_held_apply(

@@ -35,9 +35,13 @@ from .. import profiler
 from .registry import register
 
 _NEG = -1e30
-# what the attention call names for jax.checkpoint policies: the forward
-# kernel's output and log-sum-exp, the two residuals only it can produce
-ATTENTION_RESIDUALS = ("attn.out", "attn.lse")
+# what a decoder layer's calls name for jax.checkpoint policies: the
+# attention's forward kernel's output and log-sum-exp, the two residuals only
+# it can produce, and the held experts' slot table (parallel/moe.py: three
+# sorts' results, 1.3 MB a layer, where each sort recomputed is 1 to 1.5 MB
+# of program)
+SLOT_TABLE = "moe.table"
+ATTENTION_RESIDUALS = ("attn.out", "attn.lse", SLOT_TABLE)
 
 
 # ---------------------------------------------------------------------------
@@ -1093,6 +1097,253 @@ def gated_short_conv(streams, taps, interpret=None, rows=SHORT_CONV_ROWS):
     f.defvjp(f_fwd, f_bwd)
     with jax.named_scope("conv.gated"):
         return f(streams, taps_t)
+
+
+# ---------------------------------------------------------------------------
+# the held experts' grouped products
+# ---------------------------------------------------------------------------
+SLOT_TILE_ROWS = 256        # slots to a tile: one expert's, one grid step
+_WGRAD_WIDTH = 768          # an expert's hidden units to a weight-gradient step
+
+
+def slot_tile_rows(pairs, held):
+    """Rows of a tile of the slot table that holds ``pairs`` (row, expert)
+    pairs over ``held`` experts: ``SLOT_TILE_ROWS``, or for a table that
+    small the mean group rounded up to a multiple of 16 (a bfloat16 tile's
+    rows)."""
+    return min(SLOT_TILE_ROWS, max(16, -(-pairs // (16 * held)) * 16))
+
+
+def _tn(a, b):
+    """a.T @ b on the MXU, float32 out."""
+    import jax
+    import jax.numpy as jnp
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _silu_parts(g):
+    """(sigmoid(g), silu(g))."""
+    import jax
+    s = jax.nn.sigmoid(g)
+    return s, g * s
+
+
+class _Experts:
+    """The grouped products of a held experts' layer over a table of
+    ``S`` slots in tiles of ``tm``, every tile one expert's
+    (``tile_expert``, (S / tm,) int32, each expert's tiles consecutive and
+    every expert with at least one): forward, the gradients of the slots'
+    values, and the weights' gradients.  ``gate_w``, ``up_w``: (held, f, d);
+    ``down_w``: (held, d, f).
+
+    On a TPU (or where ``interpret`` is given), with ``d`` and ``f``
+    multiples of 128 and ``tm`` of 16: four kernels, ``moe_experts_hidden``,
+    ``moe_experts_down``, ``moe_experts_bwd`` (a grid step a tile, the tile's
+    expert by scalar prefetch, that expert's matrices whole in VMEM and
+    fetched again only when the expert changes; the down projection is a
+    call of its own so that a recomputed forward pass, which needs the
+    hidden units and not the output, drops it) and ``moe_experts_wgrad`` (a
+    grid step a tile and block of at most ``_WGRAD_WIDTH`` hidden units, an
+    expert's gradient blocks resident over its consecutive tiles).  The
+    number of grid steps
+    follows from the shapes alone.  The products take bfloat16 operands
+    (cast once, outside) and accumulate in float32, which is what XLA's
+    default precision gives float32 operands on the TPU; everything between
+    them is float32.  Elsewhere the same products a tile at a time in XLA,
+    at the operands' own dtype: the kernels' oracle, and the path off the
+    TPU."""
+
+    def __init__(self, tile_expert, tm, d, f, held, interpret=None):
+        import jax
+        import jax.numpy as jnp
+        self.te, self.tm, self.d, self.f, self.held = tile_expert, tm, d, f, \
+            held
+        self.n = tile_expert.shape[0]
+        use = interpret is not None or jax.default_backend() == "tpu"
+        self.kernels = bool(use and d % 128 == 0 and f % 128 == 0
+                            and tm % 16 == 0)
+        self.interpret = bool(interpret)
+        # what the matrix units are fed
+        self.cdt = jnp.bfloat16 if self.kernels else None
+
+    def cast(self, a):
+        return a.astype(self.cdt) if self.cdt is not None else a
+
+    def _tiles(self, a):
+        return a.reshape(self.n, self.tm, -1)
+
+    # -- specs ---------------------------------------------------------------
+    def _call(self, kernel, name, grid, in_specs, out_specs, out_shape):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+                out_specs=out_specs),
+            out_shape=out_shape,
+            # an expert's matrices (or its gradients' blocks) whole, twice
+            # for the pipeline, beside the tiles: 38 MiB at 1536 x 2048
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * len(grid),
+                vmem_limit_bytes=100 * 2 ** 20),
+            interpret=self.interpret, name=name)
+
+    def _specs(self):
+        from jax.experimental import pallas as pl
+        tm, d, f = self.tm, self.d, self.f
+        rows = lambda w: pl.BlockSpec((tm, w), lambda i, te: (i, 0))
+        of_expert = lambda a, b: pl.BlockSpec(
+            (None, a, b), lambda i, te: (te[i], 0, 0))
+        return rows, of_expert(f, d), of_expert(d, f)
+
+    # -- forward -------------------------------------------------------------
+    def hidden(self, x_s, w_s, gate_w, up_w, keep):
+        """Every slot's weighted hidden units ``silu(gate x) * up x * w`` by
+        its tile's expert, (S, f), as the down product takes them; with
+        ``keep`` also ``gate x`` and ``up x``, float32, which the backward
+        pass reads."""
+        import jax
+        import jax.numpy as jnp
+        S = x_s.shape[0]
+        if not self.kernels:
+            xt = self._tiles(x_s)
+            g = jnp.einsum("ntd,nfd->ntf", xt, gate_w[self.te])
+            u = jnp.einsum("ntd,nfd->ntf", xt, up_w[self.te])
+            hw = jax.nn.silu(g) * u * self._tiles(w_s)
+            return tuple(a.reshape(S, -1) for a in ((hw, g, u) if keep
+                                                    else (hw,)))
+
+        def kernel(te_ref, x_ref, w_ref, wg_ref, wu_ref, hw_ref, *kept):
+            x = x_ref[...]
+            g = _nt(x, wg_ref[...])
+            u = _nt(x, wu_ref[...])
+            hw_ref[...] = (jax.nn.silu(g) * u * w_ref[...]).astype(
+                hw_ref.dtype)
+            if kept:
+                kept[0][...], kept[1][...] = g, u
+
+        rows, in_w, _ = self._specs()
+        out_shape = [jax.ShapeDtypeStruct((S, self.f), t) for t in (
+            (x_s.dtype, jnp.float32, jnp.float32) if keep else (x_s.dtype,))]
+        return self._call(
+            kernel, "moe_experts_hidden", (self.n,),
+            [rows(self.d), rows(1), in_w, in_w],
+            [rows(self.f)] * len(out_shape), out_shape)(
+                self.te, x_s, w_s[:, None], gate_w, up_w)
+
+    def down(self, hw, down_w):
+        """``y_s`` (S, d) float32: every slot's hidden units through its
+        tile's expert's down projection."""
+        import jax
+        import jax.numpy as jnp
+        S = hw.shape[0]
+        if not self.kernels:
+            return jnp.einsum("ntf,ndf->ntd", self._tiles(hw),
+                              down_w[self.te]).reshape(S, -1)
+
+        def kernel(te_ref, hw_ref, wd_ref, y_ref):
+            y_ref[...] = _nt(hw_ref[...], wd_ref[...])
+
+        rows, _, out_w = self._specs()
+        return self._call(
+            kernel, "moe_experts_down", (self.n,), [rows(self.f), out_w],
+            rows(self.d), jax.ShapeDtypeStruct((S, self.d), jnp.float32))(
+                self.te, hw, down_w)
+
+    # -- backward: the slots' values -----------------------------------------
+    def backward(self, dy_s, g, u, w_s, gate_w, up_w, down_w):
+        """(dx_s (S, d) float32, dw_s (S,) float32, dg, du (S, f) as the
+        weight gradients' products take them)."""
+        import jax
+        import jax.numpy as jnp
+        S = dy_s.shape[0]
+        if not self.kernels:
+            tiles = self._tiles
+            dhw = jnp.einsum("ntd,ndf->ntf", tiles(dy_s), down_w[self.te])
+            s, sg = _silu_parts(tiles(g))
+            dw = jnp.sum(dhw * sg * tiles(u), axis=-1)
+            dh = dhw * tiles(w_s)
+            du = dh * sg
+            dg = dh * tiles(u) * (s + sg * (1 - s))
+            dx = jnp.einsum("ntf,nfd->ntd", dg, gate_w[self.te]) \
+                + jnp.einsum("ntf,nfd->ntd", du, up_w[self.te])
+            return (dx.reshape(S, -1), dw.reshape(S), dg.reshape(S, -1),
+                    du.reshape(S, -1))
+
+        def kernel(te_ref, dy_ref, g_ref, u_ref, w_ref, wg_ref, wu_ref,
+                   wd_ref, dx_ref, dw_ref, dg_ref, du_ref):
+            g, u = g_ref[...], u_ref[...]
+            dhw = jnp.dot(dy_ref[...], wd_ref[...],
+                          preferred_element_type=jnp.float32)
+            s, sg = _silu_parts(g)
+            dw_ref[...] = jnp.sum(dhw * sg * u, axis=1, keepdims=True)
+            dh = dhw * w_ref[...]
+            du = (dh * sg).astype(du_ref.dtype)
+            dg = (dh * u * (s + sg * (1 - s))).astype(dg_ref.dtype)
+            dg_ref[...], du_ref[...] = dg, du
+            dx_ref[...] = jnp.dot(
+                dg, wg_ref[...], preferred_element_type=jnp.float32) \
+                + jnp.dot(du, wu_ref[...], preferred_element_type=jnp.float32)
+
+        rows, in_w, out_w = self._specs()
+        dx, dw, dg, du = self._call(
+            kernel, "moe_experts_bwd", (self.n,),
+            [rows(self.d), rows(self.f), rows(self.f), rows(1), in_w, in_w,
+             out_w],
+            [rows(self.d), rows(1), rows(self.f), rows(self.f)],
+            [jax.ShapeDtypeStruct((S, self.d), jnp.float32),
+             jax.ShapeDtypeStruct((S, 1), jnp.float32),
+             jax.ShapeDtypeStruct((S, self.f), dy_s.dtype),
+             jax.ShapeDtypeStruct((S, self.f), dy_s.dtype)])(
+                 self.te, dy_s, g, u, w_s[:, None], gate_w, up_w, down_w)
+        return dx, dw[:, 0], dg, du
+
+    # -- backward: the weights -----------------------------------------------
+    def weight_gradients(self, x_s, dy_s, dg, du, hw):
+        """(d gate_w, d up_w (held, f, d), d down_w (held, d, f)), float32:
+        each expert's sums over its consecutive tiles; an expert whose tiles
+        hold no pair gets zeros."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+        held, d, f, tm = self.held, self.d, self.f, self.tm
+        if not self.kernels:
+            tiles = self._tiles
+            xt, dyt = tiles(x_s), tiles(dy_s)
+            by_expert = lambda a: jax.ops.segment_sum(a, self.te, held)
+            return (by_expert(jnp.einsum("ntf,ntd->nfd", tiles(dg), xt)),
+                    by_expert(jnp.einsum("ntf,ntd->nfd", tiles(du), xt)),
+                    by_expert(jnp.einsum("ntd,ntf->ndf", dyt, tiles(hw))))
+        fb = max(b for b in range(128, min(f, _WGRAD_WIDTH) + 1, 128)
+                 if f % b == 0)
+
+        def kernel(te_ref, x_ref, dy_ref, dg_ref, du_ref, hw_ref, dwg_ref,
+                   dwu_ref, dwd_ref):
+            i = pl.program_id(1)
+
+            @pl.when((i == 0) | (te_ref[i] != te_ref[jnp.maximum(i - 1, 0)]))
+            def _():
+                for ref in (dwg_ref, dwu_ref, dwd_ref):
+                    ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+            x = x_ref[...]
+            dwg_ref[...] += _tn(dg_ref[...], x)
+            dwu_ref[...] += _tn(du_ref[...], x)
+            dwd_ref[...] += _tn(dy_ref[...], hw_ref[...])
+
+        wide = pl.BlockSpec((tm, d), lambda j, i, te: (i, 0))
+        block = pl.BlockSpec((tm, fb), lambda j, i, te: (i, j))
+        in_w = pl.BlockSpec((None, fb, d), lambda j, i, te: (te[i], j, 0))
+        out_w = pl.BlockSpec((None, d, fb), lambda j, i, te: (te[i], 0, j))
+        return self._call(
+            kernel, "moe_experts_wgrad", (f // fb, self.n),
+            [wide, wide, block, block, block], [in_w, in_w, out_w],
+            [jax.ShapeDtypeStruct((held, f, d), jnp.float32),
+             jax.ShapeDtypeStruct((held, f, d), jnp.float32),
+             jax.ShapeDtypeStruct((held, d, f), jnp.float32)])(
+                 self.te, x_s, dy_s, dg, du, hw)
 
 
 @register("_contrib_flash_attention")

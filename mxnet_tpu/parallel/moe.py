@@ -9,12 +9,14 @@ selection bias that stays out of the weights):
   fixed per-expert capacity, tokens over capacity dropped, the exchange by
   ``all_to_all`` inside ``shard_map``.
 * ``moe_held_apply`` (end of the file): the dropless layer for the experts
-  one chip holds.  The router scores all experts; every held expert is
-  computed for every token and weighted by the router's weight for that
-  token, 0 where the token did not choose it: nothing to drop, whatever the
-  load.  What the experts held elsewhere would add is their chips' to
-  compute; on one chip the layer runs with no exchange and nothing stands in
-  for the absent chips.
+  one chip holds.  The router scores all experts; a row's pairs that land on
+  a held expert are sorted by expert into a table of slots sized for any
+  routing (a row picks distinct experts, so ``rows x min(k, held)`` slots
+  and a tile of alignment an expert hold them all), and grouped products
+  run over the table's tiles, every tile one expert's: nothing to drop,
+  whatever the load, and the same work whatever the load.  What the experts
+  held elsewhere would add is their chips' to compute; on one chip the
+  layer runs with no exchange and nothing stands in for the absent chips.
 
 The capacity layer:
 
@@ -202,6 +204,151 @@ def route_tokens(x, router_w, k, scoring="softmax", scale=1.0, bias=None):
                        SIGMOID_NORM_EPS, scale)
 
 
+def slot_table(experts, first_expert, held, tm):
+    """Where every (row, pick) pair of ``experts`` (T, k) goes in the table
+    of slots that the held experts' products run over, and what each slot
+    holds.  The table has ``S = ceil(T min(k, held) / tm) tm + held tm``
+    slots in tiles of ``tm``: a row picks distinct experts, so at most
+    ``min(k, held)`` of its pairs land on the ``held`` experts here, whatever
+    the router does, and each expert's group is padded to whole tiles (at
+    most ``tm`` slots an expert; an expert with no pair keeps one empty
+    tile, so that its gradient is written).  ``S`` and the number of tiles
+    follow from the shapes alone.
+
+    One sort of the ``T k`` pairs and the padding entries (``N`` in all, at
+    least ``S``) by (expert | not held here, padding last, then the entry's
+    own number) lays them out: an entry's position in the sorted order is its slot.  The pairs of
+    experts held elsewhere and the padding no group needs fill the tail,
+    which belongs to the last held expert and carries weight 0; what lies
+    past ``S`` is in no tile.  A second sort inverts the permutation.
+    Returns ``source`` (N,): the entry at each position, a pair ``t k + j``
+    or a number from ``T k`` up for padding; ``slot_of`` (N,): each entry's
+    position; ``tile_expert`` (S / tm,): each tile's held expert; ``here``
+    (T, k): whether the pair's expert is held here; and the held experts'
+    loads (held,)."""
+    import jax
+    import jax.numpy as jnp
+    T, k = experts.shape
+    pairs = T * k
+    slots = -(-T * min(k, held) // tm) * tm + held * tm
+    padding = max(slots - pairs, held * tm)
+    local = experts - first_expert
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).reshape(pairs)
+    per_expert = jnp.sum(group[:, None] == jnp.arange(held), axis=0)
+    # what completes each group's last tile; a whole tile for an empty group
+    fill = jnp.where(per_expert == 0, tm, -per_expert % tm)
+    pad = jnp.arange(padding)
+    pad_group = jnp.minimum(pad // tm, held - 1)
+    needed = (pad < held * tm) & (pad % tm < fill[pad_group])
+    keys = jnp.concatenate([2 * group, jnp.where(
+        needed, 2 * pad_group, 2 * held) + 1]).astype(jnp.int32)
+    entries = pairs + padding
+    entry = jnp.arange(entries, dtype=jnp.int32)
+    # every key made its entry's own, so that no sort here need be stable:
+    # XLA's stable sort of 70 k pairs is 2.5 MB of program and half a minute
+    # of compiling, an unstable one of single numbers 0.9 MB and 2.5 s
+    if (2 * held + 2) * entries < 2 ** 31:
+        both = jax.lax.sort(keys * entries + entry, is_stable=False)
+        keys, source = both // entries, both % entries
+    else:
+        keys, source = jax.lax.sort((keys, entry), num_keys=2,
+                                    is_stable=False)
+    slot_of = _moved(source, entry)
+    tile_expert = jnp.minimum(keys[:slots:tm] // 2, held - 1)
+    return source, slot_of, tile_expert, here, per_expert
+
+
+def _moved(by, values):
+    """``values`` in the order of the permutation ``by``'s inverse:
+    ``out[by[i]] = values[i]``, by a sort on ``by`` (whose numbers differ, so
+    an unstable one): a scatter or a gather of as many single numbers takes
+    the TPU six times as long."""
+    import jax
+    return jax.lax.sort((by, values), num_keys=1, is_stable=False)[1]
+
+
+def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
+                  tile_expert, here, tm):
+    """``sum_j weights[t, j] expert_j(x[t])`` over each row's pairs that
+    ``here`` marks, through the slot table of ``slot_table``: rows gathered
+    to slots, the grouped products of ``ops.pallas_ops._Experts`` over whole
+    tiles, each row's slots gathered back and added.  Both directions of
+    both passes are gathers of rows by ``source`` or ``slot_of`` (the
+    permutation and its inverse), and a value a slot or a pair moves the
+    other way by a sort on them: the backward pass is written out, since
+    the transpose of a gather is a scatter-add."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+    from ..ops.pallas_ops import SLOT_TABLE, _Experts
+
+    (T, d), k = x.shape, weights.shape[1]
+    held, f, _ = gate_w.shape
+    pairs, entries = T * k, source.shape[0]
+    slots = tile_expert.shape[0] * tm
+
+    def rows_of(a, index):
+        return a.at[index].get(mode="promise_in_bounds")
+
+    def to_slots(experts, x, weights, source, slot_of, here):
+        with jax.named_scope("moe.sort"):
+            row_of_slot = jnp.where(source < pairs, source // k, 0)[:slots]
+            w_entry = jnp.pad(jnp.where(here, weights, 0.0).reshape(pairs),
+                              (0, entries - pairs))
+            return row_of_slot, checkpoint_name(
+                _moved(slot_of, w_entry)[:slots], SLOT_TABLE), \
+                rows_of(experts.cast(x), row_of_slot)
+
+    def from_slots(a_s, slot_of):
+        """(T, width): each row's slots' values added up.  A pair of an
+        expert held elsewhere sits in an empty slot, whose values are 0, or
+        past the table: the table's last slot then stands in, which is
+        empty, since the tail never is."""
+        with jax.named_scope("moe.combine"):
+            slot_of_pair = jnp.minimum(slot_of[:pairs], slots - 1)
+            return jnp.sum(rows_of(a_s, slot_of_pair.reshape(T, k).T), axis=0)
+
+    def forward(keep, x, weights, gate_w, up_w, down_w, source, slot_of,
+                tile_expert, here):
+        experts = _Experts(tile_expert, tm, d, f, held)
+        row_of_slot, w_s, x_s = to_slots(experts, x, weights, source,
+                                         slot_of, here)
+        with jax.named_scope("moe.experts"):
+            matrices = tuple(experts.cast(w) for w in (gate_w, up_w, down_w))
+            hw, *kept = experts.hidden(x_s, w_s, *matrices[:2], keep=keep)
+            y_s = experts.down(hw, matrices[2])
+        return from_slots(y_s, slot_of).astype(x.dtype), (
+            row_of_slot, w_s, x_s, hw, *kept, *matrices, source, slot_of,
+            tile_expert, here)
+
+    @jax.custom_vjp
+    def layer(*args):
+        return forward(False, *args)[0]
+
+    def layer_bwd(kept, dout):
+        row_of_slot, w_s, x_s, hw, g, u, gate_w, up_w, down_w, source, \
+            slot_of, tile_expert, here = kept
+        experts = _Experts(tile_expert, tm, d, f, held)
+        with jax.named_scope("moe.sort"):
+            dy_s = rows_of(experts.cast(dout), row_of_slot)
+        with jax.named_scope("moe.experts"):
+            dx_s, dw_s, dg, du = experts.backward(dy_s, g, u, w_s, gate_w,
+                                                  up_w, down_w)
+            d_matrices = experts.weight_gradients(x_s, dy_s, dg, du, hw)
+        dx = from_slots(dx_s, slot_of).astype(dout.dtype)
+        with jax.named_scope("moe.sort"):
+            dw_entry = _moved(source, jnp.pad(dw_s, (0, entries - slots)))
+            d_weights = jnp.where(here, dw_entry[:pairs].reshape(T, k), 0.0)
+        return (dx, d_weights.astype(dout.dtype),
+                *(m.astype(dout.dtype) for m in d_matrices),
+                None, None, None, None)
+
+    layer.defvjp(functools.partial(forward, True), layer_bwd)
+    return layer(x, weights, gate_w, up_w, down_w, source, slot_of,
+                 tile_expert, here)
+
+
 def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
                    scoring="softmax", scale=1.0, bias=None):
     """The held experts' part of a mixture-of-experts layer, no token
@@ -219,20 +366,26 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
     ``SIGMOID_NORM_EPS``); either times ``scale``.  The bias enters the
     selection alone, so no gradient reaches it.
 
-    Every held expert's hidden units are computed for every token, as one
-    feed-forward of width ``E_held * f`` (three plain products on the MXU),
-    and a token's hidden units of an expert it did not choose are multiplied
-    by 0, of one it chose by the router's weight.  The work is the same
-    whatever the router does: no sort, no gather or scatter, no buffer that
-    a load can overflow, and a step's time does not depend on the data.
-    That is ``E / k`` times the products an even load needs (16 times with 8
-    of 128 experts per token), so it is the slower form wherever the load is
-    near even: sorted pairs through grouped products were probed at 2.8 ms a
-    layer against this form's 40 at 8,192 tokens on one v5e (PERF.md, PR 29).
-    It is here because a load-following layer's time follows the router, and
-    from seeded weights the router collapses (one expert takes most rows, by
-    the seed), where this form's time is the same for every seed; PERF.md §7
-    has what has to change before the sorted form can be measured.
+    A row's picked experts are computed, not every held one.  The (row,
+    pick) pairs are sorted by expert into a table of ``T min(k, E_held)``
+    slots plus a tile of alignment an expert (``slot_table``; the tile's
+    rows from ``ops.pallas_ops.slot_tile_rows``), which holds every pair of
+    every possible routing, since a row picks distinct experts: nothing is
+    dropped and nothing overflows, one expert taking every row included.
+    Every tile is one expert's and **every slot is computed every step**, an
+    empty one with weight 0: the number of slots, tiles and grid steps
+    follows from the shapes alone, so a step's time does not depend on the
+    data.  That is half the expert rows of computing every held expert for
+    every row (``T E_held``: the form until PR 36, 40 ms a layer at 8,192
+    rows of 16 experts of 768 on one v5e and 107.5 at 16,384 rows of 8 of
+    1536) and still ``E / k`` / 2 = 8 times what an even load fills:
+    skipping the empty tiles would follow the load, and a load-following
+    layer's time follows the router, which from seeded weights collapses by
+    the seed (PR 29 measured windows of sorted pairs at 444 to 38,892 pairs
+    a layer: 2.5% between seeds); PERF.md §6 and §7 have this form's
+    numbers and what has to change before the empty tiles can go.  The
+    table's sorts are named ``ops.pallas_ops.SLOT_TABLE`` for a recomputed
+    layer's policy: a sort of 70 k keys is 1 to 1.5 MB of compiled program.
 
     Returns ``(out, load)``: ``out`` (T, d) is
     ``sum_e w_e expert_e(x)`` over each token's chosen experts that are held
@@ -241,10 +394,13 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
     load]``."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
     from .. import profiler
+    from ..ops.pallas_ops import SLOT_TABLE, slot_tile_rows
 
     T, d = x.shape
-    E, (held, f, _) = router_w.shape[0], gate_w.shape
+    E, held = router_w.shape[0], gate_w.shape[0]
+    tm = slot_tile_rows(T * min(k, held), held)
     profiler.count("moe.layers")
     profiler.count("moe.experts_held", held)
     profiler.count("moe.experts_total", E)
@@ -252,19 +408,15 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
 
     with jax.named_scope("moe.route"):
         weights, experts = route_tokens(x, router_w, k, scoring, scale, bias)
-        # (T, held): the weight of each held expert for each token, 0 where
-        # the token did not choose it
-        chosen = (experts - first_expert)[:, :, None] == jnp.arange(held)
-        gates = jnp.sum(jnp.where(chosen, weights[:, :, None], 0.0), axis=1)
-        per_expert = jnp.sum(chosen, axis=(0, 1))
+    with jax.named_scope("moe.sort"):
+        source, slot_of, tile_expert, here, per_expert = slot_table(
+            experts, first_expert, held, tm)
+        # named, so that a recomputed layer can keep the sorts' results
+        # (hybridize(remat_policy=ATTENTION_RESIDUALS)); an identity elsewhere
+        source, slot_of, tile_expert = (checkpoint_name(a, SLOT_TABLE)
+                                        for a in (source, slot_of, tile_expert))
         load = jnp.stack([jnp.sum(per_expert),
                           jnp.max(per_expert)]).astype(jnp.float32)
-
-    with jax.named_scope("moe.experts"):
-        gate = jnp.dot(x, gate_w.reshape(held * f, d).T)       # (T, held*f)
-        up = jnp.dot(x, up_w.reshape(held * f, d).T)
-        hidden = (jax.nn.silu(gate) * up).reshape(T, held, f)
-    with jax.named_scope("moe.combine"):
-        hidden = hidden * gates.astype(x.dtype)[:, :, None]
-        return jnp.einsum("tef,edf->td", hidden, down_w), load
-
+    profiler.count("moe.slots", tile_expert.shape[0] * tm)
+    return _held_experts(x, weights.astype(x.dtype), gate_w, up_w, down_w,
+                         source, slot_of, tile_expert, here, tm), load

@@ -679,7 +679,7 @@ def test_forward_kernel_runs_once_a_layer_with_both_results_kept(
 def test_build_keeps_the_attention_residuals():
     net = block_diffusion.build(CONFIG)
     assert [layer._flags for layer in net.layers] == \
-        [dict(remat=True, remat_policy=("attn.out", "attn.lse"))] * 2
+        [dict(remat=True, remat_policy=("attn.out", "attn.lse", "moe.table"))] * 2
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -703,7 +703,7 @@ def test_naming_is_an_identity_outside_a_recomputed_region(monkeypatch,
     monkeypatch.setattr(jax.ad_checkpoint, "checkpoint_name",
                         lambda x, name: (seen.append(name), x)[1])
     assert lowered() == named
-    assert sorted(set(seen)) == sorted(NAMES)
+    assert sorted(set(seen)) == sorted(NAMES[:2])       # the attention's two
 
 
 def test_gauge_is_read_when_totals_are_asked():

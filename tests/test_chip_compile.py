@@ -227,22 +227,43 @@ def test_sparse_attention_compiles_for_v5e_forward_and_backward(one_chip):
     _fits(compiled)
 
 
-def test_held_experts_layer_compiles_for_v5e(one_chip):
-    """16 of 128 experts of 768 x 2048, 8 per token, 8,192 tokens, forward
-    and backward: three plain products over all 16 experts' hidden units."""
+@pytest.mark.parametrize("rows,total,held,width,picked,slots", [
+    (8192, 128, 16, 768, 8, 69632),     # the third and fourth cells' layer
+    (16384, 64, 8, 1536, 4, 67584),     # the fifth cell's
+])
+def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
+                                             total, held, width, picked,
+                                             slots):
+    """A chip's held experts of ``width`` x 2048, ``picked`` per token, forward
+    and backward: the pairs in ``rows * picked`` slots and a tile of 256 an
+    expert, four kernels over whole tiles with an expert's matrices in VMEM;
+    a grid step a tile (and, for the weights' gradients, a block of 768
+    hidden units), whatever the router picks."""
     from mxnet_tpu.parallel.moe import moe_held_apply
+    # the layer asks the backend whether its kernels can run: here the CPU's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def s(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     def loss(x, router_w, gate_w, up_w, down_w):
-        out, load = moe_held_apply(x, router_w, gate_w, up_w, down_w, 8)
+        out, load = moe_held_apply(x, router_w, gate_w, up_w, down_w, picked)
         return jnp.sum(out) + load[1]
 
-    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
-        s(8192, 2048), s(128, 2048), s(16, 768, 2048), s(16, 768, 2048),
-        s(16, 2048, 768)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    # the value too: a pass that needs no output drops the down projection
+    step = jax.value_and_grad(loss, (0, 1, 2, 3, 4))
+    shapes = (s(rows, 2048), s(total, 2048), s(held, width, 2048),
+              s(held, width, 2048), s(held, 2048, width))
+    compiled = jax.jit(step).lower(*shapes).compile()
+    assert set(re.findall(r"%(moe_\w+?)(?:\.\d+)? = ",
+                          compiled.as_text())) == {
+        "moe_experts_hidden", "moe_experts_down", "moe_experts_bwd",
+        "moe_experts_wgrad"}
+    tiles = slots // 256
+    assert _pallas_grids(jax.make_jaxpr(step)(*shapes).jaxpr) == {
+        "moe_experts_hidden": (tiles,), "moe_experts_down": (tiles,),
+        "moe_experts_bwd": (tiles,),
+        "moe_experts_wgrad": (width // 768, tiles)}
     _fits(compiled)
 
 

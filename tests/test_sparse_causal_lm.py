@@ -574,7 +574,7 @@ def test_compiled_step_trains_recomputes_and_records(monkeypatch):
 def test_build_keeps_the_selection_beside_the_attention_residuals():
     net = sparse_causal_lm.build(CONFIG)
     assert all(layer._flags == {
-        "remat": True, "remat_policy": ("attn.out", "attn.lse",
+        "remat": True, "remat_policy": ("attn.out", "attn.lse", "moe.table",
                                         "dsa.threshold", "dsa.tie_cut")}
         for layer in net.layers)
 
