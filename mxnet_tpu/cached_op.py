@@ -106,8 +106,9 @@ class CachedOp:
         return "|".join(parts)
 
     def _note_dispatch(self, training, input_vals):
-        """Count the dispatch; True where it is the first of its signature
-        (jax.jit traces, lowers and compiles or loads inside the call)."""
+        """Count the dispatch; its signature where it is the first of that
+        signature (the program is then traced, lowered and compiled or
+        loaded), else None."""
         sig = self._signature(training, input_vals)
         with self._stats_lock:
             rec = self._sig_stats.get(sig)
@@ -115,7 +116,22 @@ class CachedOp:
                 self._sig_stats[sig] = [0, 1]
             else:
                 rec[0] += 1
-        return rec is None
+        return sig if rec is None else None
+
+    def _first_call(self, jitted, vals, signature):
+        """A signature's first call in its parts, ahead of the dispatch:
+        ``cachedop.lower`` (trace and lower: Python and JAX) and
+        ``cachedop.compile`` (the backend's compile, or the persistent
+        cache's load: the recorder charges it there, and its
+        ``compile.cache_hits`` says which).  The call that follows finds both
+        in jax's caches, so nothing is traced or compiled twice.  The
+        executable goes to the recorder, which can name its instructions'
+        scopes when asked (profiler.program_ops)."""
+        with profiler.span("cachedop.lower", op=self._name):
+            lowered = jitted.lower(*vals)
+        with profiler.span("cachedop.compile", op=self._name):
+            compiled = lowered.compile()
+        profiler.program(self._name, signature, compiled)
 
     def cache_stats(self):
         """Per-signature compile-cache counters (debugging / serving aid).
@@ -263,9 +279,11 @@ class CachedOp:
         n_aux = len(self._aux_names)
         first = self._note_dispatch(training, input_vals)
         # the dispatch, not the work: jitted() returns once the program is
-        # enqueued (and, on a first call, traced, lowered and compiled)
-        with profiler.span("cachedop.first_call" if first
-                           else "cachedop.call", op=self._name):
+        # enqueued (on a first call: traced, lowered, compiled and enqueued)
+        with profiler.span("cachedop.call" if first is None
+                           else "cachedop.first_call", op=self._name):
+            if first is not None:
+                self._first_call(jitted, vals, first)
             if profiler.profiling_imperative():
                 # in a session also under the name of the reference's
                 # _CachedOp engine op (cached_op.cc registers the whole

@@ -57,14 +57,17 @@ class BlockDiffusionMoEDecoder(HybridBlock):
                               flatten=False, prefix="head_")
 
     def hybrid_forward(self, F, tokens):
+        import jax
         seq_len = tokens.shape[1] // 2
         position = F._arange(start=0, stop=seq_len, dtype="int32")
         positions = F.concat(position, position, dim=0)
-        x = self.embed(tokens)
+        with jax.named_scope("lm.embed"):
+            x = self.embed(tokens)
         for layer in self.layers:
             x = layer(x, positions)
-        noised = F.slice_axis(x, axis=1, begin=0, end=seq_len)
-        return self.head(self.final_norm(noised))
+        with jax.named_scope("lm.head"):     # the final norm with it
+            noised = F.slice_axis(x, axis=1, begin=0, end=seq_len)
+            return self.head(self.final_norm(noised))
 
 
 def build(config):

@@ -103,11 +103,14 @@ class ShortConvLM(HybridBlock):
                               flatten=False, params=self.embed.params)
 
     def hybrid_forward(self, F, tokens):
+        import jax
         positions = F._arange(start=0, stop=tokens.shape[1], dtype="int32")
-        x = self.embed(tokens)
+        with jax.named_scope("lm.embed"):
+            x = self.embed(tokens)
         for layer in self.layers:
             x = layer(x, positions)
-        return self.head(self.final_norm(x))
+        with jax.named_scope("lm.head"):     # the final norm with it
+            return self.head(self.final_norm(x))
 
 
 def build(config):

@@ -67,16 +67,20 @@ class SparseCausalLM(HybridBlock):
                               flatten=False, prefix="head_")
 
     def hybrid_forward(self, F, tokens):
+        import jax
         # text: the three position rows (temporal, height, width) are equal
         row = F.reshape(F._arange(start=0, stop=tokens.shape[1],
                                   dtype="int32"), shape=(1, -1))
         positions = F.concat(row, row, row, dim=0)
-        x = self.embed(tokens)
+        with jax.named_scope("lm.embed"):
+            x = self.embed(tokens)
         index_loss = None
         for layer in self.layers:
             x, loss_i = layer(x, positions)
             index_loss = loss_i if index_loss is None else index_loss + loss_i
-        return self.head(self.final_norm(x)), index_loss * self._loss_weight
+        with jax.named_scope("lm.head"):     # the final norm with it
+            logits = self.head(self.final_norm(x))
+        return logits, index_loss * self._loss_weight
 
 
 def build(config):

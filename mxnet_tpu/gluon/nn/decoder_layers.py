@@ -65,17 +65,24 @@ class _GroupedQueryAttention(HybridBlock):
         return F.transpose(t, axes=(0, 2, 1, 3))
 
     def _qkv(self, F, x, rotary):
-        """The three operands of the attention call, (B, H, T, D)."""
-        q = rotary(self._split(F, self.q(x), self._heads, self._head_dim,
-                               self.q_norm))
-        k = rotary(self._split(F, self.k(x), self._kv_heads, self._head_dim,
-                               self.k_norm))
-        return q, k, self._split(F, self.v(x), self._kv_heads, self._head_dim)
+        """The three operands of the attention call, (B, H, T, D).  With
+        ``_output`` under the scope ``attn.proj``: what an attention block
+        costs around its kernels."""
+        import jax
+        with jax.named_scope("attn.proj"):
+            q = rotary(self._split(F, self.q(x), self._heads, self._head_dim,
+                                   self.q_norm))
+            k = rotary(self._split(F, self.k(x), self._kv_heads,
+                                   self._head_dim, self.k_norm))
+            return q, k, self._split(F, self.v(x), self._kv_heads,
+                                     self._head_dim)
 
     def _output(self, F, out):
         """The attention's (B, H, T, D) through the output projection."""
-        return self.o(F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
-                                shape=(0, 0, -1)))
+        import jax
+        with jax.named_scope("attn.proj"):
+            return self.o(F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                                    shape=(0, 0, -1)))
 
 
 class BlockDiffusionAttention(_GroupedQueryAttention):
@@ -251,19 +258,21 @@ class IndexedSparseAttention(_GroupedQueryAttention):
         q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
             t, positions, base=self._rope_base, sections=self._sections))
 
-        u = F.stop_gradient(x)
-        first_row = F.reshape(F.slice_axis(positions, axis=0, begin=0, end=1),
-                              shape=(-1,))
-        index_q = F._contrib_rotary_embedding(
-            self._split(F, self.index_q(u), self._index_heads,
-                        self._index_dim),
-            first_row, base=self._rope_base)
-        index_k = F._contrib_rotary_embedding(
-            F.LayerNorm(self.index_k(u), index_k_norm_gamma,
-                        index_k_norm_beta, eps=1e-6),
-            first_row, base=self._rope_base)
-        weights = self.index_w(u) * (self._index_heads ** -0.5
-                                     * self._index_dim ** -0.5)
+        import jax
+        with jax.named_scope("dsa.proj"):       # the indexer's operands
+            u = F.stop_gradient(x)
+            first_row = F.reshape(
+                F.slice_axis(positions, axis=0, begin=0, end=1), shape=(-1,))
+            index_q = F._contrib_rotary_embedding(
+                self._split(F, self.index_q(u), self._index_heads,
+                            self._index_dim),
+                first_row, base=self._rope_base)
+            index_k = F._contrib_rotary_embedding(
+                F.LayerNorm(self.index_k(u), index_k_norm_gamma,
+                            index_k_norm_beta, eps=1e-6),
+                first_row, base=self._rope_base)
+            weights = self.index_w(u) * (self._index_heads ** -0.5
+                                         * self._index_dim ** -0.5)
         scores, pairs = F._contrib_index_select(index_q, index_k, weights,
                                                 topk=self._topk)
         out, lse = F._contrib_sparse_attention(q, k, v, pairs)
@@ -338,8 +347,12 @@ class GatedShortConv(HybridBlock):
                                                shape=(hidden, taps))
 
     def hybrid_forward(self, F, x, positions=None, taps_weight=None):
-        return self.out_proj(F._contrib_gated_short_conv(self.in_proj(x),
-                                                         taps_weight))
+        import jax
+        with jax.named_scope("conv.proj"):      # the kernels: conv.gated
+            streams = self.in_proj(x)
+        mixed = F._contrib_gated_short_conv(streams, taps_weight)
+        with jax.named_scope("conv.proj"):
+            return self.out_proj(mixed)
 
 
 class GatedMLP(HybridBlock):

@@ -818,8 +818,9 @@ class CompiledTrainStep:
                 outs, new_aux = functional_call(block, full, *x_vals,
                                                 training=True,
                                                 rng_key=keys_t[0])
-                loss = loss_fn([NDArray(o) for o in outs],
-                               *[NDArray(v) for v in label_vals])
+                with jax.named_scope("loss"):
+                    loss = loss_fn([NDArray(o) for o in outs],
+                                   *[NDArray(v) for v in label_vals])
                 # mxnet reductions keep a (1,) shape; grad needs a scalar
                 return loss._data.reshape(()), (new_aux, outs)
 

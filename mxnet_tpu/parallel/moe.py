@@ -401,9 +401,7 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
     T, d = x.shape
     E, held = router_w.shape[0], gate_w.shape[0]
     tm = slot_tile_rows(T * min(k, held), held)
-    profiler.count("moe.layers")
     profiler.count("moe.experts_held", held)
-    profiler.count("moe.experts_total", E)
     profiler.count("moe.rows", T)
 
     with jax.named_scope("moe.route"):

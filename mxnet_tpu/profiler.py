@@ -31,6 +31,13 @@ Under it sits the recorder that the training hot path uses all the time
 * A ``jax.monitoring`` listener charges every backend compile and every
   persistent-cache hit or miss to the innermost span open on the thread it
   happens on: ``totals()[name]["compile.count"]`` says which step compiled.
+* ``program(name, signature, compiled)`` keeps the executable of a
+  signature's first call (``CachedOp`` hands it over), and
+  ``program_ops(name)`` reads from its optimized HLO, when asked, which
+  ``fwd`` / ``bwd`` / ``opt`` / ``metric`` phase and which scope each of the
+  program's instructions belongs to: a device trace names an operation by
+  its instruction, so this table is what turns a trace's operations into the
+  program's own parts (see "the programs" below).
 
 How to read them: ``totals()`` in a live process (it survives the feed that
 ``fit()`` drops at each epoch's end), ``spans()`` for the newest records, the
@@ -45,6 +52,7 @@ from __future__ import annotations
 
 import collections
 import os
+import re
 import time
 import json
 import threading
@@ -55,7 +63,8 @@ __all__ = ["set_config", "set_state", "state", "dump", "dumps", "merge_dumps",
            "pause", "resume", "memory_summary",
            "Domain", "Task", "Frame", "Event", "Counter", "Marker",
            "span", "count", "totals", "spans", "reset_spans", "Span",
-           "RING_SIZE"]
+           "RING_SIZE", "gauge", "program", "programs", "program_ops",
+           "scope_of", "PHASES", "PROGRAMS_KEPT"]
 
 _config = {"profile_all": False, "profile_symbolic": True, "profile_imperative": True,
            "profile_memory": False, "profile_api": False,
@@ -273,15 +282,174 @@ def spans(since_ns=None):
 
 
 def reset_spans():
-    """Forget every span, total, gauge and compile charge (for tests)."""
+    """Forget every span, total, gauge, compile charge and kept program (for
+    tests)."""
     with _lock:
         _ring.clear()
         _gauges.clear()
+        _programs.clear()
         for table in _retired:
             table.clear()
         for _, thread_totals, thread_compiles in _tables:
             thread_totals.clear()
             thread_compiles.clear()
+
+
+# ---------------------------------------------------------------------------
+# the programs: whose device time an instruction is
+# ---------------------------------------------------------------------------
+# The names are the program's own, so the rule that reads them lives here.
+# A compiled step is traced under ``jax.named_scope``s, which XLA keeps as the
+# ``op_name`` of every instruction of the optimized program (a fusion carries
+# its root's): ``jit(train_step)/bwd/transpose(jvp(fwd))/jvp()/checkpoint/
+# rematted_computation/moe.experts/dot_general``.  On that path
+#
+# * the phase is the first component that is one of ``PHASES``: the step opens
+#   ``fwd``, ``bwd``, ``opt`` and ``metric`` (module/compiled_step.py), and a
+#   backward operation also names, wrapped, the forward one it transposes;
+# * the scope is the innermost component, ``jvp(...)`` and ``transpose(...)``
+#   unwrapped, that is a scope's name: lower-case words joined by dots,
+#   ``<layer>.<part>`` (``moe.experts``, ``attn.block_mask``, ``dsa.index``,
+#   ``conv.gated``, ``mlp.dense``, ``attn.proj``, ``lm.head``), or one of the
+#   step's own ``SCOPE_WORDS`` (``loss``).  A symbol's node names and a
+#   block's prefixes carry no dot and are no scope;
+# * ``rematted_computation`` says the operation recomputes, in the backward
+#   pass, a forward value that ``hybridize(remat=True)`` did not keep;
+# * an instruction that the compiler made and that has no ``op_name`` (a copy
+#   between memory spaces with its start and its done, 6% of a ResNet-50
+#   step's device time) belongs to no scope and is counted with the phase
+#   in which the schedule runs it: the text lists a computation's
+#   instructions in the order they run, and the phase is that of the next
+#   one that has a name, which is the one that waits for the copy.
+
+PHASES = ("fwd", "bwd", "opt", "metric")
+SCOPE_WORDS = ("loss",)
+PROGRAMS_KEPT = 8
+_REMAT = "rematted_computation"
+_WRAPPED = re.compile(r"(?:jvp|transpose)\((.*)\)\Z")
+_SCOPE = re.compile(r"[a-z][a-z0-9_]*(?:\.[a-z][a-z0-9_]*)+\Z")
+_HLO_COMPUTATION = re.compile(r"(?:ENTRY )?%?([\w.\-]+) (?:\(.*)?\{\Z")
+_HLO_INSTRUCTION = re.compile(
+    r"\s+(?:ROOT )?%?([\w.\-]+) = (?:.*? )??([a-z][a-z\-]*)\(")
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# guarded by _lock: (name, signature) -> [compiled, table or None], oldest
+# first
+_programs = collections.OrderedDict()
+
+
+def scope_of(op_name):
+    """``(phase, scope, recomputed)`` of an instruction's ``op_name`` path,
+    by the rule above; ``(None, None, False)`` for a path that names none."""
+    phase = scope = None
+    parts = op_name.split("/")
+    for part in parts:
+        if phase is None and part in PHASES:
+            phase = part
+        inner = _WRAPPED.match(part)
+        while inner:
+            part = inner.group(1)
+            inner = _WRAPPED.match(part)
+        if part in SCOPE_WORDS or _SCOPE.match(part):
+            scope = part
+    return phase, scope, _REMAT in parts
+
+
+def program(name, signature, compiled):
+    """Keep ``compiled``, the ``jax.stages.Compiled`` of the first call of
+    ``signature`` of the program ``name``: one dictionary insert, nothing is
+    read from it until ``program_ops`` asks.  It holds the executable and the
+    arguments' shapes, not the function, the block or a buffer, so it
+    outlives the step that made it; the newest ``PROGRAMS_KEPT`` of the
+    process are kept."""
+    with _lock:
+        _programs.pop((name, signature), None)
+        _programs[(name, signature)] = [compiled, None]
+        while len(_programs) > PROGRAMS_KEPT:
+            _programs.popitem(last=False)
+
+
+def programs():
+    """``{(name, signature): compiled}`` of the kept programs, oldest first."""
+    with _lock:
+        return {key: entry[0] for key, entry in _programs.items()}
+
+
+def _hlo_text(compiled):
+    """The optimized program's text without what a reader of names does not
+    need: a custom call's backend configuration (a Pallas kernel's serialized
+    body, most of a 100 MB executable's text), large constants, shapes."""
+    from jax._src.lib import xla_client
+    options = xla_client._xla.HloPrintOptions.short_parsable()
+    options.print_metadata = True
+    options.print_backend_config = False
+    options.print_large_constants = False
+    options.print_operand_shape = False
+    options.print_result_shape = False
+    module, = compiled.runtime_executable().hlo_modules()
+    return module.to_string(options)
+
+
+def _parse_ops(text):
+    """``{instruction: (phase, scope, recomputed, opcode)}`` of HLO text, for
+    every instruction of every computation that no ``fusion`` calls: the
+    device lists the operations of the entry, of a loop's body and condition
+    and of a branch under their own names, and a fusion as one.  An
+    instruction without ``op_name`` takes its phase from the schedule (see
+    the rule above)."""
+    computations, fused, current = {}, set(), None
+    for line in text.split("\n"):
+        if current is None:
+            opened = _HLO_COMPUTATION.match(line)
+            if opened:
+                current = computations.setdefault(opened.group(1), {})
+                made = set()         # by the compiler: no op_name
+            continue
+        if line.startswith("}"):
+            phase = None             # of the named instruction that runs next
+            for name in reversed(current):
+                if name in made:
+                    current[name] = (phase,) + current[name][1:]
+                else:
+                    phase = current[name][0]
+            current = None
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if not found:
+            continue
+        name, opcode = found.groups()
+        if opcode == "fusion":
+            called = _HLO_CALLS.search(line)
+            if called:
+                fused.add(called.group(1))
+        op_name = _HLO_OP_NAME.search(line)
+        if op_name is None:
+            made.add(name)
+        current[name] = scope_of(op_name.group(1) if op_name else "") \
+            + (opcode,)
+    table = {}
+    for computation, rows in computations.items():
+        if computation not in fused:
+            table.update(rows)
+    return table
+
+
+def program_ops(name):
+    """``{instruction name: (phase, scope, recomputed, opcode)}`` of the
+    newest kept program called ``name``, or None where none is kept or its
+    executable gives no text.  Built from the executable's optimized HLO at
+    the first asking and kept; nothing is parsed before."""
+    with _lock:
+        entry = next((e for (n, _), e in reversed(_programs.items())
+                      if n == name), None)
+    if entry is None:
+        return None
+    if entry[1] is None:
+        try:
+            entry[1] = _parse_ops(_hlo_text(entry[0]))
+        except Exception:       # an executable that cannot print itself
+            return None
+    return entry[1]
 
 
 def _event(name, cat, ph, ts_us, args):

@@ -581,9 +581,10 @@ def test_compiled_step_trains_recomputes_and_records(model, monkeypatch):
     # recomputed
     assert wrapped == ["pure", "pure"]
     totals = profiler.totals()
-    assert totals["moe.layers"]["count"] == 2
+    # two routed layers were traced, each over the same rows and experts
+    assert totals["moe.rows"]["count"] == 2 * totals["moe.rows"]["max"]
     assert totals["moe.experts_held"]["max"] == 4
-    assert totals["moe.experts_total"]["max"] == 8
+    assert totals["moe.experts_held"]["count"] == 2 * 4
     loads = [v for k, v in totals.items() if k.startswith("moe.load.")
              and k.startswith("moe.load." + net.prefix)]
     assert len(loads) == 2

@@ -158,6 +158,9 @@ def test_quantize_model_zoo_resnet_agreement(tmp_path):
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo import vision
     rng = np.random.RandomState(0)
+    # the weights from a fixed seed, as the inputs are: untrained logits of
+    # 16 inputs lie close, and at MXNET_TEST_SEED=1887739772 they agreed 0.81
+    mx.random.seed(0)
     net = vision.get_model("resnet18_v1", classes=10)
     net.initialize(mx.init.Xavier())
     net(mx.nd.zeros((1, 3, 32, 32)))

@@ -561,7 +561,8 @@ def test_compiled_step_trains_recomputes_and_records(monkeypatch):
     assert len(layers) == 2 and all(p is not None for p in layers)
     assert [name for name, _ in wrapped if name != "pure"] == ["one", "one"]
     totals = profiler.totals()
-    assert totals["moe.layers"]["count"] == 2
+    # two routed layers were traced, each over the same rows
+    assert totals["moe.rows"]["count"] == 2 * totals["moe.rows"]["max"]
     assert totals["dsa.pairs_selected"]["count"] * 528 \
         == totals["dsa.pairs_causal"]["count"] * 228
     found = [v for k, v in totals.items()
