@@ -357,23 +357,25 @@ class GatedShortConv(HybridBlock):
 
 class GatedMLP(HybridBlock):
     """A dense gated feed-forward, ``down(silu(gate u) * up u)`` of width
-    ``width``, no bias."""
+    ``width``, no bias: the operator ``_contrib_gated_mlp``, whose backward
+    pass feeds its five products from operands written once.  Weights
+    ``gate_weight``, ``up_weight`` (width, hidden) and ``down_weight``
+    (hidden, width): the reference's names and a ``Dense``'s layout."""
 
     def __init__(self, hidden, width, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
-            self.gate, self.up, self.down = (
-                Dense(units, in_units=in_units, use_bias=False, flatten=False,
-                      prefix=prefix)
-                for units, in_units, prefix in ((width, hidden, "gate_"),
-                                                (width, hidden, "up_"),
-                                                (hidden, width, "down_")))
+            self.gate_weight, self.up_weight, self.down_weight = (
+                self.params.get(name, shape=shape)
+                for name, shape in (("gate_weight", (width, hidden)),
+                                    ("up_weight", (width, hidden)),
+                                    ("down_weight", (hidden, width))))
 
-    def hybrid_forward(self, F, x):
+    def hybrid_forward(self, F, x, gate_weight, up_weight, down_weight):
         import jax
         with jax.named_scope("mlp.dense"):
-            gate = self.gate(x)
-            return self.down(gate * F.sigmoid(gate) * self.up(x))
+            return F._contrib_gated_mlp(x, gate_weight, up_weight,
+                                        down_weight)
 
 
 class DecoderLayer(HybridBlock):

@@ -1,6 +1,7 @@
 """Operators of a decoder language model's layers that ``nn_ops`` lacks:
 weighted RMSNorm, rotary position embedding with the positions as an input
-(one row, or three with the frequencies in sections), the operator of the
+(one row, or three with the frequencies in sections), a dense gated
+feed-forward whose backward pass is written out, the operator of the
 mixture-of-experts layer for the experts held on this chip (the layer itself
 is parallel/moe.py's, imported when the operator runs: ``mx.nd`` installs its
 operators before ``parallel`` is imported, so an operator registered there
@@ -65,6 +66,70 @@ def _rotary_embedding(attrs, x, positions):
     x1, x2 = x32[..., :half], x32[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+@register("_contrib_gated_mlp", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _gated_mlp(attrs, x, gate_w, up_w, down_w):
+    """A dense gated feed-forward without bias, ``down(silu(gate x) * up
+    x)``: ``x`` (..., d); ``gate_w``, ``up_w``: (f, d); ``down_w``: (d, f)
+    (``gated_mlp``)."""
+    return gated_mlp(x, gate_w, up_w, down_w)
+
+
+def gated_mlp(x, gate_w, up_w, down_w):
+    """The forward pass is the three ``FullyConnected`` products.  The
+    backward pass is written out: it recomputes ``g = gate x`` and ``u = up
+    x`` from ``x`` (the residuals are ``x`` and the weights, so a layer's
+    recomputed forward pass has no consumer here), computes ``dh = dy
+    down_w``, then ``dg``, ``du`` and ``h = silu(g) u`` in one float32 pass,
+    and writes them once, behind a barrier, in the dtype the matrix units
+    take; the input's gradient and the three weights' are products of those
+    over the flattened rows, accumulated in float32.  Without the barrier
+    XLA fuses the SiLU's backward into every contraction that reads it and
+    rebuilds the operand from float32 ``g``, ``u`` and ``dh`` for each
+    output tile.  On a TPU that dtype is bfloat16, which is what XLA's
+    default precision gives float32 operands of a product there, so the
+    products see the values they saw; elsewhere the operands keep their own
+    dtype, and this is the oracle of the TPU's form."""
+    import jax
+    import jax.numpy as jnp
+    cdt = jnp.bfloat16 if jax.default_backend() == "tpu" else None
+
+    def cast(a):
+        return a.astype(cdt) if cdt is not None else a
+
+    def forward(x, gate_w, up_w, down_w):
+        g = jnp.matmul(x, gate_w.T)
+        return jnp.matmul(g * jax.nn.sigmoid(g) * jnp.matmul(x, up_w.T),
+                          down_w.T)
+
+    def product(a, b, contract):
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def backward(kept, dy):
+        x, gate_w, up_w, down_w = kept
+        with jax.named_scope("mlp.dense"):
+            d = x.shape[-1]
+            rows, dy_r = cast(x.reshape(-1, d)), cast(dy.reshape(-1, d))
+            wg, wu, wd = cast(gate_w), cast(up_w), cast(down_w)
+            g = product(rows, wg, ((1,), (1,)))
+            u = product(rows, wu, ((1,), (1,)))
+            dh = product(dy_r, wd, ((1,), (0,)))
+            s = jax.nn.sigmoid(g)
+            silu = g * s
+            dg, du, h = jax.lax.optimization_barrier(tuple(cast(a) for a in (
+                dh * u * s * (1 + g * (1 - s)), dh * silu, silu * u)))
+            dx = product(dg, wg, ((1,), (0,))) + product(du, wu, ((1,), (0,)))
+            return (dx.reshape(x.shape).astype(x.dtype),
+                    product(dg, rows, ((0,), (0,))).astype(gate_w.dtype),
+                    product(du, rows, ((0,), (0,))).astype(up_w.dtype),
+                    product(dy_r, h, ((0,), (0,))).astype(down_w.dtype))
+
+    layer = jax.custom_vjp(forward)
+    layer.defvjp(lambda *args: (forward(*args), args), backward)
+    return layer(x, gate_w, up_w, down_w)
 
 
 @register("_contrib_moe_held_experts", num_outputs=2, no_jit=True,

@@ -392,6 +392,133 @@ def test_causal_kernels_match_reference_at_head_size_64(rows, tiles, batch):
         pallas_ops.causal_attention(q, k[:, :, :64], v[:, :, :64])
 
 
+# -- the dense feed-forward's operator ------------------------------------------
+
+def _plain_mlp(x, gate_w, up_w, down_w, product=lambda a, w: a @ w.T):
+    """The three products as three ``Dense`` make them, differentiated by
+    JAX."""
+    g = product(x, gate_w)
+    return product(jax.nn.silu(g) * product(x, up_w), down_w)
+
+
+def _bf16(a):
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _mxu_product(a, w):
+    """``a @ w.T`` whose operands, in both passes, are rounded as the TPU's
+    matrix units take float32 at the default precision; float32 sums."""
+    return jnp.matmul(_bf16(a), _bf16(w).T, precision="highest")
+
+
+def _mxu_product_bwd(kept, g):
+    a, w = kept
+    rows = lambda t: _bf16(t).reshape(-1, t.shape[-1])
+    return (jnp.matmul(_bf16(g), _bf16(w), precision="highest"),
+            jnp.matmul(rows(g).T, rows(a), precision="highest"))
+
+
+_mxu_product.defvjp(lambda a, w: (_mxu_product(a, w), (a, w)),
+                    _mxu_product_bwd)
+
+
+def _mlp_grads(layer, x, weights, dy):
+    return jax.value_and_grad(lambda x, w: jnp.sum(layer(x, *w) * dy),
+                              (0, 1))(x, weights)
+
+
+@pytest.fixture(scope="module")
+def published_mlp():
+    """256 rows at the fifth cell's widths (2,048 to 11,776), a seeded
+    cotangent; the plain form's value and gradients in float32."""
+    keys = jax.random.split(jax.random.PRNGKey(38), 5)
+    d, f = 2048, 11776
+    x = jax.random.normal(keys[0], (2, 128, d))
+    weights = tuple(jax.random.normal(k, s) / s[1] ** 0.5 for k, s in zip(
+        keys[1:4], ((f, d), (f, d), (d, f))))
+    dy = jax.random.normal(keys[4], (2, 128, d))
+    with jax.default_matmul_precision("highest"):
+        return x, weights, dy, _mlp_grads(_plain_mlp, x, weights, dy)
+
+
+def _gaps(got, want):
+    """Each leaf's error over its norm."""
+    return [float(jnp.linalg.norm((a - b).ravel()) / jnp.linalg.norm(
+        b.ravel())) for a, b in zip(jax.tree.leaves(got),
+                                    jax.tree.leaves(want))]
+
+
+def test_gated_mlp_is_the_plain_form_in_float32(published_mlp):
+    x, weights, dy, want = published_mlp
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda *a: _mlp_grads(lambda *b: get_op(
+            "_contrib_gated_mlp").fcompute({}, *b), *a))(x, weights, dy)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), got) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), want)
+    assert max(_gaps(got, want)) < 1e-6
+
+
+def test_gated_mlp_feeds_the_tpu_s_operands_to_its_products(published_mlp,
+                                                            monkeypatch):
+    """The backward pass in bfloat16 operands as on the TPU: the gradients
+    of the plain form whose every product's operands are rounded as the
+    TPU's default precision rounds them, to the tolerance of that rounding
+    itself (a float32 within an ulp of a bfloat16 boundary rounds either
+    way: the same form in float32 and float64 arithmetic reads 2.6e-5 to
+    3.4e-5 apart, the operator 3.0e-5 to 4.8e-5 from the float32 one); the
+    forward pass is the plain form's."""
+    from mxnet_tpu.ops.decoder_ops import gated_mlp
+    x, weights, dy, plain = published_mlp
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = jax.jit(lambda *a: _mlp_grads(gated_mlp, *a))(x, weights, dy)
+    want = jax.jit(lambda *a: _mlp_grads(lambda *b: _plain_mlp(
+        *b, product=_mxu_product), *a))(x, weights, dy)
+    assert max(_gaps(got[0], plain[0])) < 1e-6
+    gaps = _gaps(got[1], want[1])
+    assert max(gaps) < 1e-4, gaps
+    # and what the rounding is: far above that, from the float32 form's
+    assert min(_gaps(got[1], plain[1])) > 1e-3
+
+
+def test_a_recomputed_layer_s_gradients_are_those_of_the_plain_form(
+        monkeypatch):
+    """A ``DecoderLayer`` with a dense feed-forward under
+    ``hybridize(remat=True)``: the input's and every parameter's gradient,
+    the operator against the three products differentiated by JAX."""
+    from mxnet_tpu.ops import decoder_ops
+    layer = decoder_layers.DecoderLayer(
+        64, lambda: decoder_layers.GatedShortConv(64, 3, prefix="conv_"),
+        lambda: decoder_layers.GatedMLP(64, 96, prefix="mlp_"),
+        prefix="layer0_")
+    layer.initialize(mx.init.Xavier(), ctx=mx.current_context())
+    layer.hybridize(remat=True)
+    values = {k: p.data()._data for k, p in layer.collect_params().items()}
+    assert sorted(k for k in values if "_mlp_" in k) == [
+        "layer0_mlp_down_weight", "layer0_mlp_gate_weight",
+        "layer0_mlp_up_weight"]
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.normal(0, 1, (BATCH, L, 64)), jnp.float32)
+    dy = jnp.asarray(rng.normal(0, 1, (BATCH, L, 64)), jnp.float32)
+    positions = jnp.arange(L, dtype=jnp.int32)
+    wrapped = []
+    checkpoint = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **kw: (
+        wrapped.append(f.__name__), checkpoint(f, **kw))[1])
+
+    def grads():
+        return jax.grad(lambda v, x: jnp.sum(functional_call(
+            layer, v, x, positions, training=True)[0][0] * dy), (0, 1))(
+                values, x)
+
+    with jax.default_matmul_precision("highest"):
+        got = grads()
+        monkeypatch.setattr(decoder_ops, "gated_mlp", _plain_mlp)
+        want = grads()
+    assert wrapped and set(wrapped) == {"pure"}      # recomputed
+    assert max(_gaps(got, want)) < 1e-6
+
+
 # -- the model against the plain reference ---------------------------------------
 
 def test_parameters_carry_the_reference_names(model):
