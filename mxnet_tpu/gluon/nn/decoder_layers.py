@@ -1,12 +1,14 @@
 """Layers of decoder language models: weighted RMSNorm, grouped-query
-attention with query/key norm and rotary positions (causal, under the
-block-diffusion mask, or causal over the keys a learned indexer picks for each
-query), a gated short convolution along the sequence, a dense gated
+attention with query/key norm and rotary positions (causal, causal over a
+sliding window of keys, under the block-diffusion mask, or causal over the
+keys a learned indexer picks for each query; the rotary's default frequencies
+or YaRN's), a gated short convolution along the sequence, a dense gated
 feed-forward, the mixture-of-experts layer for the experts this chip holds
 (softmax or sigmoid router), and the decoder layers that join them: attention
 over experts (two forms), and a layer whose operator and feed-forward are
-chosen per layer.  gluon/model_zoo/block_diffusion.py, sparse_causal_lm.py and
-short_conv_lm.py build models of them from a configuration.
+chosen per layer.  gluon/model_zoo/block_diffusion.py, sparse_causal_lm.py,
+short_conv_lm.py and window_moe_lm.py build models of them from a
+configuration.
 """
 from __future__ import annotations
 
@@ -38,7 +40,9 @@ class RMSNorm(HybridBlock):
 class _GroupedQueryAttention(HybridBlock):
     """What the attention blocks share: q, k, v and o projections without
     bias, ``heads`` query heads to ``kv_heads`` key/value heads of
-    ``head_dim``, and RMSNorm over each head's dimensions of q and of k."""
+    ``head_dim``, RMSNorm over each head's dimensions of q and of k, and a
+    rotary embedding (each block's own form) of the normed q and k under the
+    scope ``attn.proj``; the blocks differ in the mask of their kernels."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim, epsilon, **kwargs):
         super().__init__(**kwargs)
@@ -312,18 +316,27 @@ class CausalAttention(_GroupedQueryAttention):
     """Causal grouped-query attention: ``BlockDiffusionAttention``'s block
     (projections without bias, RMSNorm over each head's dimensions of q and
     k, rotary on all of them, scale ``1 / sqrt(head_dim)``) under the causal
-    mask, by the attention kernels at the default matmul precision.  Inputs:
-    ``x`` (B, L, hidden) and ``positions`` (L,)."""
+    mask, or with ``window`` under the sliding window of the ``window`` keys
+    up to each query, itself included (``ops.pallas_ops.window_attention``),
+    by the attention kernels at the default matmul precision.  ``rope``: the
+    rotary embedding's attrs beside its base (``rope_type`` "yarn" and
+    YaRN's parameters, as ``_contrib_rotary_embedding`` takes them; none for
+    the default form).  Inputs: ``x`` (B, L, hidden) and ``positions``
+    (L,)."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim, rope_base=1e6,
-                 epsilon=1e-6, **kwargs):
+                 epsilon=1e-6, window=None, rope=None, **kwargs):
         super().__init__(hidden, heads, kv_heads, head_dim, epsilon, **kwargs)
-        self._rope_base = rope_base
+        self._rope_base, self._window = rope_base, window
+        self._rope = dict(rope or {})
 
     def hybrid_forward(self, F, x, positions):
         q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
-            t, positions, base=self._rope_base))
-        return self._output(F, F._contrib_causal_attention(q, k, v))
+            t, positions, base=self._rope_base, **self._rope))
+        if self._window is None:
+            return self._output(F, F._contrib_causal_attention(q, k, v))
+        return self._output(F, F._contrib_window_attention(
+            q, k, v, window=self._window))
 
 
 class GatedShortConv(HybridBlock):

@@ -1,16 +1,19 @@
 """Operators of a decoder language model's layers that ``nn_ops`` lacks:
 weighted RMSNorm, rotary position embedding with the positions as an input
-(one row, or three with the frequencies in sections), a dense gated
+(one row, or three with the frequencies in sections; the default frequencies
+or YaRN's), a dense gated
 feed-forward whose backward pass is written out, the operator of the
 mixture-of-experts layer for the experts held on this chip (the layer itself
 is parallel/moe.py's, imported when the operator runs: ``mx.nd`` installs its
 operators before ``parallel`` is imported, so an operator registered there
 would not be found), a gated short convolution along the sequence, and a
 learned indexer's two: the selection of each query's keys and the loss that
-trains it.  The attention kernels (causal, block mask, picked pairs) are in
-pallas_ops.py.
+trains it.  The attention kernels (causal, sliding window, block mask,
+picked pairs) are in pallas_ops.py.
 """
 from __future__ import annotations
+
+import numpy as _np
 
 from .. import profiler
 from .registry import register
@@ -35,19 +38,58 @@ def _rms_norm(attrs, x, gamma):
     return (x32 * inv).astype(x.dtype) * gamma.astype(x.dtype)
 
 
+def yarn_frequency_scale(dim, base, factor, original_max_positions,
+                         beta_fast, beta_slow):
+    """YaRN's factor on each of the ``dim / 2`` rotary frequencies (Peng et
+    al., arXiv:2309.00071, as the released ``rope_type: yarn`` computes it):
+    1 for the pairs below ``low``, ``1 / factor`` above ``high``, a linear
+    ramp between, where ``low`` = floor(c(beta_fast)) and ``high`` =
+    ceil(c(beta_slow)) with ``c(r) = dim ln(original_max_positions / (2 pi
+    r)) / (2 ln base)``, the pair that turns ``r`` times over the original
+    context.  A host array (float64)."""
+    import math
+
+    def pair(rotations):
+        return dim * math.log(original_max_positions
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), dim - 1)
+    ramp = _np.clip((_np.arange(dim // 2) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return (1.0 - ramp) + ramp / factor
+
+
 @register("_contrib_rotary_embedding")
 def _rotary_embedding(attrs, x, positions):
     """Rotary position embedding over all of the last axis (rotate-half
-    pairing: dimension i with i + D/2).  ``x``: (..., T, D); ``positions``:
+    pairing: dimension i with i + D/2), frequency ``i`` ``base^(-i / (D/2))``.
+    ``x``: (..., T, D); ``positions``:
     (T,) integers, given by the caller, so that two rows may share one; or
     (3, T) with attr ``sections`` (three counts that sum to D/2): frequency
     ``i`` takes its angle from position row 0 if ``i < sections[0]``, row 1
     for the next ``sections[1]``, row 2 for the rest (temporal, height and
-    width of a multimodal sequence; for text the three rows are equal)."""
+    width of a multimodal sequence; for text the three rows are equal).
+
+    attr ``rope_type`` "default" (the above) or "yarn", which stretches a
+    model to positions past its original context: the frequencies times
+    ``yarn_frequency_scale`` of attrs ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast`` and ``beta_slow``,
+    and ``cos`` and ``sin`` times ``attention_factor`` (so that every score
+    between two rotated heads is scaled by its square)."""
     import jax.numpy as jnp
     base = float(attrs.get("base", 10000.0))
     half = x.shape[-1] // 2
     inv_freq = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    kind = attrs.get("rope_type", "default")
+    if kind == "yarn":
+        inv_freq = inv_freq * jnp.asarray(yarn_frequency_scale(
+            x.shape[-1], base, float(attrs["factor"]),
+            float(attrs["original_max_position_embeddings"]),
+            float(attrs["beta_fast"]), float(attrs["beta_slow"])),
+            jnp.float32)
+    elif kind != "default":
+        raise ValueError("rope_type is default or yarn, not %r" % (kind,))
     positions = positions.astype(jnp.float32)
     if positions.ndim == 2:
         sections = tuple(int(n) for n in attrs["sections"])
@@ -62,6 +104,9 @@ def _rotary_embedding(attrs, x, positions):
         positions = positions[:, None]
     angle = positions * inv_freq[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)                 # (T, D/2)
+    if kind == "yarn":
+        factor = float(attrs["attention_factor"])
+        cos, sin = cos * factor, sin * factor
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

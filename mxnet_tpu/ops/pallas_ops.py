@@ -8,7 +8,8 @@ on the MXU, forward and backward.
 
 One pair of kernels (the forward; one backward for the query, key and value
 gradients) serves every mask.  A static mask (none, causal 'top' / 'bottom'
-aligned, the block diffusion mask over ``[noised; clean]`` rows) is a
+aligned, a causal window of the last ``W`` keys, the block diffusion mask over
+``[noised; clean]`` rows) is a
 function of a row's and a column's index; from it the wrapper works out on
 the host, per query tile, which key tiles hold a visible pair (the others
 are never visited: no DMA, no MXU pass, no grid step) and which are wholly
@@ -47,7 +48,8 @@ ATTENTION_RESIDUALS = ("attn.out", "attn.lse", SLOT_TABLE)
 # ---------------------------------------------------------------------------
 # static masks
 # ---------------------------------------------------------------------------
-# A mask is a hashable tuple: ("none",), ("causal", offset),
+# A mask is a hashable tuple: ("none",), ("causal", offset), ("window", W)
+# (query i sees keys i - W < j <= i: itself and the W - 1 before it),
 # ("block_diffusion", L, block_length) or DATA_MASK, whose visible pairs are
 # an operand of the call.
 DATA_MASK = ("data",)
@@ -85,6 +87,8 @@ def mask_visible(mask, q_pos, k_pos):
         return (q_pos >= 0) & (k_pos >= 0)
     if kind == "causal":
         return q_pos + mask[1] >= k_pos
+    if kind == "window":
+        return (k_pos <= q_pos) & (k_pos > q_pos - mask[1])
     _, L, bl = mask
     q_clean, k_clean = q_pos >= L, k_pos >= L
     qb = (q_pos - L * q_clean) // bl
@@ -379,7 +383,8 @@ def _attention_bwd_pallas(plan, q, k, v, out, lse, g, pairs_t=None):
     the group's first head and scaled at the last entry of its last, and
     each tile adds its (block_k, D) rows in place.
     VMEM therefore grows with the key length (4 MiB a gradient at 8,192 x
-    128, twice for the pipeline's second buffer); nothing in HBM grows
+    128 and 8 MiB at 16,384, twice for the pipeline's second buffer: 48 MiB
+    of the cap below at 16,384), whatever the mask; nothing in HBM grows
     with tiles x heads.  Under a data mask ``pairs_t`` are the padded pairs,
     key rows by query columns."""
     import jax
@@ -627,6 +632,28 @@ def causal_attention(q, k, v, scale=None, precision="default", interpret=None,
     with jax.named_scope("attn.causal"):
         return _attention(q, k, v, ("causal", 0), scale, precision, interpret,
                           block_q, block_k, scope="attn.causal")
+
+
+def window_attention(q, k, v, window, scale=None, precision="default",
+                     interpret=None, block_q=512, block_k=512):
+    """Sliding-window attention of a decoder block in training: ``q`` (B, H,
+    T, D), ``k``/``v`` (B, Hkv, T, D), query ``t`` sees keys ``t - window <
+    s <= t`` (itself and the ``window - 1`` before it).  The kernels of
+    ``causal_attention`` under the mask ``("window", window)``: only the band
+    of tiles that hold a visible pair is visited (at 512 x 512 tiles and a
+    window of 1,024, three a query tile), those cut by either edge masked
+    from their indices."""
+    import jax
+    if q.shape[2] != k.shape[2]:
+        raise ValueError("window attention over %d queries and %d keys: one "
+                         "square" % (q.shape[2], k.shape[2]))
+    if int(window) < 1:
+        raise ValueError("a window of %r keys" % (window,))
+    if scale is None:
+        scale = 1.0 / _np.sqrt(q.shape[-1])
+    with jax.named_scope("attn.window"):
+        return _attention(q, k, v, ("window", int(window)), scale, precision,
+                          interpret, block_q, block_k, scope="attn.window")
 
 
 def block_mask_attention(q, k, v, seq_len, block_length, scale=None,
@@ -1184,7 +1211,8 @@ class _Experts:
                 out_specs=out_specs),
             out_shape=out_shape,
             # an expert's matrices (or its gradients' blocks) whole, twice
-            # for the pipeline, beside the tiles: 38 MiB at 1536 x 2048
+            # for the pipeline, beside the tiles: 36 MiB at 1536 x 2048,
+            # 24 MiB at 896 x 2304
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * len(grid),
                 vmem_limit_bytes=100 * 2 ** 20),
@@ -1358,6 +1386,16 @@ def _causal_attention_op(attrs, q, k, v):
     """Causal attention at the default matmul precision (``causal_attention``);
     optional attr ``scale``."""
     return causal_attention(q, k, v, scale=attrs.get("scale"))
+
+
+@register("_contrib_window_attention", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _window_attention_op(attrs, q, k, v):
+    """Sliding-window attention at the default matmul precision
+    (``window_attention``): attr ``window`` (keys a query sees, itself
+    included), optional ``scale``."""
+    return window_attention(q, k, v, int(attrs["window"]),
+                            scale=attrs.get("scale"))
 
 
 @register("_contrib_block_mask_attention", no_jit=True, shape_rule="input",

@@ -227,18 +227,20 @@ def test_sparse_attention_compiles_for_v5e_forward_and_backward(one_chip):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("rows,total,held,width,picked,slots", [
-    (8192, 128, 16, 768, 8, 69632),     # the third and fourth cells' layer
-    (16384, 64, 8, 1536, 4, 67584),     # the fifth cell's
+@pytest.mark.parametrize("rows,total,held,width,picked,slots,hidden,blocks", [
+    (8192, 128, 16, 768, 8, 69632, 2048, 1),   # the third and fourth cells'
+    (16384, 64, 8, 1536, 4, 67584, 2048, 2),   # the fifth cell's
+    (16384, 64, 8, 896, 8, 133120, 2304, 7),   # the sixth cell's
 ])
 def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
                                              total, held, width, picked,
-                                             slots):
-    """A chip's held experts of ``width`` x 2048, ``picked`` per token, forward
-    and backward: the pairs in ``rows * picked`` slots and a tile of 256 an
-    expert, four kernels over whole tiles with an expert's matrices in VMEM;
-    a grid step a tile (and, for the weights' gradients, a block of 768
-    hidden units), whatever the router picks."""
+                                             slots, hidden, blocks):
+    """A chip's held experts of ``width`` x ``hidden``, ``picked`` per token,
+    forward and backward: the pairs in ``rows * min(picked, held)`` slots and
+    a tile of 256 an expert, four kernels over whole tiles with an expert's
+    matrices in VMEM; a grid step a tile (and, for the weights' gradients, a
+    block of the largest multiple of 128 up to 768 hidden units that divides
+    the width: 128 of 896), whatever the router picks."""
     from mxnet_tpu.parallel.moe import moe_held_apply
     # the layer asks the backend whether its kernels can run: here the CPU's
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -252,8 +254,8 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
 
     # the value too: a pass that needs no output drops the down projection
     step = jax.value_and_grad(loss, (0, 1, 2, 3, 4))
-    shapes = (s(rows, 2048), s(total, 2048), s(held, width, 2048),
-              s(held, width, 2048), s(held, 2048, width))
+    shapes = (s(rows, hidden), s(total, hidden), s(held, width, hidden),
+              s(held, width, hidden), s(held, hidden, width))
     compiled = jax.jit(step).lower(*shapes).compile()
     assert set(re.findall(r"%(moe_\w+?)(?:\.\d+)? = ",
                           compiled.as_text())) == {
@@ -263,7 +265,7 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
     assert _pallas_grids(jax.make_jaxpr(step)(*shapes).jaxpr) == {
         "moe_experts_hidden": (tiles,), "moe_experts_down": (tiles,),
         "moe_experts_bwd": (tiles,),
-        "moe_experts_wgrad": (width // 768, tiles)}
+        "moe_experts_wgrad": (blocks, tiles)}
     _fits(compiled)
 
 
@@ -304,6 +306,35 @@ def test_short_conv_decoder_s_blocks_compile_for_v5e(one_chip):
         "short_conv_fwd": (2, 32), "short_conv_bwd": (2, 32)}
     _fits(compiled)
 
+
+
+@pytest.mark.parametrize("mask,steps", [("window", 93), ("causal", 528)])
+def test_sixth_cell_s_attention_compiles_for_v5e(one_chip, mask, steps):
+    """The sixth cell's two attention layers: 32 query heads to 4 of 128
+    over one sequence of 16,384 rows, float32 in, tiles of 512 x 512,
+    forward and backward.  The sliding layers' window of 1,024 keys visits
+    the band of 93 tiles a head (the causal square's 528), each a grid step;
+    the backward kernel holds one key/value head's whole dk and dv, 8 MiB
+    each at 16,384 rows and twice for the pipeline, whatever the mask."""
+    from mxnet_tpu.ops.pallas_ops import causal_attention, window_attention
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.float32,
+                              sharding=one_chip)
+    if mask == "window":
+        attend = lambda q, k, v: window_attention(q, k, v, 1024,  # noqa: E731
+                                                  interpret=False)
+    else:
+        attend = lambda q, k, v: causal_attention(q, k, v,  # noqa: E731
+                                                  interpret=False)
+    step = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v)), (0, 1, 2))
+    compiled = jax.jit(step).lower(q, kv, kv).compile()
+    assert set(re.findall(r"%(attention_\w+?)(?:\.\d+)? = ",
+                          compiled.as_text())) == {
+        "attention_fwd", "attention_bwd"}
+    assert _pallas_grids(jax.make_jaxpr(step)(q, kv, kv).jaxpr) == {
+        "attention_fwd": (32, steps), "attention_bwd": (32, steps)}
+    _fits(compiled)
 
 # -- the fifth cell's dense feed-forward ---------------------------------------
 
