@@ -1131,6 +1131,7 @@ def gated_short_conv(streams, taps, interpret=None, rows=SHORT_CONV_ROWS):
 # ---------------------------------------------------------------------------
 SLOT_TILE_ROWS = 256        # slots to a tile: one expert's, one grid step
 _WGRAD_WIDTH = 768          # an expert's hidden units to a weight-gradient step
+COMBINE_SLAB = 128          # slots a step of ``slot_combine`` reads at once
 
 
 def slot_tile_rows(pairs, held):
@@ -1139,6 +1140,14 @@ def slot_tile_rows(pairs, held):
     small the mean group rounded up to a multiple of 16 (a bfloat16 tile's
     rows)."""
     return min(SLOT_TILE_ROWS, max(16, -(-pairs // (16 * held)) * 16))
+
+
+def combine_tile_rows(rows):
+    """Rows of a tile of ``slot_combine``'s output: an expert's slots of a
+    tile's rows are one run of at most as many, which a slab of
+    ``COMBINE_SLAB`` read from a multiple of 8 holds whole, so 120 (all the
+    rows, rounded up to 8, where fewer)."""
+    return min(COMBINE_SLAB - 8, -(-rows // 8) * 8)
 
 
 def _tn(a, b):
@@ -1156,24 +1165,44 @@ def _silu_parts(g):
     return s, g * s
 
 
+def _bf16_pieces(a):
+    """Three bfloat16 arrays whose sum is float32 ``a`` exactly: its top 8
+    significant bits, the next 8 and the rest, each cut off, not rounded.
+    The three then have ``a``'s sign and no bit in common, so every partial
+    sum of them is exact, in any order; a product with 0s and 1s selects
+    each exactly (``a`` finite)."""
+    import jax
+    import jax.numpy as jnp
+
+    def top(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.int32) & -65536
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+    hi = top(a)
+    rest = a - hi
+    mid = top(rest)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
+
+
 class _Experts:
     """The grouped products of a held experts' layer over a table of
     ``S`` slots in tiles of ``tm``, every tile one expert's
     (``tile_expert``, (S / tm,) int32, each expert's tiles consecutive and
     every expert with at least one): forward, the gradients of the slots'
-    values, and the weights' gradients.  ``gate_w``, ``up_w``: (held, f, d);
-    ``down_w``: (held, d, f).
+    values, the weights' gradients, and the slots' values added back to
+    rows.  ``gate_w``, ``up_w``: (held, f, d); ``down_w``: (held, d, f).
 
     On a TPU (or where ``interpret`` is given), with ``d`` and ``f``
-    multiples of 128 and ``tm`` of 16: four kernels, ``moe_experts_hidden``,
+    multiples of 128 and ``tm`` of 16: five kernels, ``moe_experts_hidden``,
     ``moe_experts_down``, ``moe_experts_bwd`` (a grid step a tile, the tile's
     expert by scalar prefetch, that expert's matrices whole in VMEM and
     fetched again only when the expert changes; the down projection is a
     call of its own so that a recomputed forward pass, which needs the
-    hidden units and not the output, drops it) and ``moe_experts_wgrad`` (a
+    hidden units and not the output, drops it), ``moe_experts_wgrad`` (a
     grid step a tile and block of at most ``_WGRAD_WIDTH`` hidden units, an
-    expert's gradient blocks resident over its consecutive tiles).  The
-    number of grid steps
+    expert's gradient blocks resident over its consecutive tiles) and
+    ``slot_combine`` (``combine``: each row's slots added up, a grid step a
+    tile of rows and a held expert; not ``moe_``-named, since its time is
+    no product's).  The number of grid steps
     follows from the shapes alone.  The products take bfloat16 operands
     (cast once, outside) and accumulate in float32, which is what XLA's
     default precision gives float32 operands on the TPU; everything between
@@ -1372,6 +1401,75 @@ class _Experts:
              jax.ShapeDtypeStruct((held, f, d), jnp.float32),
              jax.ShapeDtypeStruct((held, d, f), jnp.float32)])(
                  self.te, x_s, dy_s, dg, du, hw)
+
+    # -- the slots back to rows ----------------------------------------------
+    def combine_runs(self, rows):
+        """Grid steps of ``combine`` over ``rows`` rows, a tile of rows and
+        a held expert each; 0 where the kernels do not run."""
+        return (-(-rows // combine_tile_rows(rows)) * self.held
+                if self.kernels else 0)
+
+    def combine(self, a_s, slot_of, here):
+        """(T, width) float32: the values of each row's slots added up, for
+        float32 ``a_s`` (S, width) whose empty slots hold 0; ``slot_of`` and
+        ``here`` (T, k) as ``parallel.moe.slot_table`` gives them.  One
+        kernel, ``slot_combine``: a grid step a tile of rows and a held
+        expert.  An expert's group holds its pairs in the order of their
+        rows, a row at most one, so a tile's pairs with the expert are one
+        run of consecutive slots, no longer than the tile: the step reads a
+        slab of ``COMBINE_SLAB`` slots from a multiple of 8 at or below the
+        run's first (scalar prefetch; the slab clamped into the table), and
+        a product with a one-hot matrix places each slot's row at its row
+        of the tile, in three exact bfloat16 pieces (``_bf16_pieces``) that
+        are added before the sum over the experts, which stays resident in
+        float32.  A pair of an expert held elsewhere is read by no step.
+        The grid and the slab follow from the shapes alone."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+        T, k = here.shape
+        held, tm, d = self.held, self.tm, a_s.shape[1]
+        slots = self.n * tm
+        tr = combine_tile_rows(T)
+        nt, slab = -(-T // tr), min(COMBINE_SLAB, slots)
+        # each row's slot with each held expert, -1 for none: an expert's
+        # group runs from its first tile to the next expert's
+        first = tm * jnp.sum(self.te[:, None] < jnp.arange(held), axis=0)
+        end = jnp.append(first[1:], slots)
+        slot = slot_of[:T * k].reshape(T, k, 1)
+        hit = here[..., None] & (slot >= first) & (slot < end)
+        row_slots = jnp.pad(jnp.max(jnp.where(hit, slot, -1), axis=1),
+                            ((0, nt * tr - T), (0, 0)), constant_values=-1)
+        lowest = jnp.min(jnp.where(row_slots >= 0, row_slots, slots)
+                         .reshape(nt, tr, held), axis=1)
+        # the slab's first slot over 8, which the compiler can see is aligned
+        start = jnp.minimum(lowest // 8, (slots - slab) // 8).reshape(
+            nt * held)
+
+        def kernel(start_ref, rows_ref, a_ref, out_ref):
+            i, e = pl.program_id(0), pl.program_id(1)
+
+            @pl.when(e == 0)
+            def _():
+                out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+            rows = rows_ref[...]
+            mine = jnp.max(jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, rows.shape, 1) == e, rows, -1), axis=1,
+                keepdims=True) - 8 * start_ref[i * held + e]
+            pick = (mine == jax.lax.broadcasted_iota(
+                jnp.int32, (tr, slab), 1)).astype(jnp.bfloat16)
+            hi, mid, lo = (jnp.dot(pick, p, preferred_element_type=jnp.float32)
+                           for p in _bf16_pieces(a_ref[...]))
+            out_ref[...] += hi + mid + lo
+
+        return self._call(
+            kernel, "slot_combine", (nt, held),
+            [pl.BlockSpec((tr, held), lambda i, e, s: (i, 0)),
+             pl.BlockSpec((pl.Element(slab), pl.Element(d)),
+                          lambda i, e, s: (8 * s[i * held + e], 0))],
+            pl.BlockSpec((tr, d), lambda i, e, s: (i, 0)),
+            jax.ShapeDtypeStruct((T, d), jnp.float32))(start, row_slots, a_s)
 
 
 @register("_contrib_flash_attention")

@@ -268,15 +268,43 @@ def _moved(by, values):
     return jax.lax.sort((by, values), num_keys=1, is_stable=False)[1]
 
 
+def _rows_of(a, index):
+    return a.at[index].get(mode="promise_in_bounds")
+
+
+def from_slots(experts, a_s, slot_of, here):
+    """(T, width) float32: the values of each row's slots of float32 ``a_s``
+    (S, width) added up, for the slot table of ``slot_table`` (``slot_of``,
+    ``here`` (T, k) as it gives them) whose empty slots hold 0.  Where
+    ``experts`` (``ops.pallas_ops._Experts``) runs its kernels, its
+    ``combine``: a tile of rows' slots with an expert are one run of
+    consecutive slots, read as a slab at the memory's bandwidth, and a pair
+    of an expert held elsewhere is never read.  Elsewhere XLA's form, which
+    is also the kernel's oracle: every (row, pick) pair's slot gathered and
+    the picks added; a pair of an expert held elsewhere sits in an empty
+    slot or past the table, where the table's last slot stands in, which
+    is empty, since the tail never is.  The two differ only in the order of
+    their float32 additions."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("moe.combine"):
+        if experts.kernels:
+            return experts.combine(a_s, slot_of, here)
+        T, k = here.shape
+        slot_of_pair = jnp.minimum(slot_of[:T * k], a_s.shape[0] - 1)
+        return jnp.sum(_rows_of(a_s, slot_of_pair.reshape(T, k).T), axis=0)
+
+
 def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
                   tile_expert, here, tm):
     """``sum_j weights[t, j] expert_j(x[t])`` over each row's pairs that
     ``here`` marks, through the slot table of ``slot_table``: rows gathered
     to slots, the grouped products of ``ops.pallas_ops._Experts`` over whole
-    tiles, each row's slots gathered back and added.  Both directions of
-    both passes are gathers of rows by ``source`` or ``slot_of`` (the
-    permutation and its inverse), and a value a slot or a pair moves the
-    other way by a sort on them: the backward pass is written out, since
+    tiles, and each row's slots added up (``from_slots``: on a TPU one
+    kernel that reads each tile of rows' run of slots with each expert).
+    Rows go to slots by gathers by ``source``, and a value a slot or a pair
+    moves the other way by a sort on ``source`` or ``slot_of`` (the
+    permutation and its inverse): the backward pass is written out, since
     the transpose of a gather is a scatter-add."""
     import jax
     import jax.numpy as jnp
@@ -288,9 +316,6 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
     pairs, entries = T * k, source.shape[0]
     slots = tile_expert.shape[0] * tm
 
-    def rows_of(a, index):
-        return a.at[index].get(mode="promise_in_bounds")
-
     def to_slots(experts, x, weights, source, slot_of, here):
         with jax.named_scope("moe.sort"):
             row_of_slot = jnp.where(source < pairs, source // k, 0)[:slots]
@@ -298,16 +323,7 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
                               (0, entries - pairs))
             return row_of_slot, checkpoint_name(
                 _moved(slot_of, w_entry)[:slots], SLOT_TABLE), \
-                rows_of(experts.cast(x), row_of_slot)
-
-    def from_slots(a_s, slot_of):
-        """(T, width): each row's slots' values added up.  A pair of an
-        expert held elsewhere sits in an empty slot, whose values are 0, or
-        past the table: the table's last slot then stands in, which is
-        empty, since the tail never is."""
-        with jax.named_scope("moe.combine"):
-            slot_of_pair = jnp.minimum(slot_of[:pairs], slots - 1)
-            return jnp.sum(rows_of(a_s, slot_of_pair.reshape(T, k).T), axis=0)
+                _rows_of(experts.cast(x), row_of_slot)
 
     def forward(keep, x, weights, gate_w, up_w, down_w, source, slot_of,
                 tile_expert, here):
@@ -318,7 +334,7 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
             matrices = tuple(experts.cast(w) for w in (gate_w, up_w, down_w))
             hw, *kept = experts.hidden(x_s, w_s, *matrices[:2], keep=keep)
             y_s = experts.down(hw, matrices[2])
-        return from_slots(y_s, slot_of).astype(x.dtype), (
+        return from_slots(experts, y_s, slot_of, here).astype(x.dtype), (
             row_of_slot, w_s, x_s, hw, *kept, *matrices, source, slot_of,
             tile_expert, here)
 
@@ -331,12 +347,12 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
             slot_of, tile_expert, here = kept
         experts = _Experts(tile_expert, tm, d, f, held)
         with jax.named_scope("moe.sort"):
-            dy_s = rows_of(experts.cast(dout), row_of_slot)
+            dy_s = _rows_of(experts.cast(dout), row_of_slot)
         with jax.named_scope("moe.experts"):
             dx_s, dw_s, dg, du = experts.backward(dy_s, g, u, w_s, gate_w,
                                                   up_w, down_w)
             d_matrices = experts.weight_gradients(x_s, dy_s, dg, du, hw)
-        dx = from_slots(dx_s, slot_of).astype(dout.dtype)
+        dx = from_slots(experts, dx_s, slot_of, here).astype(dout.dtype)
         with jax.named_scope("moe.sort"):
             dw_entry = _moved(source, jnp.pad(dw_s, (0, entries - slots)))
             d_weights = jnp.where(here, dw_entry[:pairs].reshape(T, k), 0.0)
@@ -396,7 +412,7 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
     from .. import profiler
-    from ..ops.pallas_ops import SLOT_TABLE, slot_tile_rows
+    from ..ops.pallas_ops import SLOT_TABLE, _Experts, slot_tile_rows
 
     T, d = x.shape
     E, held = router_w.shape[0], gate_w.shape[0]
@@ -416,5 +432,7 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
         load = jnp.stack([jnp.sum(per_expert),
                           jnp.max(per_expert)]).astype(jnp.float32)
     profiler.count("moe.slots", tile_expert.shape[0] * tm)
+    profiler.count("moe.combine_runs", _Experts(
+        tile_expert, tm, d, gate_w.shape[1], held).combine_runs(T))
     return _held_experts(x, weights.astype(x.dtype), gate_w, up_w, down_w,
                          source, slot_of, tile_expert, here, tm), load

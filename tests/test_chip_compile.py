@@ -236,11 +236,16 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
                                              total, held, width, picked,
                                              slots, hidden, blocks):
     """A chip's held experts of ``width`` x ``hidden``, ``picked`` per token,
-    forward and backward: the pairs in ``rows * min(picked, held)`` slots and
-    a tile of 256 an expert, four kernels over whole tiles with an expert's
-    matrices in VMEM; a grid step a tile (and, for the weights' gradients, a
-    block of the largest multiple of 128 up to 768 hidden units that divides
-    the width: 128 of 896), whatever the router picks."""
+    forward and backward, recomputed as the decoder cells' layers are: the
+    pairs in ``rows * min(picked, held)`` slots and a tile of 256 an expert,
+    four kernels over whole tiles with an expert's matrices in VMEM; a grid
+    step a tile (and, for the weights' gradients, a block of the largest
+    multiple of 128 up to 768 hidden units that divides the width: 128 of
+    896), whatever the router picks.  Each row's slots are added up by
+    ``slot_combine``, a grid step a tile of 120 rows and a held expert, for
+    the output and for the rows' gradient and not in the recomputed pass,
+    whose output nothing reads."""
+    from mxnet_tpu.ops.pallas_ops import ATTENTION_RESIDUALS
     from mxnet_tpu.parallel.moe import moe_held_apply
     # the layer asks the backend whether its kernels can run: here the CPU's
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -248,8 +253,13 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
     def s(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *ATTENTION_RESIDUALS)
+
     def loss(x, router_w, gate_w, up_w, down_w):
-        out, load = moe_held_apply(x, router_w, gate_w, up_w, down_w, picked)
+        out, load = jax.checkpoint(lambda *a: moe_held_apply(*a, picked),
+                                   policy=policy)(
+            x, router_w, gate_w, up_w, down_w)
         return jnp.sum(out) + load[1]
 
     # the value too: a pass that needs no output drops the down projection
@@ -257,15 +267,20 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
     shapes = (s(rows, hidden), s(total, hidden), s(held, width, hidden),
               s(held, width, hidden), s(held, hidden, width))
     compiled = jax.jit(step).lower(*shapes).compile()
-    assert set(re.findall(r"%(moe_\w+?)(?:\.\d+)? = ",
-                          compiled.as_text())) == {
+    text = compiled.as_text()
+    assert set(re.findall(r"%(moe_\w+?)(?:\.\d+)? = ", text)) == {
         "moe_experts_hidden", "moe_experts_down", "moe_experts_bwd",
         "moe_experts_wgrad"}
+    assert len(re.findall(r"%slot_combine(?:\.\d+)? = ", text)) == 2
+    # the recomputed pass runs the hidden units' kernel again, and no other
+    assert len(re.findall(r"%moe_experts_hidden(?:\.\d+)? = ", text)) == 2
+    assert len(re.findall(r"%moe_experts_down(?:\.\d+)? = ", text)) == 1
     tiles = slots // 256
     assert _pallas_grids(jax.make_jaxpr(step)(*shapes).jaxpr) == {
         "moe_experts_hidden": (tiles,), "moe_experts_down": (tiles,),
         "moe_experts_bwd": (tiles,),
-        "moe_experts_wgrad": (blocks, tiles)}
+        "moe_experts_wgrad": (blocks, tiles),
+        "slot_combine": (-(-rows // 120), held)}
     _fits(compiled)
 
 

@@ -4,6 +4,8 @@ picked experts computed, the pairs sorted by expert into a fixed number of
 slots, grouped products over whole tiles.  Held here against a plain loop
 over the held experts, for every routing the table has to hold; the kernels
 run interpreted, against the XLA path the CPU takes."""
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,7 +169,9 @@ def test_slots_tiles_and_program_follow_from_the_shapes_alone():
     step = jax.value_and_grad(
         lambda *a: jnp.sum(_slots(*a, k, first, "softmax")[0]), (0, 1, 2, 3, 4))
     before = dict(profiler.totals()).get("moe.slots", {"count": 0})["count"]
+    runs = _runs()
     texts = {str(jax.make_jaxpr(step)(*a)) for a in (even, one)}
+    assert _runs() == runs      # XLA's gather adds the slots up here
     assert len(texts) == 1
     text = texts.pop()
     assert "cond[" not in text and "while[" not in text
@@ -244,9 +248,17 @@ def test_experts_kernels_interpreted_match_the_xla_path(held, f, d, tm,
           1e-5)
 
 
+def _runs():
+    return dict(profiler.totals()).get("moe.combine_runs",
+                                       {"count": 0})["count"]
+
+
 def test_layer_through_the_interpreted_kernels(monkeypatch):
     """The whole layer, forward and backward, with the kernels in place of
-    the XLA products (as on a TPU), against the loop: one bfloat16 pass."""
+    the XLA products and of the gather that adds each row's slots (as on a
+    TPU), against the loop: one bfloat16 pass; and against the same layer
+    with XLA's gather-and-sum, which only the order of float32 additions
+    tells apart."""
     real = pallas_ops._Experts
     monkeypatch.setattr(pallas_ops, "_Experts", lambda *a: real(
         *a, interpret=True))
@@ -265,9 +277,107 @@ def test_layer_through_the_interpreted_kernels(monkeypatch):
             layer(*a, None, k, first, "softmax"))), (0, 1, 2, 3, 4))(
                 *args[:5])
 
+    before = _runs()
     got = step(_slot_out)
+    # a tile of 120 rows and a held expert a grid step: 128 rows are 2 tiles
+    assert pallas_ops.combine_tile_rows(rows) == 120
+    assert _runs() - before == 2 * held
     want = step(_loop)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         gap = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
         assert gap < 1e-2, gap
+
+    gather = moe.from_slots
+    monkeypatch.setattr(moe, "from_slots", lambda experts, *a: gather(
+        types.SimpleNamespace(kernels=False), *a))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(step(_slot_out))):
+        gap = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        assert gap < 1e-6, gap
+
+
+def _combine_case(rows, total, held, first, k, picked, values, seed):
+    """A table of slots as the layer leaves it (``values`` in the slots of
+    held pairs, 0 in every other) and its rows' sums by the interpreted
+    kernel and by XLA's gather."""
+    tm = 16
+    source, slot_of, te, here, _ = moe.slot_table(
+        jnp.asarray(picked, jnp.int32), first, held, tm)
+    S = te.shape[0] * tm
+    held_pairs = np.asarray(slot_of[:rows * k]).reshape(rows, k)[
+        np.asarray(here)]
+    full = np.zeros((S, 1), bool)
+    full[held_pairs] = True
+    a_s = jnp.asarray(np.where(full, values(np.random.RandomState(seed), S),
+                               0), jnp.float32)
+    ker = pallas_ops._Experts(te, tm, 128, 128, held, interpret=True)
+    xla = pallas_ops._Experts(te, tm, 128, 128, held)
+    assert ker.kernels and not xla.kernels
+    return (np.asarray(moe.from_slots(e, a_s, slot_of, here))
+            for e in (ker, xla)), np.asarray(here)
+
+
+def _normal(rng, S):
+    return rng.normal(0, 1, (S, 128))
+
+
+def _just_above_one(rng, S):
+    # 1 + 2**-20 times -2, -1, 1 or 2: no bfloat16 holds them, and every
+    # sum of three of them is exact in float32
+    return (1 + 2.0 ** -20) * rng.choice([-2, -1, 1, 2], (S, 128))
+
+
+def _picks(rng, rows, total, k, steer):
+    """Each row's ``k`` distinct experts: those of ``steer`` first, then
+    others at random, never ``-e`` for an ``-e`` in ``steer``."""
+    first = [e for e in steer if e >= 0]
+    rest = [e for e in range(total) if e not in first and -e not in steer]
+    return np.stack([first + list(rng.permutation(rest)[:k - len(first)])
+                     for _ in range(rows)])
+
+
+# (rows, experts in all, held, first held, per token, the picks' steer,
+# values)
+COMBINES = {
+    "picks_equal_held": (240, 16, 4, 4, 4, (), _normal),
+    "picks_fewer_than_held": (240, 16, 8, 0, 3, (), _normal),
+    "rows_no_tile_divides": (250, 16, 4, 4, 2, (), _normal),
+    "one_expert_every_row": (250, 16, 4, 4, 2, (6,), _normal),
+    "a_held_expert_without_pair": (250, 16, 4, 4, 2, (-5,), _normal),
+    "pairs_held_elsewhere": (130, 16, 4, 4, 3, (0, 15), _normal),
+    "values_bfloat16_cannot_hold": (250, 16, 4, 4, 3, (), _just_above_one),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMBINES))
+def test_slot_combine_kernel_is_xla_s_gather_and_sum(case):
+    """The kernel (interpreted) against XLA's form, row by row, to 1e-6 of
+    the row: the same float32 values added in another order.  Where every
+    partial sum is exact, bit for bit, so a value rounded through bfloat16
+    anywhere fails."""
+    rows, total, held, first, k, steer, values = COMBINES[case]
+    picked = _picks(np.random.RandomState(len(case)), rows, total, k, steer)
+    (got, want), here = _combine_case(rows, total, held, first, k, picked,
+                                      values, len(case))
+    assert got.shape == want.shape == (rows, 128)
+    gap = np.linalg.norm(got - want, axis=1)
+    assert (gap <= 1e-6 * np.linalg.norm(want, axis=1)).all(), gap.max()
+    assert ((want == 0) == (got == 0)).all()
+    if case == "one_expert_every_row":
+        assert here[:, 0].all()
+    if case == "a_held_expert_without_pair":
+        assert not (picked == 5).any()
+    if case == "pairs_held_elsewhere":
+        assert not here[:, :2].any() and here[:, 2].any()
+    if case == "values_bfloat16_cannot_hold":
+        assert (got == want).all()
+        assert (got[here.any(1)] != np.asarray(jnp.asarray(
+            got[here.any(1)]).astype(jnp.bfloat16).astype(jnp.float32))
+                ).any()
+    if case in ("picks_equal_held", "rows_no_tile_divides"):
+        tiles = -(-rows // pallas_ops.combine_tile_rows(rows))
+        assert tiles == (2 if rows == 240 else 3)
+        assert pallas_ops._Experts(
+            jnp.zeros(4, jnp.int32), 16, 128, 128, held,
+            interpret=True).combine_runs(rows) == tiles * held
