@@ -1,14 +1,16 @@
 """Causal decoder language models whose layers differ, and the stack that
 builds them from their configuration (``LayerTypedLM``): embedding, one
-``DecoderLayer`` for each layer held, whose operator and feed-forward its
-published index chooses, final RMSNorm, and a head over every row, tied to the
-embedding or a leaf of its own.  ``held_layers`` reads that choice:
-``layer_types`` gives a layer's operator, and its feed-forward is dense where
-``mlp_layer_types`` says ``dense`` or, where the configuration has no such
-list, for the leading ``num_dense_layers`` layers; ``deployment.layers``
-holds the published indices of the layers held (default: the first
-``num_hidden_layers``).  gluon/model_zoo/window_moe_lm.py builds a second
-model on it.
+layer for each layer held, whose blocks its published index chooses, final
+RMSNorm, and a head over every row, tied to the embedding or a leaf of its
+own.  ``held_layers`` reads that choice.  A ``DecoderLayer`` joins an
+operator and a feed-forward: ``layer_types`` gives a layer's operator, and
+its feed-forward is dense where ``mlp_layer_types`` says ``dense`` or, where
+the configuration has no such list, for the leading ``num_dense_layers``
+layers.  A ``ResidualLayer`` is one block behind one norm: the letter of
+``hybrid_override_pattern`` gives its kind, where the configuration has that
+string.  ``deployment.layers`` holds the published indices of the layers held
+(default: the first ``num_hidden_layers``).  Three models share the stack:
+this module's, gluon/model_zoo/window_moe_lm.py's and hybrid_ssm_lm.py's.
 
 The model of this module is LFM2-24B-A2B
 (https://huggingface.co/LiquidAI/LFM2-24B-A2B, ``model_type: lfm2_moe``): a
@@ -39,7 +41,8 @@ from ...ops.pallas_ops import ATTENTION_RESIDUALS
 from ..block import HybridBlock
 from ..nn import Dense, Embedding
 from ..nn.decoder_layers import (CausalAttention, DecoderLayer, GatedMLP,
-                                 GatedShortConv, HeldExpertsMoE, RMSNorm)
+                                 GatedShortConv, HeldExpertsMoE,
+                                 ResidualLayer, RMSNorm)
 
 __all__ = ["LayerTypedLM", "ShortConvLM", "build", "loss", "N_INPUTS"]
 
@@ -59,33 +62,45 @@ def _dense(config, index):
 
 def held_layers(config):
     """``(operator's kind, whether the feed-forward is dense)`` of each layer
-    held here, by its published index."""
+    held here, by its published index; where the configuration has a
+    ``hybrid_override_pattern``, ``(its letter, None)``: a layer of one
+    block."""
     count = config["num_hidden_layers"]
     held = config.get("deployment", {}).get("layers") or range(count)
     if len(held) != count:
         raise ValueError("%d layers held of num_hidden_layers %d"
                          % (len(held), count))
+    if "hybrid_override_pattern" in config:
+        return [(config["hybrid_override_pattern"][i], None) for i in held]
     return [(config["layer_types"][i], _dense(config, i)) for i in held]
 
 
 class LayerTypedLM(HybridBlock):
     """The stack.  ``operator(kind)`` and ``feed_forward(dense)`` give, for
     a layer of ``held_layers``, what builds its operator and its feed-forward
-    (``DecoderLayer`` calls them inside the layer's name scope); ``eps`` is
-    the RMSNorms'; ``tied``: the head is the embedding's own matrix, which
-    then gets a gradient from the lookup and from the head, else a leaf
+    (``DecoderLayer`` calls them inside the layer's name scope); for a layer
+    of one block ``block(kind)`` gives ``(what builds it, whether it mixes
+    rows)`` (``ResidualLayer``'s ``block`` and ``mixes``); ``eps`` is the
+    RMSNorms'; ``tied``: the head is the embedding's own matrix, which then
+    gets a gradient from the lookup and from the head, else a leaf
     ``head_weight`` of its own."""
 
-    def __init__(self, config, operator, feed_forward, eps, tied, **kwargs):
+    def __init__(self, config, operator, feed_forward, eps, tied, block=None,
+                 **kwargs):
         super().__init__(**kwargs)
         hidden, vocab = config["hidden_size"], config["vocab_size"]
         with self.name_scope():
             self.embed = Embedding(vocab, hidden, prefix="embed_")
             self.layers = []
             for i, (kind, dense) in enumerate(held_layers(config)):
-                layer = DecoderLayer(hidden, operator(kind),
-                                     feed_forward(dense), eps,
-                                     prefix="layer%d_" % i)
+                if dense is None:
+                    made, mixes = block(kind)
+                    layer = ResidualLayer(hidden, made, eps, mixes,
+                                          prefix="layer%d_" % i)
+                else:
+                    layer = DecoderLayer(hidden, operator(kind),
+                                         feed_forward(dense), eps,
+                                         prefix="layer%d_" % i)
                 self.register_child(layer)
                 self.layers.append(layer)
             self.final_norm = RMSNorm(hidden, eps, prefix="final_norm_")

@@ -1,13 +1,16 @@
 """Layers of decoder language models: weighted RMSNorm, grouped-query
-attention with query/key norm and rotary positions (causal, causal over a
-sliding window of keys, under the block-diffusion mask, or causal over the
-keys a learned indexer picks for each query; the rotary's default frequencies
-or YaRN's), a gated short convolution along the sequence, a dense gated
-feed-forward, the mixture-of-experts layer for the experts this chip holds
-(softmax or sigmoid router), and the decoder layers that join them: attention
-over experts (two forms), and a layer whose operator and feed-forward are
-chosen per layer.  gluon/model_zoo/block_diffusion.py, sparse_causal_lm.py,
-short_conv_lm.py and window_moe_lm.py build models of them from a
+attention with or without query/key norm and rotary positions (causal,
+causal over a sliding window of keys, under the block-diffusion mask, or
+causal over the keys a learned indexer picks for each query; the rotary's
+default frequencies or YaRN's), a gated short convolution along the
+sequence, a Mamba-2 mixer (a state-space scan between its projections), a
+dense gated feed-forward and a dense relu² one, the mixture-of-experts layer
+for the experts this chip holds (softmax or sigmoid router, SiLU-gated or
+relu² experts, an optional shared expert), and the decoder layers that join
+them: attention over experts (two forms), a layer whose operator and
+feed-forward are chosen per layer, and a layer of one block.
+gluon/model_zoo/block_diffusion.py, sparse_causal_lm.py, short_conv_lm.py,
+window_moe_lm.py and hybrid_ssm_lm.py build models of them from a
 configuration.
 """
 from __future__ import annotations
@@ -21,7 +24,8 @@ from .basic_layers import Dense
 __all__ = ["RMSNorm", "BlockDiffusionAttention", "HeldExpertsMoE",
            "BlockDiffusionDecoderLayer", "IndexedSparseAttention",
            "SparseDecoderLayer", "CausalAttention", "GatedShortConv",
-           "GatedMLP", "DecoderLayer"]
+           "Mamba2Mixer", "GatedMLP", "ReluSquaredMLP", "DecoderLayer",
+           "ResidualLayer"]
 
 
 class RMSNorm(HybridBlock):
@@ -40,11 +44,13 @@ class RMSNorm(HybridBlock):
 class _GroupedQueryAttention(HybridBlock):
     """What the attention blocks share: q, k, v and o projections without
     bias, ``heads`` query heads to ``kv_heads`` key/value heads of
-    ``head_dim``, RMSNorm over each head's dimensions of q and of k, and a
-    rotary embedding (each block's own form) of the normed q and k under the
-    scope ``attn.proj``; the blocks differ in the mask of their kernels."""
+    ``head_dim``, with ``qk_norm`` RMSNorm over each head's dimensions of q
+    and of k, and a rotary embedding (each block's own form, or none) of q
+    and k, all under the scope ``attn.proj``; the blocks differ in the mask
+    of their kernels."""
 
-    def __init__(self, hidden, heads, kv_heads, head_dim, epsilon, **kwargs):
+    def __init__(self, hidden, heads, kv_heads, head_dim, epsilon,
+                 qk_norm=True, **kwargs):
         super().__init__(**kwargs)
         self._heads, self._kv_heads, self._head_dim = heads, kv_heads, head_dim
         with self.name_scope():
@@ -52,8 +58,10 @@ class _GroupedQueryAttention(HybridBlock):
             self.k = self._proj(kv_heads * head_dim, hidden, "k_")
             self.v = self._proj(kv_heads * head_dim, hidden, "v_")
             self.o = self._proj(hidden, heads * head_dim, "o_")
-            self.q_norm = RMSNorm(head_dim, epsilon, prefix="q_norm_")
-            self.k_norm = RMSNorm(head_dim, epsilon, prefix="k_norm_")
+            self.q_norm = self.k_norm = None
+            if qk_norm:
+                self.q_norm = RMSNorm(head_dim, epsilon, prefix="q_norm_")
+                self.k_norm = RMSNorm(head_dim, epsilon, prefix="k_norm_")
 
     @staticmethod
     def _proj(units, in_units, prefix):
@@ -114,10 +122,12 @@ class HeldExpertsMoE(HybridBlock):
     chip give (parallel/moe.py ``moe_held_apply``): the router scores all
     ``experts_total`` experts, takes ``experts_per_token`` with their weights
     normalised over the picked ones, and experts ``first_expert ..
-    first_expert + experts_held - 1`` compute ``down(silu(gate y) * up y)``
-    for the tokens routed to them.  No token is dropped: a row's pairs are
-    sorted by expert into a table of ``rows x min(experts_per_token,
-    experts_held)`` slots and a tile of alignment an expert, which holds
+    first_expert + experts_held - 1`` compute, for the tokens routed to
+    them, ``down(silu(gate y) * up y)`` (``act`` "swiglu") or
+    ``down(relu(up y)^2)`` (``act`` "relu2", no gate leaf).  No token is
+    dropped: a row's pairs are sorted by expert into a table of ``rows x
+    min(experts_per_token, experts_held)`` slots and a tile of alignment an
+    expert, which holds
     every pair of every routing, and grouped products run over its tiles
     (every slot every step, an empty one with weight 0: a step's time does
     not follow the routing).  The held experts' matrices are stacked in 2-D
@@ -130,6 +140,13 @@ class HeldExpertsMoE(HybridBlock):
     ``expert_bias`` (experts_total,) added to the sigmoid scores for the
     selection alone: the weights are the unbiased scores', so its gradient
     is exactly 0 and a training step leaves it where the checkpoint had it.
+    ``router_eps``: what the sigmoid router adds under its normalisation
+    (default ``parallel.moe.SIGMOID_NORM_EPS``).
+
+    ``shared_width``: a shared expert beside the held ones, a
+    ``ReluSquaredMLP`` of that width (child ``shared``) over every row,
+    computed whole on every chip of the layer alike and added to the held
+    experts' part, under the scope ``mlp.shared``.
 
     ``load`` (non-trainable state, written by every training step) holds the
     newest step's ``[pairs routed here, largest held expert's load]``;
@@ -138,11 +155,17 @@ class HeldExpertsMoE(HybridBlock):
 
     def __init__(self, hidden, width, experts_total, experts_per_token,
                  experts_held, first_expert=0, scoring="softmax", scale=1.0,
-                 selection_bias=False, **kwargs):
+                 selection_bias=False, act="swiglu", router_eps=None,
+                 shared_width=None, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"experts_per_token": experts_per_token,
                        "expert_width": width, "first_expert": first_expert,
                        "scoring": scoring, "scale": scale}
+        if act != "swiglu":
+            self._attrs["act"] = act
+        if router_eps is not None:
+            self._attrs["router_eps"] = router_eps
+        self.shared = None
         with self.name_scope():
             get = self.params.get
             if selection_bias:
@@ -150,27 +173,33 @@ class HeldExpertsMoE(HybridBlock):
                                        init="zeros")
             self.router_weight = get("router_weight",
                                      shape=(experts_total, hidden))
-            self.gate_weight = get("gate_weight",
-                                   shape=(experts_held * width, hidden))
+            if act == "swiglu":
+                self.gate_weight = get("gate_weight",
+                                       shape=(experts_held * width, hidden))
             self.up_weight = get("up_weight",
                                  shape=(experts_held * width, hidden))
             self.down_weight = get("down_weight",
                                    shape=(experts_held * hidden, width))
             self.load = get("load", shape=(2,), init="zeros",
                             grad_req="null", differentiable=False)
+            if shared_width:
+                self.shared = ReluSquaredMLP(hidden, shared_width,
+                                             prefix="shared_")
         # the gauge keeps this leaf (two numbers) alive, not the block: a
         # reader asks for the load after the step and its network are freed
         state = self.load
         profiler.gauge("moe.load." + self.prefix,
                        lambda: tuple(float(v) for v in state.data().asnumpy()))
 
-    def hybrid_forward(self, F, x, router_weight, gate_weight, up_weight,
-                       down_weight, load, expert_bias=None):
+    def hybrid_forward(self, F, x, router_weight, up_weight, down_weight,
+                       load, gate_weight=None, expert_bias=None):
         out, now = F._contrib_moe_held_experts(
             x, router_weight, gate_weight, up_weight, down_weight,
             expert_bias, **self._attrs)
         if autograd.is_training() and isinstance(out, NDArray):
             load._set_data(now._data)
+        if self.shared is not None:
+            out = out + self.shared(x)
         return out
 
     def route(self, F, x, router_weight, expert_bias=None):
@@ -321,18 +350,24 @@ class CausalAttention(_GroupedQueryAttention):
     by the attention kernels at the default matmul precision.  ``rope``: the
     rotary embedding's attrs beside its base (``rope_type`` "yarn" and
     YaRN's parameters, as ``_contrib_rotary_embedding`` takes them; none for
-    the default form).  Inputs: ``x`` (B, L, hidden) and ``positions``
-    (L,)."""
+    the default form).  A ``rope_base`` of None: no rotary; ``qk_norm``
+    false: no norm of q and k.  Inputs: ``x`` (B, L, hidden) and
+    ``positions`` (L,)."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim, rope_base=1e6,
-                 epsilon=1e-6, window=None, rope=None, **kwargs):
-        super().__init__(hidden, heads, kv_heads, head_dim, epsilon, **kwargs)
+                 epsilon=1e-6, window=None, rope=None, qk_norm=True,
+                 **kwargs):
+        super().__init__(hidden, heads, kv_heads, head_dim, epsilon, qk_norm,
+                         **kwargs)
         self._rope_base, self._window = rope_base, window
         self._rope = dict(rope or {})
 
     def hybrid_forward(self, F, x, positions):
-        q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
-            t, positions, base=self._rope_base, **self._rope))
+        if self._rope_base is None:
+            q, k, v = self._qkv(F, x, lambda t: t)
+        else:
+            q, k, v = self._qkv(F, x, lambda t: F._contrib_rotary_embedding(
+                t, positions, base=self._rope_base, **self._rope))
         if self._window is None:
             return self._output(F, F._contrib_causal_attention(q, k, v))
         return self._output(F, F._contrib_window_attention(
@@ -368,6 +403,62 @@ class GatedShortConv(HybridBlock):
             return self.out_proj(mixed)
 
 
+class Mamba2Mixer(HybridBlock):
+    """A Mamba-2 mixer (the released ``nemotron_h`` form): the input
+    projection gives ``[z, xBC, dt]`` of widths ``heads * head_dim``,
+    ``heads * head_dim + 2 * groups * state`` and ``heads``; ``xBC =
+    silu(conv(xBC) + conv_bias)``, a causal depthwise convolution of
+    ``taps`` taps (``_contrib_ssm_conv``); the state-space scan of ``x``
+    (``heads`` heads of ``head_dim``) under ``B`` and ``C`` (``groups``
+    groups of ``state``) with steps ``softplus(dt + dt_bias)``, decay ``A =
+    -exp(A_log)`` and skip ``D`` a head, in chunks of ``chunk`` rows
+    (``_contrib_ssd_scan``); ``RMSNorm(y * silu(z))`` in ``groups`` groups
+    of channels, weight ``norm_gamma`` (``_contrib_gated_rms_norm``); the
+    output projection.  No bias but the convolution's.  Scopes ``ssm.proj``
+    (both projections), ``ssm.conv``, ``ssm.scan`` and ``ssm.norm``.
+    Inputs: ``x`` (B, L, hidden) and, as every operator of a layer, the
+    positions, which it does not read."""
+
+    def __init__(self, hidden, heads, head_dim, state, groups, taps=4,
+                 chunk=128, epsilon=1e-5, **kwargs):
+        super().__init__(**kwargs)
+        inner = heads * head_dim
+        self._inner, self._conv = inner, inner + 2 * groups * state
+        self._scan = {"heads": heads, "groups": groups, "state": state,
+                      "chunk": chunk}
+        self._norm = {"groups": groups, "eps": epsilon}
+        with self.name_scope():
+            self.in_proj = Dense(inner + self._conv + heads, in_units=hidden,
+                                 use_bias=False, flatten=False, prefix="in_")
+            self.out_proj = Dense(hidden, in_units=inner, use_bias=False,
+                                  flatten=False, prefix="out_")
+            get = self.params.get
+            self.conv_weight = get("conv_weight", shape=(self._conv, taps))
+            self.conv_bias = get("conv_bias", shape=(self._conv,),
+                                 init="zeros")
+            self.dt_bias = get("dt_bias", shape=(heads,), init="zeros")
+            self.A_log = get("A_log", shape=(heads,), init="zeros")
+            self.D = get("D", shape=(heads,), init="ones")
+            self.norm_gamma = get("norm_gamma", shape=(inner,), init="ones")
+
+    def hybrid_forward(self, F, x, positions=None, conv_weight=None,
+                       conv_bias=None, dt_bias=None, A_log=None, D=None,
+                       norm_gamma=None):
+        import jax
+        inner, conv = self._inner, self._conv
+        with jax.named_scope("ssm.proj"):
+            streams = self.in_proj(x)
+            z = F.slice_axis(streams, axis=-1, begin=0, end=inner)
+            xbc = F.slice_axis(streams, axis=-1, begin=inner,
+                               end=inner + conv)
+            dt = F.slice_axis(streams, axis=-1, begin=inner + conv, end=None)
+        xbc = F._contrib_ssm_conv(xbc, conv_weight, conv_bias)
+        y = F._contrib_ssd_scan(xbc, dt, dt_bias, A_log, D, **self._scan)
+        y = F._contrib_gated_rms_norm(y, z, norm_gamma, **self._norm)
+        with jax.named_scope("ssm.proj"):
+            return self.out_proj(y)
+
+
 class GatedMLP(HybridBlock):
     """A dense gated feed-forward, ``down(silu(gate u) * up u)`` of width
     ``width``, no bias: the operator ``_contrib_gated_mlp``, whose backward
@@ -391,6 +482,26 @@ class GatedMLP(HybridBlock):
                                         down_weight)
 
 
+class ReluSquaredMLP(HybridBlock):
+    """A dense feed-forward without gate or bias, ``down(relu(up u)^2)`` of
+    width ``width`` (``_contrib_relu2_mlp``), under the scope ``mlp.shared``:
+    its one use is ``HeldExpertsMoE``'s shared expert.  Weights
+    ``up_weight`` (width, hidden) and ``down_weight`` (hidden, width)."""
+
+    def __init__(self, hidden, width, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.up_weight = self.params.get("up_weight",
+                                             shape=(width, hidden))
+            self.down_weight = self.params.get("down_weight",
+                                               shape=(hidden, width))
+
+    def hybrid_forward(self, F, x, up_weight, down_weight):
+        import jax
+        with jax.named_scope("mlp.shared"):
+            return F._contrib_relu2_mlp(x, up_weight, down_weight)
+
+
 class DecoderLayer(HybridBlock):
     """``h = x + Op(RMSNorm(x)); x' = h + FF(RMSNorm(h))`` with the operator
     and the feed-forward chosen for this layer: ``operator`` and
@@ -412,3 +523,22 @@ class DecoderLayer(HybridBlock):
     def hybrid_forward(self, F, x, positions):
         h = x + self.operator(self.operator_norm(x), positions)
         return h + self.feed_forward(self.ffn_norm(h))
+
+
+class ResidualLayer(HybridBlock):
+    """``x' = x + Block(RMSNorm(x))``: a layer of one block behind one norm
+    (``norm_gamma``).  ``block`` builds it (a callable without arguments,
+    called inside this layer's name scope); ``mixes``: the block mixes rows
+    and takes ``(rows, positions)``, else it takes the rows alone."""
+
+    def __init__(self, hidden, block, epsilon=1e-6, mixes=True, **kwargs):
+        super().__init__(**kwargs)
+        self._mixes = mixes
+        with self.name_scope():
+            self.norm = RMSNorm(hidden, epsilon, prefix="norm_")
+            self.block = block()
+
+    def hybrid_forward(self, F, x, positions):
+        u = self.norm(x)
+        return x + (self.block(u, positions) if self._mixes
+                    else self.block(u))

@@ -2,14 +2,16 @@
 weighted RMSNorm, rotary position embedding with the positions as an input
 (one row, or three with the frequencies in sections; the default frequencies
 or YaRN's), a dense gated
-feed-forward whose backward pass is written out, the operator of the
-mixture-of-experts layer for the experts held on this chip (the layer itself
-is parallel/moe.py's, imported when the operator runs: ``mx.nd`` installs its
-operators before ``parallel`` is imported, so an operator registered there
-would not be found), a gated short convolution along the sequence, and a
-learned indexer's two: the selection of each query's keys and the loss that
-trains it.  The attention kernels (causal, sliding window, block mask,
-picked pairs) are in pallas_ops.py.
+feed-forward whose backward pass is written out, a dense relu² one, the
+operator of the mixture-of-experts layer for the experts held on this chip
+(the layer itself is parallel/moe.py's, imported when the operator runs:
+``mx.nd`` installs its operators before ``parallel`` is imported, so an
+operator registered there would not be found), a gated short convolution
+along the sequence, a Mamba-2 mixer's three (its causal convolution, its
+state-space scan and its gated norm in groups), and a learned indexer's two:
+the selection of each query's keys and the loss that trains it.  The
+attention kernels (causal, sliding window, block mask, picked pairs) and the
+scan's are in pallas_ops.py.
 """
 from __future__ import annotations
 
@@ -177,30 +179,52 @@ def gated_mlp(x, gate_w, up_w, down_w):
     return layer(x, gate_w, up_w, down_w)
 
 
+@register("_contrib_relu2_mlp", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _relu2_mlp(attrs, x, up_w, down_w):
+    """A dense feed-forward without gate or bias, ``down(relu(up x)^2)``:
+    ``x`` (..., d); ``up_w``: (f, d); ``down_w``: (d, f).  Two products at
+    the default matmul precision; XLA's derivative."""
+    import jax
+    import jax.numpy as jnp
+    return jnp.matmul(jnp.square(jax.nn.relu(jnp.matmul(x, up_w.T))),
+                      down_w.T)
+
+
 @register("_contrib_moe_held_experts", num_outputs=2, no_jit=True,
           shape_rule="input", dtype_rule="input")
-def _moe_held_experts(attrs, x, router_w, gate_w, up_w, down_w, bias=None):
+def _moe_held_experts(attrs, x, router_w, *weights):
     """``parallel.moe.moe_held_apply`` as an operator.  ``x``: (..., d);
-    ``router_w``: (E, d) over all experts; ``gate_w``, ``up_w``:
-    (E_held * f, d) and ``down_w``: (E_held * d, f), the held experts'
-    matrices stacked along the first axis; optionally ``bias``: (E,), a
-    sigmoid router's selection bias.  attrs: ``experts_per_token``,
-    ``expert_width`` (f), ``first_expert``, and the router's form:
-    ``scoring`` ("softmax", the default, or "sigmoid") and ``scale`` (the
-    weights' factor, default 1).  Outputs: the layer's output, shaped as
-    ``x``, and float32 ``[pairs routed here, largest load]``.  The rows'
-    picked experts are computed through a table of slots sorted by expert,
-    sized from the shapes alone (on a TPU by the kernels ``moe_experts_*``
-    of ``pallas_ops``, elsewhere by XLA's products over the same table)."""
-    from ..parallel.moe import moe_held_apply
+    ``router_w``: (E, d) over all experts; then the held experts' matrices
+    stacked along the first axis, ``gate_w``, ``up_w``: (E_held * f, d) and
+    ``down_w``: (E_held * d, f) (with attr ``act`` "relu2" no ``gate_w``);
+    optionally ``bias``: (E,), a sigmoid router's selection bias.  attrs:
+    ``experts_per_token``, ``expert_width`` (f), ``first_expert``, ``act``
+    ("swiglu", the default, or "relu2"), and the router's form: ``scoring``
+    ("softmax", the default, or "sigmoid"), ``scale`` (the weights' factor,
+    default 1) and ``router_eps`` (under the sigmoid router's normalisation,
+    default ``parallel.moe.SIGMOID_NORM_EPS``).  Outputs: the layer's
+    output, shaped as ``x``, and float32 ``[pairs routed here, largest
+    load]``.  The rows' picked experts are computed through a table of
+    slots sorted by expert, sized from the shapes alone (on a TPU by the
+    kernels ``moe_experts_*`` of ``pallas_ops``, elsewhere by XLA's products
+    over the same table)."""
+    from ..parallel.moe import SIGMOID_NORM_EPS, moe_held_apply
     d, f = x.shape[-1], int(attrs["expert_width"])
+    act = str(attrs.get("act", "swiglu"))
+    count = 2 if act == "relu2" else 3
+    bias = weights[count] if len(weights) > count else None
+    rows = x.reshape(-1, d)
+    gate_w = None if count == 2 else weights[0].reshape(-1, f, d)
+    up_w, down_w = weights[count - 2:count]
     out, load = moe_held_apply(
-        x.reshape(-1, d), router_w, gate_w.reshape(-1, f, d),
+        rows, router_w, gate_w,
         up_w.reshape(-1, f, d), down_w.reshape(-1, d, f),
         int(attrs["experts_per_token"]),
         first_expert=int(attrs.get("first_expert", 0)),
         scoring=str(attrs.get("scoring", "softmax")),
-        scale=float(attrs.get("scale", 1.0)), bias=bias)
+        scale=float(attrs.get("scale", 1.0)), bias=bias, act=act,
+        eps=float(attrs.get("router_eps", SIGMOID_NORM_EPS)))
     return out.reshape(x.shape), load
 
 
@@ -212,11 +236,11 @@ def _moe_route(attrs, x, router_w, bias=None):
     the same inputs and attrs (those of the experts are not read); outputs
     the picked experts' weights, float32, and their ids, int32, each
     (rows, ``experts_per_token``)."""
-    from ..parallel.moe import route_tokens
+    from ..parallel.moe import SIGMOID_NORM_EPS, route_tokens
     return route_tokens(
         x.reshape(-1, x.shape[-1]), router_w, int(attrs["experts_per_token"]),
         str(attrs.get("scoring", "softmax")), float(attrs.get("scale", 1.0)),
-        bias)
+        bias, float(attrs.get("router_eps", SIGMOID_NORM_EPS)))
 
 
 @register("_contrib_gated_short_conv", no_jit=True, shape_rule="input",
@@ -228,6 +252,73 @@ def _gated_short_conv(attrs, streams, taps):
     channel; output ``Cg * conv(Bg * X)``, (B, L, d), causal along L."""
     from .pallas_ops import gated_short_conv
     return gated_short_conv(streams, taps)
+
+
+@register("_contrib_ssm_conv", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _ssm_conv(attrs, x, weight, bias):
+    """A Mamba-2 mixer's convolution: ``silu(conv(x) + bias)``, ``conv`` a
+    causal depthwise convolution along L of ``K`` taps, one filter a
+    channel: ``x`` (B, L, c); ``weight`` (c, K); ``bias`` (c,).  Row ``t``
+    reads rows ``t - K + 1 .. t`` of its own sequence, zeros before the
+    first (``weight[:, K - 1]`` multiplies row ``t``).  XLA's form, ``K``
+    shifted copies in one elementwise pass, under the scope ``ssm.conv``."""
+    import jax
+    import jax.numpy as jnp
+    K, length = weight.shape[1], x.shape[1]
+    with jax.named_scope("ssm.conv"):
+        padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+        w = weight.astype(x.dtype)
+        out = padded[:, :length] * w[:, 0]
+        for j in range(1, K):
+            out = out + padded[:, j:j + length] * w[:, j]
+        return jax.nn.silu(out + bias.astype(x.dtype))
+
+
+@register("_contrib_ssd_scan", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _ssd_scan(attrs, xbc, dt, dt_bias, a_log, d_skip):
+    """A Mamba-2 mixer's state-space scan (``pallas_ops.ssd_scan``) from
+    what the mixer's convolution and projection give: ``xbc`` (B, L, H P + 2
+    G N), the streams ``x``, ``B`` and ``C`` side by side; ``dt`` (B, L, H),
+    the steps before ``softplus(dt + dt_bias)``; ``a_log``, ``d_skip`` (H,):
+    ``A = -exp(a_log)`` and the skip ``D``.  attrs ``heads`` (H), ``groups``
+    (G), ``state`` (N), optional ``chunk``.  Output ``y`` (B, L, H P)."""
+    import jax
+    import jax.numpy as jnp
+    from .pallas_ops import SSD_CHUNK, ssd_scan
+    H, G, N = int(attrs["heads"]), int(attrs["groups"]), int(attrs["state"])
+    Bt, L, width = xbc.shape
+    inner = width - 2 * G * N
+    with jax.named_scope("ssm.scan"):
+        x = xbc[..., :inner].reshape(Bt, L, H, inner // H)
+        B = xbc[..., inner:inner + G * N].reshape(Bt, L, G, N)
+        C = xbc[..., inner + G * N:].reshape(Bt, L, G, N)
+        step = jax.nn.softplus((dt + dt_bias).astype(jnp.float32))
+        A = -jnp.exp(a_log.astype(jnp.float32))
+        y = ssd_scan(x, step, A, B, C, d_skip,
+                     chunk=int(attrs.get("chunk", SSD_CHUNK)))
+        return y.reshape(Bt, L, inner)
+
+
+@register("_contrib_gated_rms_norm", no_jit=True, shape_rule="input",
+          dtype_rule="input")
+def _gated_rms_norm(attrs, y, z, gamma):
+    """A Mamba-2 mixer's gated norm in groups (``norm_before_gate`` false):
+    ``h = y * silu(z)``, each of ``groups`` groups of consecutive channels
+    divided by its RMS (``sqrt(mean(h^2) + eps)``, in float32), times
+    ``gamma``.  ``y``, ``z`` (..., c); ``gamma`` (c,); attrs ``groups``,
+    ``eps``.  Under the scope ``ssm.norm``."""
+    import jax
+    import jax.numpy as jnp
+    groups, eps = int(attrs["groups"]), float(attrs.get("eps", 1e-5))
+    with jax.named_scope("ssm.norm"):
+        h = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        parts = h.reshape(h.shape[:-1] + (groups, -1))
+        parts = parts * jax.lax.rsqrt(
+            jnp.mean(parts * parts, axis=-1, keepdims=True) + eps)
+        return (parts.reshape(h.shape) * gamma.astype(jnp.float32)).astype(
+            y.dtype)
 
 
 def _order_bits(scores):

@@ -1127,6 +1127,324 @@ def gated_short_conv(streams, taps, interpret=None, rows=SHORT_CONV_ROWS):
 
 
 # ---------------------------------------------------------------------------
+# the state-space scan (Mamba-2's structured state-space duality)
+# ---------------------------------------------------------------------------
+SSD_CHUNK = 128             # rows of the sequence a chunk of the scan holds
+
+
+def _ssd_heads(a, groups):
+    """(Bt, L, G, N) of a group's values as (Bt, L, H, N), head ``h``
+    reading group ``h // (H / G)``."""
+    import jax.numpy as jnp
+    return jnp.repeat(a, groups, axis=2)
+
+
+def _ssd_chunks_reference(x, dt, l, B, C, chunk):
+    """The scan without ``D``, XLA's chunked form (Mamba-2's "SSD minimal"):
+    each chunk's rows from the chunk's own pairs (a ``chunk x chunk`` square
+    of decays a head) and the state at its start, the states from chunk to
+    chunk by a carry.  ``x`` (Bt, L, H, P), ``dt`` and ``l`` (Bt, L, H): the
+    step and the cumulative log decay inside each chunk; ``B``, ``C`` (Bt,
+    L, G, N).  Returns (y (Bt, L, H, P) float32, each chunk's starting state
+    (Bt, n, H, P, N) float32)."""
+    import jax
+    import jax.numpy as jnp
+    Bt, L, H, P = x.shape
+    G = B.shape[2]
+    n, Q = L // chunk, chunk
+    x, dt, l = (a.astype(jnp.float32).reshape((Bt, n, Q) + a.shape[2:])
+                for a in (x, dt, l))
+    Bh, Ch = (_ssd_heads(a.astype(jnp.float32), H // G).reshape(
+        Bt, n, Q, H, -1) for a in (B, C))
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    lt = jnp.moveaxis(l, 2, 3)                              # (Bt, n, H, Q)
+    decay = jnp.where(causal, jnp.exp(jnp.minimum(
+        lt[..., :, None] - lt[..., None, :], 0.0)), 0.0)    # (Bt, n, H, Q, Q)
+    scores = jnp.einsum("bcqhn,bcshn->bchqs", Ch, Bh) * decay \
+        * jnp.moveaxis(dt, 2, 3)[..., None, :]
+    y = jnp.einsum("bchqs,bcshp->bcqhp", scores, x)
+    last = l[:, :, -1]                                      # (Bt, n, H)
+    w = jnp.exp(last[:, :, None] - l) * dt                  # (Bt, n, Q, H)
+    inside = jnp.einsum("bcqh,bcqhp,bcqhn->bchpn", w, x, Bh)
+
+    def carry(state, chunk_in):
+        grown, into = chunk_in
+        return jnp.exp(grown)[..., None, None] * state + into, state
+
+    _, starts = jax.lax.scan(carry, jnp.zeros((Bt, H, P, Bh.shape[-1]),
+                                              jnp.float32),
+                             (jnp.moveaxis(last, 1, 0),
+                              jnp.moveaxis(inside, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)                     # (Bt, n, H, P, N)
+    y = y + jnp.exp(l)[..., None] * jnp.einsum("bcqhn,bchpn->bcqhp", Ch,
+                                               starts)
+    return y.reshape(Bt, L, H, P), starts
+
+
+def _ssd_kernels(shape, groups, state_size, chunk, interpret):
+    """The forward and backward kernels of ``ssd_scan`` for ``x`` of
+    ``shape`` (Bt, L, H, P), ``B`` and ``C`` of ``groups`` groups of
+    ``state_size``: ``(forward, backward)``, each a function of the kernels'
+    operands."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    Bt, L, H, P = shape
+    G, Q, N = groups, chunk, state_size
+    hg, n = H // G, L // Q
+    cdt = jnp.float32 if interpret else jnp.bfloat16
+
+    def cast(a):
+        return a.astype(cdt)
+
+    def head(ref, h):                     # (Q, hg * P) block: head h's rows
+        return ref[:, h * P:(h + 1) * P]
+
+    def column(ref, h):                   # (Q, hg) block: head h's (Q, 1)
+        block = ref[...]
+        lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+        return jnp.sum(jnp.where(lane == h, block, 0.0), axis=1,
+                       keepdims=True)
+
+    def squares(b_ref, c_ref):
+        Bq, Cq = cast(b_ref[...]), cast(c_ref[...])
+        row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+        return Bq, Cq, _nt(Cq, Bq), col <= row, row
+
+    def decays(lc, lr, causal):
+        return jnp.where(causal, jnp.exp(jnp.minimum(lc - lr, 0.0)), 0.0)
+
+    def fwd_kernel(x_ref, b_ref, c_ref, lc_ref, lr_ref, dc_ref, dr_ref,
+                   y_ref, s_ref, state):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            state[...] = jnp.zeros(state.shape, state.dtype)
+
+        Bq, Cq, CB, causal, _ = squares(b_ref, c_ref)
+        for h in range(hg):
+            lc, dtc = column(lc_ref, h), column(dc_ref, h)
+            lr, dtr = lr_ref[h:h + 1, :], dr_ref[h:h + 1, :]
+            xh, start = head(x_ref, h), state[h]
+            s_ref[h] = start
+            scores = CB * decays(lc, lr, causal) * dtr
+            y_ref[:, h * P:(h + 1) * P] = jnp.dot(
+                cast(scores), cast(xh), preferred_element_type=jnp.float32) \
+                + jnp.exp(lc) * _nt(Cq, cast(start))
+            last = lc[Q - 1:Q, :]
+            w = jnp.exp(last - lc) * dtc
+            state[h] = jnp.exp(last) * start + _tn(cast(xh * w), Bq)
+
+    def bwd_kernel(x_ref, b_ref, c_ref, lc_ref, lr_ref, dc_ref, dr_ref,
+                   dy_ref, s_ref, dx_ref, db_ref, dcm_ref, ddtc_ref, ddtr_ref,
+                   dlc_ref, dlr_ref, dstate):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            dstate[...] = jnp.zeros(dstate.shape, dstate.dtype)
+
+        Bq, Cq, CB, causal, row = squares(b_ref, c_ref)
+        Bm = b_ref[...]
+        d_cb = jnp.zeros((Q, Q), jnp.float32)
+        dB = jnp.zeros(Bm.shape, jnp.float32)
+        dC = jnp.zeros(Bm.shape, jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Q, hg), 1)
+        sub = jax.lax.broadcasted_iota(jnp.int32, (hg, Q), 0)
+        ddt_c = jnp.zeros((Q, hg), jnp.float32)
+        dl_c = jnp.zeros((Q, hg), jnp.float32)
+        ddt_r = jnp.zeros((hg, Q), jnp.float32)
+        dl_r = jnp.zeros((hg, Q), jnp.float32)
+        for h in range(hg):
+            lc, dtc = column(lc_ref, h), column(dc_ref, h)
+            lr, dtr = lr_ref[h:h + 1, :], dr_ref[h:h + 1, :]
+            xh, dyh = head(x_ref, h), head(dy_ref, h)
+            start, d_end = s_ref[h], dstate[h]
+            decay = decays(lc, lr, causal)
+            e, last = jnp.exp(lc), lc[Q - 1:Q, :]
+            grown = jnp.exp(last)
+            w = jnp.exp(last - lc) * dtc
+            # y = (CB * decay * dt_s) x + e * C start^T
+            scores = CB * decay * dtr
+            d_scores = _nt(cast(dyh), cast(xh))
+            d_cbd = d_scores * CB * decay
+            d_cb = d_cb + d_scores * decay * dtr
+            pulled = d_cbd * dtr
+            edy = e * dyh
+            from_start = _nt(Cq, cast(start))
+            # the end state: grown * start + (x * w)^T B
+            x_d_end = jnp.dot(cast(xh), cast(d_end),
+                              preferred_element_type=jnp.float32)
+            dw = jnp.sum(x_d_end * Bm, axis=1, keepdims=True)
+            dx_ref[:, h * P:(h + 1) * P] = _tn(cast(scores), cast(dyh)) \
+                + w * _nt(Bq, cast(d_end))
+            dB = dB + w * x_d_end
+            dC = dC + jnp.dot(cast(edy), cast(start),
+                              preferred_element_type=jnp.float32)
+            d_last = jnp.sum(d_end * grown * start) + jnp.sum(dw * w)
+            dl = jnp.sum(pulled, axis=1, keepdims=True) \
+                + jnp.sum(edy * from_start, axis=1, keepdims=True) - dw * w \
+                + jnp.where(row[:, :1] == Q - 1, d_last, 0.0)
+            ddt_c = jnp.where(lane == h, dw * jnp.exp(last - lc), ddt_c)
+            dl_c = jnp.where(lane == h, dl, dl_c)
+            ddt_r = jnp.where(sub == h, jnp.sum(d_cbd, axis=0,
+                                                keepdims=True), ddt_r)
+            dl_r = jnp.where(sub == h, -jnp.sum(pulled, axis=0,
+                                                keepdims=True), dl_r)
+            dstate[h] = grown * d_end + _tn(cast(edy), Cq)
+        db_ref[...] = dB + _tn(cast(d_cb), Cq)
+        dcm_ref[...] = dC + jnp.dot(cast(d_cb), Bq,
+                                    preferred_element_type=jnp.float32)
+        ddtc_ref[...], dlc_ref[...] = ddt_c, dl_c
+        ddtr_ref[...], dlr_ref[...] = ddt_r, dl_r
+
+    def specs(reverse):
+        at = (lambda c: n - 1 - c) if reverse else (lambda c: c)
+        spec = pl.BlockSpec
+        wide = spec((None, Q, hg * P), lambda b, g, c: (b, at(c), g))
+        group = spec((None, Q, N), lambda b, g, c: (b, at(c), g))
+        cols = spec((None, None, Q, hg), lambda b, g, c: (b, g, at(c), 0))
+        rows = spec((None, None, hg, Q), lambda b, g, c: (b, g, 0, at(c)))
+        states = spec((None, None, hg, P, N),
+                      lambda b, g, c: (b, at(c), g, 0, 0))
+        return wide, group, cols, rows, states
+
+    def call(kernel, name, in_specs, out_specs, out_shape, scratch):
+        return pl.pallas_call(
+            kernel, grid=(Bt, G, n), in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=bool(interpret), name=name)
+
+    def forward(x, B, C, lc, lr, dc, dr):
+        wide, group, cols, rows, states = specs(False)
+        f32 = jnp.float32
+        return call(fwd_kernel, "ssd_scan_fwd",
+                    [wide, group, group, cols, rows, cols, rows],
+                    [wide, states],
+                    [jax.ShapeDtypeStruct((Bt, L, H * P), f32),
+                     jax.ShapeDtypeStruct((Bt, n, H, P, N), f32)],
+                    [pltpu.VMEM((hg, P, N), f32)])(
+                        x, B, C, lc, lr, dc, dr)
+
+    def backward(x, B, C, lc, lr, dc, dr, dy, starts):
+        wide, group, cols, rows, states = specs(True)
+        f32 = jnp.float32
+        return call(bwd_kernel, "ssd_scan_bwd",
+                    [wide, group, group, cols, rows, cols, rows, wide, states],
+                    [wide, group, group, cols, rows, cols, rows],
+                    [jax.ShapeDtypeStruct((Bt, L, H * P), f32),
+                     jax.ShapeDtypeStruct((Bt, L, G * N), f32),
+                     jax.ShapeDtypeStruct((Bt, L, G * N), f32),
+                     jax.ShapeDtypeStruct((Bt, G, L, hg), f32),
+                     jax.ShapeDtypeStruct((Bt, G, hg, L), f32),
+                     jax.ShapeDtypeStruct((Bt, G, L, hg), f32),
+                     jax.ShapeDtypeStruct((Bt, G, hg, L), f32)],
+                    [pltpu.VMEM((hg, P, N), f32)])(
+                        x, B, C, lc, lr, dc, dr, dy, starts)
+
+    return forward, backward
+
+
+def ssd_scan(x, dt, A, B, C, D, chunk=SSD_CHUNK, interpret=None):
+    """Mamba-2's state-space recurrence over a sequence, a head at a time:
+    ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T`` (``P x N`` a head, ``S_{-1}
+    = 0``), ``y_t = S_t C_t + D x_t``.  ``x`` (Bt, L, H, P); ``dt`` (Bt, L,
+    H), the steps, positive; ``A`` and ``D`` (H,), ``A`` negative; ``B``,
+    ``C`` (Bt, L, G, N), head ``h`` reading group ``h // (H / G)``.  Returns
+    ``y`` (Bt, L, H, P) in ``x``'s dtype.
+
+    The sequence is cut into chunks of ``chunk`` rows (the last padded with
+    zero steps, which neither decay nor add).  Inside a chunk the decays are
+    float32 cumulative sums of ``dt A`` (``l``); a row takes the chunk's
+    own earlier rows through a ``chunk x chunk`` square of ``exp(l_t - l_s)
+    dt_s C_t . B_s`` and the state at the chunk's start through ``exp(l_t)
+    C_t``; the state goes on to the next chunk.
+
+    On a TPU (or where ``interpret`` is given), with a group's heads' ``P``
+    and ``N`` multiples of 128 and ``chunk`` of 128 (any shape where
+    interpreted): two kernels under the scope ``ssm.scan``, ``ssd_scan_fwd``
+    and ``ssd_scan_bwd``, each a grid step a (row of the batch, group,
+    chunk), the chunk axis walked in order forward and in reverse backward.
+    A step makes ``C B^T`` once for the group's heads, and each head's state
+    (``P x N`` float32) stays in VMEM from chunk to chunk; the forward
+    kernel writes ``y`` and each chunk's starting state, the backward kernel
+    carries the state's gradient back and gives the gradients of ``x``,
+    ``B``, ``C``, the steps and the decays, which XLA takes on to ``dt`` and
+    ``A``.  The products take bfloat16 operands and accumulate in float32,
+    what XLA's default precision gives float32 operands on the TPU; the
+    decays and the state are float32.  Elsewhere XLA's chunked form
+    (``_ssd_chunks_reference``): 2.8 times the kernels' time on a v5e at
+    8,192 rows of 64 heads (PERF.md, PR 41)."""
+    import jax
+    import jax.numpy as jnp
+
+    Bt, L, H, P = x.shape
+    G, N = B.shape[2:]
+    if H % G or C.shape != B.shape or dt.shape != (Bt, L, H):
+        raise ValueError("x %s, dt %s, B %s, C %s: heads in whole groups"
+                         % (x.shape, dt.shape, B.shape, C.shape))
+    f32 = jnp.float32
+    pad = -L % chunk
+    n, hg = (L + pad) // chunk, H // G
+    profiler.count("ssm.chunks", Bt * n)
+
+    def padded(a):
+        return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) \
+            if pad else a
+
+    xp, dtp, Bp, Cp = (padded(a) for a in (x, dt.astype(f32), B, C))
+    Lp = L + pad
+    l = jnp.cumsum((dtp * A.astype(f32)).reshape(Bt, n, chunk, H),
+                   axis=2).reshape(Bt, Lp, H)
+    if interpret is None and not (
+            jax.default_backend() == "tpu" and (hg * P) % 128 == 0
+            and N % 128 == 0 and chunk % 128 == 0):
+        with jax.named_scope("ssm.scan"):
+            y, _ = _ssd_chunks_reference(xp, dtp, l, Bp, Cp, chunk)
+            y = y[:, :L] + D.astype(f32)[:, None] * x.astype(f32)
+            return y.astype(x.dtype)
+    forward, backward = _ssd_kernels((Bt, Lp, H, P), G, N, chunk,
+                                     interpret)
+
+    def forms(a):
+        """(Bt, Lp, H) as a group's columns (Bt, G, Lp, hg) and rows (Bt,
+        G, hg, Lp)."""
+        a = a.reshape(Bt, Lp, G, hg)
+        return a.transpose(0, 2, 1, 3), a.transpose(0, 2, 3, 1)
+
+    def summed(columns, rows):
+        return (columns.transpose(0, 2, 1, 3)
+                + rows.transpose(0, 3, 1, 2)).reshape(Bt, Lp, H)
+
+    @jax.custom_vjp
+    def core(x_, dt_, l_, B_, C_):
+        return core_fwd(x_, dt_, l_, B_, C_)[0]
+
+    def core_fwd(x_, dt_, l_, B_, C_):
+        operands = (x_, B_, C_) + forms(l_) + forms(dt_)
+        y, starts = forward(*operands)
+        return y, operands + (starts,)
+
+    def core_bwd(kept, dy):
+        with jax.named_scope("ssm.scan"):
+            x_, B_, C_ = kept[:3]
+            dx, dB, dC, ddt_c, ddt_r, dl_c, dl_r = backward(
+                *kept[:-1], dy.astype(f32), kept[-1])
+            return (dx.astype(x_.dtype), summed(ddt_c, ddt_r),
+                    summed(dl_c, dl_r), dB.astype(B_.dtype),
+                    dC.astype(C_.dtype))
+
+    core.defvjp(core_fwd, core_bwd)
+    with jax.named_scope("ssm.scan"):
+        y = core(xp.reshape(Bt, Lp, H * P), dtp, l, Bp.reshape(Bt, Lp, G * N),
+                 Cp.reshape(Bt, Lp, G * N))
+        y = y.reshape(Bt, Lp, H, P)[:, :L] \
+            + D.astype(f32)[:, None] * x.astype(f32)
+        return y.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
 # the held experts' grouped products
 # ---------------------------------------------------------------------------
 SLOT_TILE_ROWS = 256        # slots to a tile: one expert's, one grid step
@@ -1190,6 +1508,11 @@ class _Experts:
     every expert with at least one): forward, the gradients of the slots'
     values, the weights' gradients, and the slots' values added back to
     rows.  ``gate_w``, ``up_w``: (held, f, d); ``down_w``: (held, d, f).
+    ``act``: what an expert computes, ``"swiglu"`` (``down(silu(gate x) *
+    up x)``) or ``"relu2"`` (``down(relu(up x)^2)``, no gate: one product
+    to the hidden units, whose gradient is ``dh * 2 relu(up x)``, and two
+    weight gradients); the methods take ``gate_w`` and ``dg`` as None for
+    the second.
 
     On a TPU (or where ``interpret`` is given), with ``d`` and ``f``
     multiples of 128 and ``tm`` of 16: five kernels, ``moe_experts_hidden``,
@@ -1210,11 +1533,15 @@ class _Experts:
     at the operands' own dtype: the kernels' oracle, and the path off the
     TPU."""
 
-    def __init__(self, tile_expert, tm, d, f, held, interpret=None):
+    def __init__(self, tile_expert, tm, d, f, held, interpret=None,
+                 act="swiglu"):
         import jax
         import jax.numpy as jnp
+        if act not in ("swiglu", "relu2"):
+            raise ValueError("an expert is swiglu or relu2, not %r" % (act,))
         self.te, self.tm, self.d, self.f, self.held = tile_expert, tm, d, f, \
             held
+        self.act = act
         self.n = tile_expert.shape[0]
         use = interpret is not None or jax.default_backend() == "tpu"
         self.kernels = bool(use and d % 128 == 0 and f % 128 == 0
@@ -1264,6 +1591,8 @@ class _Experts:
         import jax
         import jax.numpy as jnp
         S = x_s.shape[0]
+        if self.act == "relu2":
+            return self._relu2_hidden(x_s, w_s, up_w, keep)
         if not self.kernels:
             xt = self._tiles(x_s)
             g = jnp.einsum("ntd,nfd->ntf", xt, gate_w[self.te])
@@ -1289,6 +1618,34 @@ class _Experts:
             [rows(self.d), rows(1), in_w, in_w],
             [rows(self.f)] * len(out_shape), out_shape)(
                 self.te, x_s, w_s[:, None], gate_w, up_w)
+
+    def _relu2_hidden(self, x_s, w_s, up_w, keep):
+        """``hidden`` of relu² experts: ``relu(up x)^2 * w``, and with
+        ``keep`` also ``up x``, float32."""
+        import jax
+        import jax.numpy as jnp
+        S = x_s.shape[0]
+        if not self.kernels:
+            u = jnp.einsum("ntd,nfd->ntf", self._tiles(x_s), up_w[self.te])
+            hw = jnp.square(jax.nn.relu(u)) * self._tiles(w_s)
+            return tuple(a.reshape(S, -1) for a in ((hw, u) if keep
+                                                    else (hw,)))
+
+        def kernel(te_ref, x_ref, w_ref, wu_ref, hw_ref, *kept):
+            u = _nt(x_ref[...], wu_ref[...])
+            hw_ref[...] = (jnp.square(jnp.maximum(u, 0.0)) * w_ref[...]
+                           ).astype(hw_ref.dtype)
+            if kept:
+                kept[0][...] = u
+
+        rows, in_w, _ = self._specs()
+        out_shape = [jax.ShapeDtypeStruct((S, self.f), t) for t in (
+            (x_s.dtype, jnp.float32) if keep else (x_s.dtype,))]
+        return self._call(
+            kernel, "moe_experts_hidden", (self.n,),
+            [rows(self.d), rows(1), in_w],
+            [rows(self.f)] * len(out_shape), out_shape)(
+                self.te, x_s, w_s[:, None], up_w)
 
     def down(self, hw, down_w):
         """``y_s`` (S, d) float32: every slot's hidden units through its
@@ -1316,6 +1673,8 @@ class _Experts:
         import jax
         import jax.numpy as jnp
         S = dy_s.shape[0]
+        if self.act == "relu2":
+            return self._relu2_backward(dy_s, u, w_s, up_w, down_w)
         if not self.kernels:
             tiles = self._tiles
             dhw = jnp.einsum("ntd,ndf->ntf", tiles(dy_s), down_w[self.te])
@@ -1357,6 +1716,42 @@ class _Experts:
                  self.te, dy_s, g, u, w_s[:, None], gate_w, up_w, down_w)
         return dx, dw[:, 0], dg, du
 
+    def _relu2_backward(self, dy_s, u, w_s, up_w, down_w):
+        """``backward`` of relu² experts: (dx_s, dw_s, None, du)."""
+        import jax
+        import jax.numpy as jnp
+        S = dy_s.shape[0]
+        if not self.kernels:
+            tiles = self._tiles
+            dhw = jnp.einsum("ntd,ndf->ntf", tiles(dy_s), down_w[self.te])
+            r = jax.nn.relu(tiles(u))
+            dw = jnp.sum(dhw * r * r, axis=-1)
+            du = dhw * tiles(w_s) * 2 * r
+            dx = jnp.einsum("ntf,nfd->ntd", du, up_w[self.te])
+            return dx.reshape(S, -1), dw.reshape(S), None, du.reshape(S, -1)
+
+        def kernel(te_ref, dy_ref, u_ref, w_ref, wu_ref, wd_ref, dx_ref,
+                   dw_ref, du_ref):
+            r = jnp.maximum(u_ref[...], 0.0)
+            dhw = jnp.dot(dy_ref[...], wd_ref[...],
+                          preferred_element_type=jnp.float32)
+            dw_ref[...] = jnp.sum(dhw * r * r, axis=1, keepdims=True)
+            du = (dhw * w_ref[...] * 2 * r).astype(du_ref.dtype)
+            du_ref[...] = du
+            dx_ref[...] = jnp.dot(du, wu_ref[...],
+                                  preferred_element_type=jnp.float32)
+
+        rows, in_w, out_w = self._specs()
+        dx, dw, du = self._call(
+            kernel, "moe_experts_bwd", (self.n,),
+            [rows(self.d), rows(self.f), rows(1), in_w, out_w],
+            [rows(self.d), rows(1), rows(self.f)],
+            [jax.ShapeDtypeStruct((S, self.d), jnp.float32),
+             jax.ShapeDtypeStruct((S, 1), jnp.float32),
+             jax.ShapeDtypeStruct((S, self.f), dy_s.dtype)])(
+                 self.te, dy_s, u, w_s[:, None], up_w, down_w)
+        return dx, dw[:, 0], None, du
+
     # -- backward: the weights -----------------------------------------------
     def weight_gradients(self, x_s, dy_s, dg, du, hw):
         """(d gate_w, d up_w (held, f, d), d down_w (held, d, f)), float32:
@@ -1366,6 +1761,8 @@ class _Experts:
         import jax.numpy as jnp
         from jax.experimental import pallas as pl
         held, d, f, tm = self.held, self.d, self.f, self.tm
+        if self.act == "relu2":
+            return self._relu2_weight_gradients(x_s, dy_s, du, hw)
         if not self.kernels:
             tiles = self._tiles
             xt, dyt = tiles(x_s), tiles(dy_s)
@@ -1401,6 +1798,44 @@ class _Experts:
              jax.ShapeDtypeStruct((held, f, d), jnp.float32),
              jax.ShapeDtypeStruct((held, d, f), jnp.float32)])(
                  self.te, x_s, dy_s, dg, du, hw)
+
+    def _relu2_weight_gradients(self, x_s, dy_s, du, hw):
+        """``weight_gradients`` of relu² experts: (d up_w, d down_w)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental import pallas as pl
+        held, d, f, tm = self.held, self.d, self.f, self.tm
+        if not self.kernels:
+            tiles = self._tiles
+            by_expert = lambda a: jax.ops.segment_sum(a, self.te, held)
+            return (by_expert(jnp.einsum("ntf,ntd->nfd", tiles(du),
+                                         tiles(x_s))),
+                    by_expert(jnp.einsum("ntd,ntf->ndf", tiles(dy_s),
+                                         tiles(hw))))
+        fb = max(b for b in range(128, min(f, _WGRAD_WIDTH) + 1, 128)
+                 if f % b == 0)
+
+        def kernel(te_ref, x_ref, dy_ref, du_ref, hw_ref, dwu_ref, dwd_ref):
+            i = pl.program_id(1)
+
+            @pl.when((i == 0) | (te_ref[i] != te_ref[jnp.maximum(i - 1, 0)]))
+            def _():
+                for ref in (dwu_ref, dwd_ref):
+                    ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+            dwu_ref[...] += _tn(du_ref[...], x_ref[...])
+            dwd_ref[...] += _tn(dy_ref[...], hw_ref[...])
+
+        wide = pl.BlockSpec((tm, d), lambda j, i, te: (i, 0))
+        block = pl.BlockSpec((tm, fb), lambda j, i, te: (i, j))
+        in_w = pl.BlockSpec((None, fb, d), lambda j, i, te: (te[i], j, 0))
+        out_w = pl.BlockSpec((None, d, fb), lambda j, i, te: (te[i], 0, j))
+        return self._call(
+            kernel, "moe_experts_wgrad", (f // fb, self.n),
+            [wide, wide, block, block], [in_w, out_w],
+            [jax.ShapeDtypeStruct((held, f, d), jnp.float32),
+             jax.ShapeDtypeStruct((held, d, f), jnp.float32)])(
+                 self.te, x_s, dy_s, du, hw)
 
     # -- the slots back to rows ----------------------------------------------
     def combine_runs(self, rows):

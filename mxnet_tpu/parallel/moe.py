@@ -184,11 +184,12 @@ def make_expert_parallel_moe(mesh, expert_fn, axis_name="ep", k=2,
 # the dropless layer for the experts held here
 # ---------------------------------------------------------------------------
 
-def route_tokens(x, router_w, k, scoring="softmax", scale=1.0, bias=None):
+def route_tokens(x, router_w, k, scoring="softmax", scale=1.0, bias=None,
+                 eps=SIGMOID_NORM_EPS):
     """The router of ``moe_held_apply``: (weights, expert ids), each (T, k),
     of tokens ``x`` (T, d) over all the experts of ``router_w`` (E, d), from
-    float32 logits at precision 'highest'; ``scoring``, ``scale`` and
-    ``bias`` as there."""
+    float32 logits at precision 'highest'; ``scoring``, ``scale``, ``bias``
+    and ``eps`` as there."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(x.astype(jnp.float32), router_w.T.astype(jnp.float32),
@@ -200,8 +201,7 @@ def route_tokens(x, router_w, k, scoring="softmax", scale=1.0, bias=None):
                          % (scoring,))
     scores = jax.nn.sigmoid(logits)
     return route_top_k(scores, k, None if bias is None
-                       else scores + bias.astype(jnp.float32),
-                       SIGMOID_NORM_EPS, scale)
+                       else scores + bias.astype(jnp.float32), eps, scale)
 
 
 def slot_table(experts, first_expert, held, tm):
@@ -296,7 +296,7 @@ def from_slots(experts, a_s, slot_of, here):
 
 
 def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
-                  tile_expert, here, tm):
+                  tile_expert, here, tm, act="swiglu"):
     """``sum_j weights[t, j] expert_j(x[t])`` over each row's pairs that
     ``here`` marks, through the slot table of ``slot_table``: rows gathered
     to slots, the grouped products of ``ops.pallas_ops._Experts`` over whole
@@ -305,14 +305,15 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
     Rows go to slots by gathers by ``source``, and a value a slot or a pair
     moves the other way by a sort on ``source`` or ``slot_of`` (the
     permutation and its inverse): the backward pass is written out, since
-    the transpose of a gather is a scatter-add."""
+    the transpose of a gather is a scatter-add.  ``act`` as
+    ``ops.pallas_ops._Experts`` takes it (``gate_w`` None for relu²)."""
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
     from ..ops.pallas_ops import SLOT_TABLE, _Experts
 
     (T, d), k = x.shape, weights.shape[1]
-    held, f, _ = gate_w.shape
+    held, f, _ = up_w.shape
     pairs, entries = T * k, source.shape[0]
     slots = tile_expert.shape[0] * tm
 
@@ -327,12 +328,15 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
 
     def forward(keep, x, weights, gate_w, up_w, down_w, source, slot_of,
                 tile_expert, here):
-        experts = _Experts(tile_expert, tm, d, f, held)
+        experts = _Experts(tile_expert, tm, d, f, held, act=act)
         row_of_slot, w_s, x_s = to_slots(experts, x, weights, source,
                                          slot_of, here)
         with jax.named_scope("moe.experts"):
-            matrices = tuple(experts.cast(w) for w in (gate_w, up_w, down_w))
+            matrices = tuple(None if w is None else experts.cast(w)
+                             for w in (gate_w, up_w, down_w))
             hw, *kept = experts.hidden(x_s, w_s, *matrices[:2], keep=keep)
+            if gate_w is None and kept:         # relu² keeps no gate
+                kept = [None] + kept
             y_s = experts.down(hw, matrices[2])
         return from_slots(experts, y_s, slot_of, here).astype(x.dtype), (
             row_of_slot, w_s, x_s, hw, *kept, *matrices, source, slot_of,
@@ -345,19 +349,22 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
     def layer_bwd(kept, dout):
         row_of_slot, w_s, x_s, hw, g, u, gate_w, up_w, down_w, source, \
             slot_of, tile_expert, here = kept
-        experts = _Experts(tile_expert, tm, d, f, held)
+        experts = _Experts(tile_expert, tm, d, f, held, act=act)
         with jax.named_scope("moe.sort"):
             dy_s = _rows_of(experts.cast(dout), row_of_slot)
         with jax.named_scope("moe.experts"):
             dx_s, dw_s, dg, du = experts.backward(dy_s, g, u, w_s, gate_w,
                                                   up_w, down_w)
             d_matrices = experts.weight_gradients(x_s, dy_s, dg, du, hw)
+            if gate_w is None:
+                d_matrices = (None,) + d_matrices
         dx = from_slots(experts, dx_s, slot_of, here).astype(dout.dtype)
         with jax.named_scope("moe.sort"):
             dw_entry = _moved(source, jnp.pad(dw_s, (0, entries - slots)))
             d_weights = jnp.where(here, dw_entry[:pairs].reshape(T, k), 0.0)
         return (dx, d_weights.astype(dout.dtype),
-                *(m.astype(dout.dtype) for m in d_matrices),
+                *(None if m is None else m.astype(dout.dtype)
+                  for m in d_matrices),
                 None, None, None, None)
 
     layer.defvjp(functools.partial(forward, True), layer_bwd)
@@ -366,21 +373,23 @@ def _held_experts(x, weights, gate_w, up_w, down_w, source, slot_of,
 
 
 def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
-                   scoring="softmax", scale=1.0, bias=None):
+                   scoring="softmax", scale=1.0, bias=None, act="swiglu",
+                   eps=SIGMOID_NORM_EPS):
     """The held experts' part of a mixture-of-experts layer, no token
     dropped.
 
     x: (T, d) tokens.  router_w: (E, d), the router over ALL experts.
     gate_w, up_w: (E_held, f, d); down_w: (E_held, d, f): the matrices of
     experts ``first_expert .. first_expert + E_held - 1``, which live here.
-    Expert e computes ``down(silu(gate y) * up y)``.
+    ``act`` is what an expert computes: "swiglu", ``down(silu(gate y) * up
+    y)``, or "relu2", ``down(relu(up y)^2)`` with ``gate_w`` None.
 
     The router's form is the model's: ``scoring`` "softmax" (probabilities
     over all E, the ``k`` largest, renormalised) or "sigmoid" (each logit's
     sigmoid; the ``k`` largest of ``score + bias`` where a ``bias`` (E,) is
-    given, weighted by the scores without it over their sum +
-    ``SIGMOID_NORM_EPS``); either times ``scale``.  The bias enters the
-    selection alone, so no gradient reaches it.
+    given, weighted by the scores without it over their sum + ``eps``,
+    ``SIGMOID_NORM_EPS`` unless given); either times ``scale``.  The bias
+    enters the selection alone, so no gradient reaches it.
 
     A row's picked experts are computed, not every held one.  The (row,
     pick) pairs are sorted by expert into a table of ``T min(k, E_held)``
@@ -415,13 +424,22 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
     from ..ops.pallas_ops import SLOT_TABLE, _Experts, slot_tile_rows
 
     T, d = x.shape
-    E, held = router_w.shape[0], gate_w.shape[0]
+    E, held = router_w.shape[0], up_w.shape[0]
     tm = slot_tile_rows(T * min(k, held), held)
+    f = up_w.shape[1]
+    if f % 128 and jax.default_backend() == "tpu":
+        # the kernels take whole lanes of hidden units: the padded ones have
+        # zero weights, give 0 for either act and get a gradient of 0
+        with jax.named_scope("moe.experts"):
+            gate_w, up_w = (None if w is None else jnp.pad(
+                w, ((0, 0), (0, -f % 128), (0, 0))) for w in (gate_w, up_w))
+            down_w = jnp.pad(down_w, ((0, 0), (0, 0), (0, -f % 128)))
     profiler.count("moe.experts_held", held)
     profiler.count("moe.rows", T)
 
     with jax.named_scope("moe.route"):
-        weights, experts = route_tokens(x, router_w, k, scoring, scale, bias)
+        weights, experts = route_tokens(x, router_w, k, scoring, scale, bias,
+                                        eps)
     with jax.named_scope("moe.sort"):
         source, slot_of, tile_expert, here, per_expert = slot_table(
             experts, first_expert, held, tm)
@@ -433,6 +451,6 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
                           jnp.max(per_expert)]).astype(jnp.float32)
     profiler.count("moe.slots", tile_expert.shape[0] * tm)
     profiler.count("moe.combine_runs", _Experts(
-        tile_expert, tm, d, gate_w.shape[1], held).combine_runs(T))
+        tile_expert, tm, d, up_w.shape[1], held).combine_runs(T))
     return _held_experts(x, weights.astype(x.dtype), gate_w, up_w, down_w,
-                         source, slot_of, tile_expert, here, tm), load
+                         source, slot_of, tile_expert, here, tm, act), load

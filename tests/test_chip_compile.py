@@ -284,6 +284,69 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
     _fits(compiled)
 
 
+def test_relu2_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """The seventh cell's held experts: 8 of 128 relu² experts of 1,856 x
+    2,688, 6 picked per token, over 8,192 rows, forward and backward,
+    recomputed as its layers are.  The width is padded to 1,920 (whole
+    lanes; the padded units have zero weights) and the same four kernels run
+    with two matrices: 49,152 slots and a tile of 256 an expert (200 tiles),
+    blocks of 640 hidden units for the weights' gradients."""
+    from mxnet_tpu.ops.pallas_ops import ATTENTION_RESIDUALS
+    from mxnet_tpu.parallel.moe import moe_held_apply
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *ATTENTION_RESIDUALS)
+
+    def loss(x, router_w, up_w, down_w, bias):
+        out, load = jax.checkpoint(lambda *a: moe_held_apply(
+            a[0], a[1], None, a[2], a[3], 6, scoring="sigmoid", scale=2.5,
+            bias=a[4], act="relu2", eps=1e-20), policy=policy)(
+            x, router_w, up_w, down_w, bias)
+        return jnp.sum(out) + load[1]
+
+    step = jax.value_and_grad(loss, (0, 1, 2, 3))
+    shapes = (s(8192, 2688), s(128, 2688), s(8, 1856, 2688),
+              s(8, 2688, 1856), s(128))
+    compiled = jax.jit(step).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert set(re.findall(r"%(moe_\w+?)(?:\.\d+)? = ", text)) == {
+        "moe_experts_hidden", "moe_experts_down", "moe_experts_bwd",
+        "moe_experts_wgrad"}
+    assert _pallas_grids(jax.make_jaxpr(step)(*shapes).jaxpr) == {
+        "moe_experts_hidden": (200,), "moe_experts_down": (200,),
+        "moe_experts_bwd": (200,), "moe_experts_wgrad": (3, 200),
+        "slot_combine": (69, 8)}
+    _fits(compiled)
+
+
+def test_state_space_scan_compiles_for_v5e(one_chip):
+    """The seventh cell's Mamba-2 scan: 64 heads of 64 in 8 groups of a
+    state of 128 over 8,192 rows, chunks of 128, float32 in, forward and
+    backward: a grid step a (row of the batch, group, chunk), each head's
+    64 x 128 state resident in VMEM from chunk to chunk, the chunk axis
+    walked in order and in reverse."""
+    from mxnet_tpu.ops.pallas_ops import ssd_scan
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    shapes = (s(1, 8192, 64, 64), s(1, 8192, 64), s(64), s(1, 8192, 8, 128),
+              s(1, 8192, 8, 128), s(64))
+    step = jax.value_and_grad(lambda *a: jnp.sum(ssd_scan(
+        *a, interpret=False)), tuple(range(6)))
+    compiled = jax.jit(step).lower(*shapes).compile()
+    assert set(re.findall(r"%(ssd_scan_\w+?)(?:\.\d+)? = ",
+                          compiled.as_text())) == {
+        "ssd_scan_fwd", "ssd_scan_bwd"}
+    assert _pallas_grids(jax.make_jaxpr(step)(*shapes).jaxpr) == {
+        "ssd_scan_fwd": (1, 8, 64), "ssd_scan_bwd": (1, 8, 64)}
+    _fits(compiled)
+
+
 def test_short_conv_decoder_s_blocks_compile_for_v5e(one_chip):
     """The causal kernels at head size 64 with 4 query heads a key/value
     head (32 to 8 over 2 sequences of 8,192 rows, float32 in, tiles of

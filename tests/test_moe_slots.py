@@ -260,8 +260,8 @@ def test_layer_through_the_interpreted_kernels(monkeypatch):
     with XLA's gather-and-sum, which only the order of float32 additions
     tells apart."""
     real = pallas_ops._Experts
-    monkeypatch.setattr(pallas_ops, "_Experts", lambda *a: real(
-        *a, interpret=True))
+    monkeypatch.setattr(pallas_ops, "_Experts", lambda *a, **kw: real(
+        *a, interpret=True, **kw))
     monkeypatch.setattr(pallas_ops, "SLOT_TILE_ROWS", 32)
     rows, total, held, first, k = 128, 8, 4, 2, 2
     rng = np.random.RandomState(7)
