@@ -8,6 +8,10 @@ of its rules for the length of a ``with`` block:
 * ``no_gate``: the gate ``silu(z)`` left out of the norm;
 * ``one_group``: the gated norm's RMS over one group instead of ``n_groups``;
 * ``conv_ahead``: a convolution whose row ``t`` reads row ``t + 1``;
+* ``rotary``: rotary applied to the attention's queries and keys anyway, at
+  the configuration's ``rope_theta`` over the whole head;
+* ``qk_norm``: an RMS norm (weight 1) over each head of the queries and keys
+  applied anyway;
 * ``relu``: the experts' relu in place of relu²;
 * ``no_scale``: the routed weights' factor (2.5) left out;
 * ``drop_expert``: the output of the held expert that most pairs land on
@@ -18,8 +22,9 @@ of its rules for the length of a ``with`` block:
 import contextlib
 from unittest import mock
 
-FAULTS = ("no_carry", "no_skip", "no_gate", "one_group", "conv_ahead", "relu",
-          "no_scale", "drop_expert", "no_shared", "half_rows")
+FAULTS = ("no_carry", "no_skip", "no_gate", "one_group", "conv_ahead",
+          "rotary", "qk_norm", "relu", "no_scale", "drop_expert", "no_shared",
+          "half_rows")
 
 
 @contextlib.contextmanager
@@ -27,6 +32,7 @@ def planted(fault):
     import jax
     import jax.numpy as jnp
     from benchmark.reference import nemotron_h as family
+    from benchmark.reference.mellum_moe import rms_norm, rotary
     sound_loss, sound_lands = family.loss, family.lands_here
 
     def half_rows(config, ops, params, aux, batch):
@@ -34,6 +40,14 @@ def planted(fault):
         keep = jnp.arange(weight.shape[1]) < weight.shape[1] // 2
         return sound_loss(config, ops, params, aux,
                           (tokens, targets, weight * keep))
+
+    def rotated(s, q, k):
+        rope = {"rope_theta": s["rope_theta"]}
+        return tuple(rotary(t, jnp.arange(t.shape[2]), rope) for t in (q, k))
+
+    def normed(s, q, k):
+        return tuple(rms_norm(t, jnp.ones(t.shape[-1], t.dtype), s["eps"])
+                     for t in (q, k))
 
     def dropped(local, held):
         """The held expert that most pairs land on, left out: at the cell's
@@ -50,6 +64,8 @@ def planted(fault):
         "no_gate": ("gate_of", jnp.ones_like),
         "one_group": ("norm_groups", lambda groups: 1),
         "conv_ahead": ("conv_front", lambda taps: taps - 2),
+        "rotary": ("query_key", rotated),
+        "qk_norm": ("query_key", normed),
         "relu": ("activation", jax.nn.relu),
         "no_scale": ("routed_scale", lambda scale: 1.0),
         "drop_expert": ("lands_here", dropped),

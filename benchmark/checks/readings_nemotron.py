@@ -13,7 +13,8 @@ step is left out and only the blocks' numbers are read; ``variant``s
 (``control_bf16``, ``witness_default_precision``, ``fault_<name>``) name the
 ones to read in the program's place, where not all are wanted.  Beside them
 what the program's expert blocks recorded of the newest step (each layer's
-pairs routed here and largest load) and the peak of device memory.  One JSON
+pairs routed here and largest load), the pairs the probe's picks send to each
+held expert, and the peak of device memory.  One JSON
 line per seed, also appended to ``chiprun_out/readings_<cell>.jsonl``.
 """
 import time
@@ -99,6 +100,10 @@ def main(argv):
             return {k: v[0] for k, v in {**held, **observed}.items()}
 
         layers = compare.program_probe(cell, *inputs)
+        first = cell.config.get("deployment", {}).get("first_expert", 0)
+        line["probe_pairs"] = [
+            int((layers["picks"] == first + e).sum())
+            for e in range(cell.config["n_routed_experts"])]
         line["program"] = both({"layers": layers} if layers_only
                                else dict(program, layers=layers))
         line["memory_peak_bytes"] = harness.memory_peak(devices)

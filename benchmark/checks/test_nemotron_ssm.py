@@ -105,7 +105,8 @@ def test_control_in_bfloat16_fails(cell):
 @pytest.mark.parametrize("fault,by", [
     ("no_carry", "ssm_rows_gap"), ("no_skip", "ssm_rows_gap"),
     ("no_gate", "ssm_rows_gap"), ("one_group", "ssm_rows_gap"),
-    ("conv_ahead", "ssm_rows_gap"), ("relu", "expert_grad_gap"),
+    ("conv_ahead", "ssm_rows_gap"), ("rotary", "attn_rows_gap"),
+    ("qk_norm", "attn_rows_gap"), ("relu", "expert_grad_gap"),
     ("no_scale", "expert_grad_gap"), ("drop_expert", "expert_grad_gap"),
     ("no_shared", "expert_grad_gap"), ("half_rows", "grad_norm_gap")])
 def test_reference_fault_is_not_correct(cell, fault, by):

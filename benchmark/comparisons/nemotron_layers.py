@@ -28,15 +28,19 @@ Two parts, all of whose numbers are held to a limit:
   and the convolution's taps and bias as a depthwise convolution of 4 taps
   is initialised (uniform on +-1/2), where Xavier's fan over 6,144 channels
   makes them 16 times smaller and the scan's rows a sliver of the skip's.
-  The routed layer's selection bias is drawn at the spread of its router's
-  scores over these rows.
+  The routed layer's selection bias is drawn at a tenth of the spread of
+  its router's scores over these rows (``BIAS_SPREAD``): enough to move the
+  picks, where the whole spread sends most rows to the few experts drawn
+  highest and leaves held experts with no pair (5 of the 8, at the cell's
+  size, on one seed), whose faults no number can then read.
 
   ``ssm_rows_gap``: the Mamba-2 block's output and its input's gradient, cut
   into groups of ``ROWS`` rows; the worst group's ``|program - reference| /
   |reference|``.  A state not carried, the skip or the gate left out, the
   norm over one group, or a convolution that sees the next row is an error
   of every group past the first chunk (or of every group).
-  ``attn_rows_gap``: the same of the attention block.
+  ``attn_rows_gap``: the same of the attention block, which applies no
+  rotary and no norm of q or k: either applied anyway reads here.
   ``expert_grad_gap``: each held expert's gradient (its two matrices
   together), the router's and the shared expert's (its two together); the
   worst one's ``|program - reference| / max(|reference|, the median
@@ -59,6 +63,7 @@ from benchmark.comparisons.lfm2_layers import _rows_gap
 STAGE = (0, 1, 5)   # published layers: a Mamba-2, a routed and attention
 SSM, MOE, ATTN = "layer0_", "layer1_", "layer2_"
 TAPS_BOUND = 0.5    # a depthwise convolution of 4 taps: 1 / sqrt(fan-in)
+BIAS_SPREAD = 0.1   # the selection bias, over the spread of the scores
 
 
 def stage_config(cell):
@@ -104,7 +109,8 @@ def probe_inputs(cell, seed, tokens):
     logits = jnp.einsum("bld,ed->ble", x, weights[MOE + "moe_router_weight"],
                         precision="highest")
     bias = weights[MOE + "moe_expert_bias"]
-    weights[MOE + "moe_expert_bias"] = jnp.std(jax.nn.sigmoid(logits)) \
+    weights[MOE + "moe_expert_bias"] = BIAS_SPREAD \
+        * jnp.std(jax.nn.sigmoid(logits)) \
         * jax.random.normal(keys[7], bias.shape, bias.dtype)
     return weights, x, [jax.random.normal(k, x.shape, x.dtype)
                         for k in keys[:3]]

@@ -83,9 +83,9 @@ not pick it 0), each layer recomputed in the backward pass
 (``jax.checkpoint``).  tests/test_hybrid_ssm_lm.py holds the two equal.
 
 ``carry_of``, ``skip_of``, ``gate_of``, ``norm_groups``, ``conv_front``,
-``activation``, ``routed_scale``, ``lands_here`` and ``shared_of`` are the
-rules a check replaces to plant a fault (benchmark/checks/faults_nemotron.py);
-nothing here reads a switch.
+``query_key``, ``activation``, ``routed_scale``, ``lands_here`` and
+``shared_of`` are the rules a check replaces to plant a fault
+(benchmark/checks/faults_nemotron.py); nothing here reads a switch.
 
 Nothing here imports the program under test.
 """
@@ -133,6 +133,12 @@ def conv_front(taps):
     return taps - 1
 
 
+def query_key(s, q, k):
+    """The queries and keys the scores are made of, from their projections
+    ``(B, heads, L, head_dim)``: as they are, with no rotary and no norm."""
+    return q, k
+
+
 def activation(u):
     """An expert's, on its up projection."""
     return jnp.square(jax.nn.relu(u))
@@ -172,6 +178,8 @@ def _sizes(config):
         "kv_heads": config["num_key_value_heads"],
         "head_dim": config.get("head_dim") or config["hidden_size"] // heads,
         "kinds": kinds, "eps": config["layer_norm_epsilon"],
+        # no equation reads it: a planted fault's rotary does
+        "rope_theta": float(config.get("rope_theta", 10000)),
         "ssm_heads": config["mamba_num_heads"],
         "ssm_head_dim": config["mamba_head_dim"],
         "state": config["ssm_state_size"], "groups": config["n_groups"],
@@ -371,8 +379,8 @@ def attention(s, ops, params, prefix, x, looped):
         t = ops.einsum("bld,ed->ble", x, params[prefix + name + "_weight"])
         return t.reshape(B, L, count, hd).transpose(0, 2, 1, 3)
 
-    q = project("attn_q", heads).reshape(B, kv, heads // kv, L, hd)
-    k, v = project("attn_k", kv), project("attn_v", kv)
+    q, k = query_key(s, project("attn_q", heads), project("attn_k", kv))
+    q, v = q.reshape(B, kv, heads // kv, L, hd), project("attn_v", kv)
     attend = _attend_looped if looped else _attend_by_shapes
     out = attend(s, ops, q, k, v, chunk, None)
     out = out.reshape(B, heads, L, hd).transpose(0, 2, 1, 3)

@@ -1501,6 +1501,18 @@ def _bf16_pieces(a):
     return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
 
 
+def _wgrad_block(f):
+    """Hidden units to a step of ``moe_experts_wgrad``: the largest multiple
+    of 128 up to ``_WGRAD_WIDTH`` that divides ``f``; where none does, the
+    fewest blocks of up to ``_WGRAD_WIDTH`` that cover it, in whole lanes
+    (640 of 1,856: the last 576), or ``f`` whole where one block holds it."""
+    if f % 128 == 0:
+        return max(b for b in range(128, min(f, _WGRAD_WIDTH) + 1, 128)
+                   if f % b == 0)
+    per_block = -(-f // -(-f // _WGRAD_WIDTH))
+    return min(-(-per_block // 128) * 128, f)
+
+
 class _Experts:
     """The grouped products of a held experts' layer over a table of
     ``S`` slots in tiles of ``tm``, every tile one expert's
@@ -1514,16 +1526,19 @@ class _Experts:
     weight gradients); the methods take ``gate_w`` and ``dg`` as None for
     the second.
 
-    On a TPU (or where ``interpret`` is given), with ``d`` and ``f``
-    multiples of 128 and ``tm`` of 16: five kernels, ``moe_experts_hidden``,
-    ``moe_experts_down``, ``moe_experts_bwd`` (a grid step a tile, the tile's
-    expert by scalar prefetch, that expert's matrices whole in VMEM and
+    On a TPU (or where ``interpret`` is given), with ``d`` a multiple of
+    128, ``f`` at least 128 and ``tm`` a multiple of 16: five kernels,
+    ``moe_experts_hidden``, ``moe_experts_down``, ``moe_experts_bwd`` (a
+    grid step a tile, the tile's expert by scalar prefetch, that expert's
+    matrices whole in VMEM, ``f`` as it is, and
     fetched again only when the expert changes; the down projection is a
     call of its own so that a recomputed forward pass, which needs the
     hidden units and not the output, drops it), ``moe_experts_wgrad`` (a
     grid step a tile and block of at most ``_WGRAD_WIDTH`` hidden units, an
-    expert's gradient blocks resident over its consecutive tiles) and
-    ``slot_combine`` (``combine``: each row's slots added up, a grid step a
+    expert's gradient blocks resident over its consecutive tiles; where
+    ``f`` is no multiple of 128 the last block is ragged, its units past
+    ``f`` read as anything and written nowhere) and ``slot_combine``
+    (``combine``: each row's slots added up, a grid step a
     tile of rows and a held expert; not ``moe_``-named, since its time is
     no product's).  The number of grid steps
     follows from the shapes alone.  The products take bfloat16 operands
@@ -1544,7 +1559,7 @@ class _Experts:
         self.act = act
         self.n = tile_expert.shape[0]
         use = interpret is not None or jax.default_backend() == "tpu"
-        self.kernels = bool(use and d % 128 == 0 and f % 128 == 0
+        self.kernels = bool(use and d % 128 == 0 and f >= 128
                             and tm % 16 == 0)
         self.interpret = bool(interpret)
         # what the matrix units are fed
@@ -1770,8 +1785,7 @@ class _Experts:
             return (by_expert(jnp.einsum("ntf,ntd->nfd", tiles(dg), xt)),
                     by_expert(jnp.einsum("ntf,ntd->nfd", tiles(du), xt)),
                     by_expert(jnp.einsum("ntd,ntf->ndf", dyt, tiles(hw))))
-        fb = max(b for b in range(128, min(f, _WGRAD_WIDTH) + 1, 128)
-                 if f % b == 0)
+        fb = _wgrad_block(f)
 
         def kernel(te_ref, x_ref, dy_ref, dg_ref, du_ref, hw_ref, dwg_ref,
                    dwu_ref, dwd_ref):
@@ -1792,7 +1806,7 @@ class _Experts:
         in_w = pl.BlockSpec((None, fb, d), lambda j, i, te: (te[i], j, 0))
         out_w = pl.BlockSpec((None, d, fb), lambda j, i, te: (te[i], 0, j))
         return self._call(
-            kernel, "moe_experts_wgrad", (f // fb, self.n),
+            kernel, "moe_experts_wgrad", (-(-f // fb), self.n),
             [wide, wide, block, block, block], [in_w, in_w, out_w],
             [jax.ShapeDtypeStruct((held, f, d), jnp.float32),
              jax.ShapeDtypeStruct((held, f, d), jnp.float32),
@@ -1812,8 +1826,7 @@ class _Experts:
                                          tiles(x_s))),
                     by_expert(jnp.einsum("ntd,ntf->ndf", tiles(dy_s),
                                          tiles(hw))))
-        fb = max(b for b in range(128, min(f, _WGRAD_WIDTH) + 1, 128)
-                 if f % b == 0)
+        fb = _wgrad_block(f)
 
         def kernel(te_ref, x_ref, dy_ref, du_ref, hw_ref, dwu_ref, dwd_ref):
             i = pl.program_id(1)
@@ -1831,7 +1844,7 @@ class _Experts:
         in_w = pl.BlockSpec((None, fb, d), lambda j, i, te: (te[i], j, 0))
         out_w = pl.BlockSpec((None, d, fb), lambda j, i, te: (te[i], 0, j))
         return self._call(
-            kernel, "moe_experts_wgrad", (f // fb, self.n),
+            kernel, "moe_experts_wgrad", (-(-f // fb), self.n),
             [wide, wide, block, block], [in_w, out_w],
             [jax.ShapeDtypeStruct((held, f, d), jnp.float32),
              jax.ShapeDtypeStruct((held, d, f), jnp.float32)])(

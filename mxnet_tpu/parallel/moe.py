@@ -426,14 +426,6 @@ def moe_held_apply(x, router_w, gate_w, up_w, down_w, k, first_expert=0,
     T, d = x.shape
     E, held = router_w.shape[0], up_w.shape[0]
     tm = slot_tile_rows(T * min(k, held), held)
-    f = up_w.shape[1]
-    if f % 128 and jax.default_backend() == "tpu":
-        # the kernels take whole lanes of hidden units: the padded ones have
-        # zero weights, give 0 for either act and get a gradient of 0
-        with jax.named_scope("moe.experts"):
-            gate_w, up_w = (None if w is None else jnp.pad(
-                w, ((0, 0), (0, -f % 128), (0, 0))) for w in (gate_w, up_w))
-            down_w = jnp.pad(down_w, ((0, 0), (0, 0), (0, -f % 128)))
     profiler.count("moe.experts_held", held)
     profiler.count("moe.rows", T)
 

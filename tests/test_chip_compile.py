@@ -287,10 +287,10 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch, rows,
 def test_relu2_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
     """The seventh cell's held experts: 8 of 128 relu² experts of 1,856 x
     2,688, 6 picked per token, over 8,192 rows, forward and backward,
-    recomputed as its layers are.  The width is padded to 1,920 (whole
-    lanes; the padded units have zero weights) and the same four kernels run
-    with two matrices: 49,152 slots and a tile of 256 an expert (200 tiles),
-    blocks of 640 hidden units for the weights' gradients."""
+    recomputed as its layers are.  The same four kernels run with two
+    matrices at the width as it is, an expert's matrices whole: 49,152 slots
+    and a tile of 256 an expert (200 tiles), blocks of 640 hidden units for
+    the weights' gradients, the last ragged (576)."""
     from mxnet_tpu.ops.pallas_ops import ATTENTION_RESIDUALS
     from mxnet_tpu.parallel.moe import moe_held_apply
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

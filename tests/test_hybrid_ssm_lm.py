@@ -299,11 +299,15 @@ def test_relu2_experts_are_a_dense_loop_over_the_experts():
         assert _max_gap(g, w) < 1e-5
 
 
-def test_relu2_kernels_match_their_xla_form():
+@pytest.mark.parametrize("f", [128, 1000])
+def test_relu2_kernels_match_their_xla_form(f):
     """The relu² forms of the grouped products, interpreted, against the
-    same products in XLA over one table of 4 experts' tiles of 16 slots."""
+    same products in XLA over one table of 4 experts' tiles of 16 slots; at
+    1,000 hidden units, no multiple of 128, the weights' gradients go in
+    blocks of 512, the last ragged (488 units), and no unit past the width
+    reaches a result."""
     rng = np.random.RandomState(4)
-    d, f, held, tm = 128, 128, 4, 16
+    d, held, tm = 128, 4, 16
     te = jnp.asarray([0, 0, 1, 2, 3, 3], jnp.int32)
     S = te.shape[0] * tm
     x_s, dy_s = (jnp.asarray(rng.normal(0, 1, (S, d)), jnp.float32)
