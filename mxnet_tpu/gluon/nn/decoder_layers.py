@@ -408,7 +408,11 @@ class Mamba2Mixer(HybridBlock):
     projection gives ``[z, xBC, dt]`` of widths ``heads * head_dim``,
     ``heads * head_dim + 2 * groups * state`` and ``heads``; ``xBC =
     silu(conv(xBC) + conv_bias)``, a causal depthwise convolution of
-    ``taps`` taps (``_contrib_ssm_conv``); the state-space scan of ``x``
+    ``taps`` taps (``_contrib_ssm_conv``, handed the projection's whole
+    output and where ``xBC`` begins in it: on a TPU its kernel pair reads
+    ``xBC``'s channels where they lie, and XLA's form, which runs elsewhere
+    and at widths that are no multiple of 128, slices them first); the
+    state-space scan of ``x``
     (``heads`` heads of ``head_dim``) under ``B`` and ``C`` (``groups``
     groups of ``state``) with steps ``softplus(dt + dt_bias)``, decay ``A =
     -exp(A_log)`` and skip ``D`` a head, in chunks of ``chunk`` rows
@@ -449,10 +453,9 @@ class Mamba2Mixer(HybridBlock):
         with jax.named_scope("ssm.proj"):
             streams = self.in_proj(x)
             z = F.slice_axis(streams, axis=-1, begin=0, end=inner)
-            xbc = F.slice_axis(streams, axis=-1, begin=inner,
-                               end=inner + conv)
             dt = F.slice_axis(streams, axis=-1, begin=inner + conv, end=None)
-        xbc = F._contrib_ssm_conv(xbc, conv_weight, conv_bias)
+        xbc = F._contrib_ssm_conv(streams, conv_weight, conv_bias,
+                                  begin=inner)
         y = F._contrib_ssd_scan(xbc, dt, dt_bias, A_log, D, **self._scan)
         y = F._contrib_gated_rms_norm(y, z, norm_gamma, **self._norm)
         with jax.named_scope("ssm.proj"):

@@ -257,22 +257,19 @@ def _gated_short_conv(attrs, streams, taps):
 @register("_contrib_ssm_conv", no_jit=True, shape_rule="input",
           dtype_rule="input")
 def _ssm_conv(attrs, x, weight, bias):
-    """A Mamba-2 mixer's convolution: ``silu(conv(x) + bias)``, ``conv`` a
-    causal depthwise convolution along L of ``K`` taps, one filter a
-    channel: ``x`` (B, L, c); ``weight`` (c, K); ``bias`` (c,).  Row ``t``
-    reads rows ``t - K + 1 .. t`` of its own sequence, zeros before the
-    first (``weight[:, K - 1]`` multiplies row ``t``).  XLA's form, ``K``
-    shifted copies in one elementwise pass, under the scope ``ssm.conv``."""
-    import jax
-    import jax.numpy as jnp
-    K, length = weight.shape[1], x.shape[1]
-    with jax.named_scope("ssm.conv"):
-        padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-        w = weight.astype(x.dtype)
-        out = padded[:, :length] * w[:, 0]
-        for j in range(1, K):
-            out = out + padded[:, j:j + length] * w[:, j]
-        return jax.nn.silu(out + bias.astype(x.dtype))
+    """A Mamba-2 mixer's convolution (``pallas_ops.ssm_conv``):
+    ``silu(conv(x) + bias)``, ``conv`` a causal depthwise convolution along L
+    of ``K`` taps, one filter a channel: ``x`` (B, L, c); ``weight`` (c, K);
+    ``bias`` (c,).  Row ``t`` reads rows ``t - K + 1 .. t`` of its own
+    sequence, zeros before the first (``weight[:, K - 1]`` multiplies row
+    ``t``).  With the attr ``begin``, ``x`` is wider and its channels
+    ``begin .. begin + c`` are convolved: the mixer hands over its input
+    projection's whole output.  On a TPU, where ``c`` and ``begin`` are
+    multiples of 128 and ``L`` of the kernels' tile, the kernel pair
+    ``ssm_conv_fwd`` / ``ssm_conv_bwd``; elsewhere XLA's form, ``K`` shifted
+    copies in one elementwise pass.  Either under the scope ``ssm.conv``."""
+    from .pallas_ops import ssm_conv
+    return ssm_conv(x, weight, bias, begin=int(attrs.get("begin", 0)))
 
 
 @register("_contrib_ssd_scan", no_jit=True, shape_rule="input",

@@ -1127,6 +1127,278 @@ def gated_short_conv(streams, taps, interpret=None, rows=SHORT_CONV_ROWS):
 
 
 # ---------------------------------------------------------------------------
+# the Mamba-2 mixer's convolution
+# ---------------------------------------------------------------------------
+SSM_CONV_ROWS = 256         # rows of the sequence to a grid step
+_SSM_CONV_BLOCK = 2048      # the most channels to a grid step
+_SSM_CONV_LANES = 512       # channels to a pass inside a step, forward
+_SSM_CONV_BWD_LANES = 256   # and backward
+_SSM_CONV_UNROLL = 2        # groups of 8 rows to an iteration
+
+
+def _ssm_conv_reference(x, weight, bias):
+    """``silu(conv(x) + bias)`` in XLA, ``K`` shifted copies in one
+    elementwise pass: the kernels' oracle, and the path off the TPU."""
+    import jax
+    import jax.numpy as jnp
+    K, length = weight.shape[1], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    w = weight.astype(x.dtype)
+    out = padded[:, :length] * w[:, 0]
+    for j in range(1, K):
+        out = out + padded[:, j:j + length] * w[:, j]
+    return jax.nn.silu(out + bias.astype(x.dtype))
+
+
+def ssm_conv(x, weight, bias, begin=0, interpret=None, rows=SSM_CONV_ROWS):
+    """A Mamba-2 mixer's convolution, ``silu(conv(x) + bias)`` over the
+    channels ``begin .. begin + c`` of ``x`` (B, L, width): ``conv`` a
+    causal depthwise convolution along L of ``K`` taps, ``weight`` (c, K),
+    ``bias`` (c,).  Row ``t`` reads rows ``t - K + 1 .. t`` of its own
+    sequence, zeros before the first (``weight[:, K - 1]`` multiplies row
+    ``t``).  Output (B, L, c); the gradient of ``x`` is 0 outside those
+    channels.  Nothing for a matrix unit: the bound is memory's, ``x`` read
+    and the output written forward, the output's gradient and ``x`` read and
+    ``x``'s gradient written backward.
+
+    On a TPU (or where ``interpret`` is given), with ``c`` and ``begin``
+    multiples of 128, ``L`` of ``rows`` and ``K - 1`` at most 8: two
+    kernels, ``ssm_conv_fwd`` and ``ssm_conv_bwd``, a grid step a (batch
+    row, tile of ``rows`` rows, block of up to 2,048 channels), each block
+    read out of ``x`` where it lies, so that the caller hands over a
+    projection's whole output and no copy of the slice is made.  A tile
+    takes the ``K - 1`` rows it needs of its neighbour from an 8-row block
+    of the same array and moves rows inside the tile on the rotate unit.
+    The backward kernel recomputes the pre-activation (and the next tile's
+    first 8 rows of it), writes ``x``'s gradient once and a tile's share of
+    the taps' and the bias's gradients, which XLA then sums.  At the seventh
+    cell's shape (8,192 rows, 6,144 channels, float32) on a v5e a call took
+    0.66 ms forward and 1.0 backward, 75% of the memory's bound, against
+    1.46 and 3.40 for XLA's form (PERF.md §6).  Elsewhere XLA's form
+    (``_ssm_conv_reference`` on the slice).  The grid steps of a forward
+    call are counted as ``ssm.conv_tiles``, 0 for XLA's form."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    B, L, width = x.shape
+    c, K = weight.shape
+    if begin < 0 or begin + c > width or bias.shape != (c,):
+        raise ValueError("channels %d .. %d of %d, a bias of %s"
+                         % (begin, begin + c, width, bias.shape))
+    rows = min(rows, L)
+    use = interpret is not None or jax.default_backend() == "tpu"
+    if not use or c % 128 or begin % 128 or L % rows or rows % _HALO \
+            or rows < 2 * _HALO or K - 1 > _HALO:
+        profiler.count("ssm.conv_tiles", 0)
+        with jax.named_scope("ssm.conv"):
+            if begin or c != width:
+                x = jax.lax.slice_in_dim(x, begin, begin + c, axis=2)
+            return _ssm_conv_reference(x, weight, bias)
+    from jax.experimental.pallas import tpu as pltpu
+    block = _SSM_CONV_BLOCK
+    while c % block or begin % block:
+        block //= 2
+    n, m, first = L // rows, c // block, begin // block
+    per_tile = rows // _HALO
+    profiler.count("ssm.conv_tiles", B * n * m)
+    # the taps a row each, along the lanes of their channels, the bias
+    # beneath them: (8 or 16, c)
+    param_rows = -(-(K + 1) // _HALO) * _HALO
+    f32 = jnp.float32
+
+    def tile(at):
+        return pl.BlockSpec((None, rows, block),
+                            lambda b, i, j: (b, i, at + j))
+
+    def before(at):         # the 8 rows before tile i (tile 0: unused)
+        return pl.BlockSpec((None, _HALO, block), lambda b, i, j: (
+            b, jnp.maximum(i * per_tile - 1, 0), at + j))
+
+    def after(at):          # the 8 rows after tile i (the last: unused)
+        return pl.BlockSpec((None, _HALO, block), lambda b, i, j: (
+            b, jnp.minimum((i + 1) * per_tile, n * per_tile - 1), at + j))
+
+    params_spec = pl.BlockSpec((param_rows, block), lambda b, i, j: (0, j))
+    groups = rows // _HALO
+
+    def silu_grad(u):
+        sig = jax.nn.sigmoid(u)
+        return sig * (1.0 + u * (1.0 - sig))
+
+    # A step walks its tile 8 rows at a time, in passes over ``lanes``
+    # channels, with the rows before (or after) in registers: a row moved
+    # ``s`` on is one rotate of each of two 8-row groups and a select.  Both
+    # walks are loops, so that a kernel's program holds one pass and
+    # ``_SSM_CONV_UNROLL`` groups of it.
+    def passes(lanes, body):
+        """``body(cols, lanes)`` for each pass over the block."""
+        lanes = min(lanes, block)       # both powers of 2 times 128
+
+        def one(j, carry):
+            body(pl.ds(pl.multiple_of(j * lanes, lanes), lanes), lanes)
+            return carry
+
+        jax.lax.fori_loop(0, block // lanes, one, 0)
+
+    def walk(count, group, carry):
+        """``carry = group(k, carry)`` for ``k = 0 .. count - 1``,
+        ``_SSM_CONV_UNROLL`` groups to an iteration of the loop."""
+        u = _SSM_CONV_UNROLL
+
+        def groups_of(j, carry):
+            for r in range(u):
+                carry = group(j * u + r, carry)
+            return carry
+
+        carry = jax.lax.fori_loop(0, count // u, groups_of, carry)
+        for k in range(count - count % u, count):
+            carry = group(k, carry)
+        return carry
+
+    def at(k):
+        start = k * _HALO
+        return pl.ds(start if isinstance(start, int)
+                     else pl.multiple_of(start, _HALO), _HALO)
+
+    def taps_of(p_ref, cols, lanes):
+        """The taps, the bias and the masks of the rows ``< s`` and ``>= 8 -
+        s``, each (8, lanes), for a pass over ``cols``."""
+        shape = (_HALO, lanes)
+        p = p_ref[:, cols].astype(f32)
+        w = [jnp.broadcast_to(p[j:j + 1], shape) for j in range(K + 1)]
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        return w[:K], w[K], [row < s for s in range(K)], [
+            row >= _HALO - s for s in range(K)]
+
+    def moved_on(before, rows_, masks):
+        """``[rows_ moved s on, s = 0 .. K - 1]``, the first ``s`` rows the
+        last of ``before``."""
+        return [rows_] + [jnp.where(masks[s], pltpu.roll(before, s, 0),
+                                    pltpu.roll(rows_, s, 0))
+                          for s in range(1, K)]
+
+    def pre_activation(w, bias, moved):
+        u = bias + w[K - 1] * moved[0]
+        for s in range(1, K):
+            u = u + w[K - 1 - s] * moved[s]
+        return u
+
+    def fwd_kernel(x_ref, xb_ref, p_ref, o_ref):
+        first_tile = pl.program_id(1) == 0
+
+        def one_pass(cols, lanes):
+            w, bias, masks, _ = taps_of(p_ref, cols, lanes)
+
+            def group(k, before):
+                x = x_ref[at(k), cols].astype(f32)
+                u = pre_activation(w, bias, moved_on(before, x, masks))
+                o_ref[at(k), cols] = (u * jax.nn.sigmoid(u)).astype(
+                    o_ref.dtype)
+                return x
+
+            walk(groups, group,
+                 jnp.where(first_tile, 0.0, xb_ref[:, cols].astype(f32)))
+
+        passes(_SSM_CONV_LANES, one_pass)
+
+    def bwd_kernel(g_ref, ga_ref, x_ref, xb_ref, xa_ref, p_ref, dx_ref,
+                   dp_ref):
+        i = pl.program_id(1)
+        dp_ref[...] = jnp.zeros(dp_ref.shape, dp_ref.dtype)
+
+        def one_pass(cols, lanes):
+            w, bias, masks, late = taps_of(p_ref, cols, lanes)
+
+            def du_of(before, x, g):
+                moved = moved_on(before, x, masks)
+                return g * silu_grad(pre_activation(w, bias, moved)), moved
+
+            def summed(sums, du, moved):
+                """The taps' and the bias's gradients by row of a group."""
+                return [sums[s] + du * moved[s] for s in range(K)] \
+                    + [sums[K] + du]
+
+            def group(k, carry):
+                """``x``'s gradient of group ``k``: row ``t`` collects
+                ``du[t + s]``, those past the group from the next, which the
+                last group takes from the next tile's first rows."""
+                x, du, sums = carry
+                last = k == groups - 1
+                ahead = at(jnp.minimum(k + 1, groups - 1))
+                x_next = jnp.where(last, xa_ref[:, cols],
+                                   x_ref[ahead, cols]).astype(f32)
+                g_next = jnp.where(
+                    last, jnp.where(i == n - 1, 0.0, ga_ref[:, cols]),
+                    g_ref[ahead, cols]).astype(f32)
+                du_next, moved = du_of(x, x_next, g_next)
+                dx = w[K - 1] * du
+                for s in range(1, K):
+                    dx = dx + w[K - 1 - s] * jnp.where(
+                        late[s], pltpu.roll(du_next, _HALO - s, 0),
+                        pltpu.roll(du, _HALO - s, 0))
+                dx_ref[at(k), cols] = dx.astype(dx_ref.dtype)
+                return x_next, du_next, summed(
+                    sums, jnp.where(last, 0.0, du_next), moved)
+
+            x0 = x_ref[at(0), cols].astype(f32)
+            du0, moved = du_of(
+                jnp.where(i == 0, 0.0, xb_ref[:, cols].astype(f32)), x0,
+                g_ref[at(0), cols].astype(f32))
+            _, _, sums = walk(groups, group, (x0, du0, summed(
+                [jnp.zeros_like(x0)] * (K + 1), du0, moved)))
+            for s in range(K):
+                dp_ref[K - 1 - s:K - s, cols] = jnp.sum(sums[s], axis=0,
+                                                        keepdims=True)
+            dp_ref[K:K + 1, cols] = jnp.sum(sums[K], axis=0, keepdims=True)
+
+        passes(_SSM_CONV_BWD_LANES, one_pass)
+
+    def call(kernel, name, in_specs, out_specs, out_shape):
+        return pl.pallas_call(
+            kernel, grid=(B, n, m), in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel"),
+                vmem_limit_bytes=64 * 2 ** 20),
+            interpret=bool(interpret), name=name)
+
+    @jax.custom_vjp
+    def f(x_, params_):
+        return f_fwd(x_, params_)[0]
+
+    def f_fwd(x_, params_):
+        out = call(fwd_kernel, "ssm_conv_fwd",
+                   [tile(first), before(first), params_spec], tile(0),
+                   jax.ShapeDtypeStruct((B, L, c), x_.dtype))(
+                       x_, x_, params_)
+        return out, (x_, params_)
+
+    def f_bwd(res, g):
+        x_, params_ = res
+        with jax.named_scope("ssm.conv"):
+            dx, dp = call(
+                bwd_kernel, "ssm_conv_bwd",
+                [tile(0), after(0), tile(first), before(first), after(first),
+                 params_spec],
+                [tile(0), pl.BlockSpec((None, None, param_rows, block),
+                                       lambda b, i, j: (b, i, 0, j))],
+                [jax.ShapeDtypeStruct((B, L, c), x_.dtype),
+                 jax.ShapeDtypeStruct((B, n, param_rows, c), f32)])(
+                     g, g, x_, x_, x_, params_)
+            if c != width:
+                dx = jnp.pad(dx, ((0, 0), (0, 0),
+                                  (begin, width - begin - c)))
+        return dx, jnp.sum(dp, axis=(0, 1)).astype(params_.dtype)
+
+    f.defvjp(f_fwd, f_bwd)
+    with jax.named_scope("ssm.conv"):
+        params = jnp.concatenate(
+            [weight.T.astype(x.dtype), bias[None].astype(x.dtype),
+             jnp.zeros((param_rows - K - 1, c), x.dtype)], axis=0)
+        return f(x, params)
+
+
+# ---------------------------------------------------------------------------
 # the state-space scan (Mamba-2's structured state-space duality)
 # ---------------------------------------------------------------------------
 SSD_CHUNK = 128             # rows of the sequence a chunk of the scan holds

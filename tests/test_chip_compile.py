@@ -347,6 +347,47 @@ def test_state_space_scan_compiles_for_v5e(one_chip):
     _fits(compiled)
 
 
+def test_mamba2_mixer_compiles_for_v5e(one_chip, monkeypatch):
+    """The seventh cell's Mamba-2 mixer, a block of 2,688 over one sequence
+    of 8,192 rows, float32, forward and backward, recomputed as its layers
+    are: the convolution runs ``ssm_conv_fwd`` (twice: the recomputed pass)
+    and ``ssm_conv_bwd``, a grid step a tile of 256 rows and a block of
+    2,048 of the 6,144 channels, each block read out of the input
+    projection's whole output (no copy of the slice); the scan its own two
+    kernels."""
+    from mxnet_tpu.gluon.block import functional_call
+    from mxnet_tpu.gluon.nn.decoder_layers import Mamba2Mixer
+    from mxnet_tpu.ops.pallas_ops import ATTENTION_RESIDUALS
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    net = Mamba2Mixer(2688, 64, 64, 128, 8, taps=4, chunk=128,
+                      prefix="ssm_")
+    shapes = {p.name: jax.ShapeDtypeStruct(p.shape, jnp.float32,
+                                           sharding=one_chip)
+              for p in net.collect_params().values()}
+    x = jax.ShapeDtypeStruct((1, 8192, 2688), jnp.float32, sharding=one_chip)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *ATTENTION_RESIDUALS)
+
+    def loss(values, x):
+        out = jax.checkpoint(lambda v, x: functional_call(
+            net, v, x, training=True)[0][0], policy=policy)(values, x)
+        return jnp.sum(out * out)
+
+    step = jax.value_and_grad(loss, (0, 1))
+    compiled = jax.jit(step).lower(shapes, x).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert sorted(calls) == ["ssd_scan_bwd", "ssd_scan_fwd", "ssd_scan_fwd",
+                             "ssm_conv_bwd", "ssm_conv_fwd", "ssm_conv_fwd"]
+    assert len(re.findall(r"%ssm_conv_fwd(?:\.\d+)? = [^\n]*"
+                          r"operand_layout_constraints=\{f32\[1,8192,10304\]",
+                          text)) == 2
+    grids = _pallas_grids(jax.make_jaxpr(step)(shapes, x).jaxpr)
+    assert grids["ssm_conv_fwd"] == grids["ssm_conv_bwd"] == (1, 32, 3)
+    _fits(compiled)
+
+
 def test_short_conv_decoder_s_blocks_compile_for_v5e(one_chip):
     """The causal kernels at head size 64 with 4 query heads a key/value
     head (32 to 8 over 2 sequences of 8,192 rows, float32 in, tiles of

@@ -4,9 +4,9 @@ attention without rotary or query/key norm), at small sizes on the CPU,
 against the benchmark's plain reference (benchmark/reference/nemotron_h.py:
 float32, ``highest``, nothing of the program); the state-space scan, in XLA
 and in its kernels interpreted, against the sequential recurrence across
-chunk boundaries; the relu² experts' kernels, interpreted, against their XLA
-form; and the programs of what was there before: the SiLU-gated experts' and
-the sixth cell's stack."""
+chunk boundaries; the convolution's kernels and the relu² experts' kernels,
+interpreted, against their XLA forms; and the programs of what was there
+before: the SiLU-gated experts' and the sixth cell's stack."""
 import hashlib
 import json
 import math
@@ -223,6 +223,97 @@ def test_scan_counts_its_chunks_and_runs_two_kernels():
     with pytest.raises(ValueError, match="whole groups"):
         pallas_ops.ssd_scan(operands[0][:, :, :3], *operands[1:3],
                             *operands[3:])
+
+
+# -- the convolution's kernels ------------------------------------------------
+
+def _conv_operands(batch, rows, width, channels, taps=4, seed=0):
+    r = np.random.RandomState(seed)
+    return [jnp.asarray(a, jnp.float32) for a in (
+        r.normal(size=(batch, rows, width)), r.normal(size=(channels, taps)),
+        r.normal(size=channels))]
+
+
+def _conv_tiles():
+    return profiler.totals()["ssm.conv_tiles"]["max"]
+
+
+@pytest.mark.parametrize("batch,rows,channels,tile,begin,width", [
+    (2, 64, 256, 16, 0, 256), (1, 48, 128, 24, 0, 128),
+    (2, 32, 128, 16, 128, 384), (1, 64, 256, 32, 256, 520)])
+def test_ssm_conv_kernels_match_xla(batch, rows, channels, tile, begin,
+                                    width):
+    """The two kernels, interpreted, over several tiles of rows (a tile's
+    first rows read the last of the tile before it, and its gradient the
+    first of the tile after), against XLA's shifted copies: the output and
+    the gradients of ``x``, the taps and the bias; handed a wider array, the
+    channels from ``begin`` are convolved and the others' gradient is 0."""
+    x, w, b = _conv_operands(batch, rows, width, channels)
+    cot = jax.random.normal(jax.random.PRNGKey(2), (batch, rows, channels))
+
+    def kernels(x, w, b):
+        out = pallas_ops.ssm_conv(x, w, b, begin=begin, interpret=True,
+                                  rows=tile)
+        return jnp.sum(out * cot), out
+
+    def oracle(x, w, b):
+        out = pallas_ops._ssm_conv_reference(
+            x[..., begin:begin + channels], w, b)
+        return jnp.sum(out * cot), out
+
+    profiler.reset_spans()
+    got, out = jax.grad(kernels, (0, 1, 2), has_aux=True)(x, w, b)
+    assert _conv_tiles() == batch * (rows // tile)
+    want, out_w = jax.grad(oracle, (0, 1, 2), has_aux=True)(x, w, b)
+    assert _max_gap(out, out_w) < 1e-5
+    for a, c in zip(got, want):
+        assert a.shape == c.shape and _max_gap(a, c) < 1e-5
+    outside = np.ones(width, bool)
+    outside[begin:begin + channels] = False
+    assert not np.any(np.asarray(got[0])[..., outside])
+    assert _pallas_names(jax.make_jaxpr(jax.grad(
+        lambda *a: kernels(*a)[0], (0, 1, 2)))(x, w, b).jaxpr) == {
+        "ssm_conv_fwd", "ssm_conv_bwd"}
+
+
+def test_ssm_conv_kernels_see_no_row_ahead_and_zeros_before():
+    """A changed row moves no output before it, in its tile or the one
+    before; each sequence's first rows see zeros before them, not the
+    previous sequence's last rows."""
+    x, w, b = _conv_operands(2, 48, 128, 128)
+
+    def conv(x):
+        return pallas_ops.ssm_conv(x, w, b, interpret=True, rows=16)
+    out = conv(x)
+    for row in (16, 21, 47):
+        moved = conv(x.at[:, row].add(1.0))
+        assert _max_gap(moved[:, :row], out[:, :row]) == 0
+        assert float(jnp.min(jnp.max(jnp.abs(
+            moved[:, row] - out[:, row]), axis=-1))) > 0
+    for t in range(3):
+        u = b + sum(w[:, 3 - s] * x[:, t - s] for s in range(t + 1))
+        assert _max_gap(out[:, t], jax.nn.silu(u)) < 1e-5
+
+
+@pytest.mark.parametrize("rows,channels,taps,tile,begin,width", [
+    (48, 96, 4, 16, 0, 96),         # channels no multiple of 128
+    (40, 128, 4, 16, 0, 128),       # rows no multiple of the tile
+    (48, 128, 10, 16, 0, 128),      # 9 rows before a row: past the halo
+    (48, 128, 4, 16, 64, 256)])     # channels beginning inside a block
+def test_ssm_conv_falls_back_to_xla_where_kernels_cannot_run(
+        rows, channels, taps, tile, begin, width):
+    x, w, b = _conv_operands(2, rows, width, channels, taps)
+    profiler.reset_spans()
+    step = jax.grad(lambda *a: jnp.sum(pallas_ops.ssm_conv(
+        *a, begin=begin, interpret=True, rows=tile)), (0, 1, 2))
+    assert _pallas_names(jax.make_jaxpr(step)(x, w, b).jaxpr) == set()
+    assert _conv_tiles() == 0
+    got = pallas_ops.ssm_conv(x, w, b, begin=begin, interpret=True,
+                              rows=tile)
+    assert _max_gap(got, pallas_ops._ssm_conv_reference(
+        x[..., begin:begin + channels], w, b)) == 0
+    with pytest.raises(ValueError, match="channels"):
+        pallas_ops.ssm_conv(x, w, b, begin=width - channels + 1)
 
 
 # -- the blocks against the reference's ---------------------------------------
@@ -530,6 +621,10 @@ def test_rooflines_count_by_hand():
     shared = _reader("shared_expert_roofline.train")
     assert shared.required_flops(config, {"seq_len": 8192}) \
         == 6 * 8192 * 2 * 2688 * 3712 * 4
+    conv = _reader("ssm_conv_roofline.train")
+    assert conv.required_bytes(config, {"batch": 1, "seq_len": 8192}) \
+        == 5 * 8192 * (64 * 64 + 2 * 8 * 128) * 4 == 1006632960
+    assert conv.PASSES == 5 and scan.ssm_layers(config) == 4
 
     # a model without these layers, or no table to read: silent
     class Cell:
@@ -538,10 +633,10 @@ def test_rooflines_count_by_hand():
         traffic = {"seq_len": 8192, "batch": 2}
     run = {"cell": Cell, "peaks": {"flops_per_s": 197e12,
                                    "hbm_bytes_per_s": 819e9}, "trace": None}
-    for reader in (scan, relu2, shared, _reader("ssm_layer_ms.train")):
+    for reader in (scan, relu2, shared, conv, _reader("ssm_layer_ms.train")):
         assert reader.read(dict(run)) is None
     Cell.config = config
-    for reader in (scan, relu2, shared):
+    for reader in (scan, relu2, shared, conv):
         assert reader.read(dict(run)) is None
 
 
@@ -566,6 +661,7 @@ def test_compiled_step_trains_and_recomputes():
         for layer in net.layers)
     totals = profiler.totals()
     assert totals["ssm.chunks"]["max"] == BATCH * L // 8
+    assert totals["ssm.conv_tiles"]["max"] == 0       # XLA's form here
     assert totals["moe.experts_held"]["max"] == 4
 
 
