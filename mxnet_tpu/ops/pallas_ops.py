@@ -1465,109 +1465,180 @@ def _ssd_kernels(shape, groups, state_size, chunk, interpret):
     Bt, L, H, P = shape
     G, Q, N = groups, chunk, state_size
     hg, n = H // G, L // Q
+    # a tile: the lanes of T heads, at most a vector register's 128 (or one
+    # head's P)
+    T = max(t for t in range(1, hg + 1)
+            if hg % t == 0 and t * P <= max(P, 128))
+    TW = T * P
     cdt = jnp.float32 if interpret else jnp.bfloat16
 
     def cast(a):
         return a.astype(cdt)
 
-    def head(ref, h):                     # (Q, hg * P) block: head h's rows
-        return ref[:, h * P:(h + 1) * P]
+    def split(a):
+        """``a`` (float32) as two arrays for the MXU whose products with a
+        matrix of 0s and 1s add up to ``a``'s to 16 bits: its rounding and
+        what that leaves."""
+        if cdt == jnp.float32:
+            return (a,)
+        hi = cast(a)
+        return hi, cast(a - hi.astype(jnp.float32))
 
-    def column(ref, h):                   # (Q, hg) block: head h's (Q, 1)
-        block = ref[...]
-        lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-        return jnp.sum(jnp.where(lane == h, block, 0.0), axis=1,
-                       keepdims=True)
+    def summed(a, h=None):
+        """By the MXU: the sums of each head's P lanes of a (Q, hg * P)
+        block, or the sums of a (Q, Q) square's rows in column h: (Q,
+        hg)."""
+        k = a.shape[1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (k, hg), 0)
+        head = jax.lax.broadcasted_iota(jnp.int32, (k, hg), 1)
+        ones = (head == h if h is not None else lane // P == head).astype(cdt)
+        return sum(jnp.dot(p, ones, preferred_element_type=jnp.float32)
+                   for p in split(a))
+
+    def causal():
+        row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        return jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1) <= row
 
     def squares(b_ref, c_ref):
+        """The chunk's B and C for the MXU, and C B^T with the pairs past
+        the diagonal 0, so that a head's decays need no mask of their
+        own."""
         Bq, Cq = cast(b_ref[...]), cast(c_ref[...])
-        row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-        return Bq, Cq, _nt(Cq, Bq), col <= row, row
+        return Bq, Cq, jnp.where(causal(), _nt(Cq, Bq), 0.0)
 
-    def decays(lc, lr, causal):
-        return jnp.where(causal, jnp.exp(jnp.minimum(lc - lr, 0.0)), 0.0)
+    def head_rows(lr_ref, dr_ref):
+        """The group's (hg, Q) rows: l, exp(l), the weight exp(l_last - l)
+        dt of a step in the chunk's end state, and exp(l_last) along N."""
+        lr = lr_ref[...]
+        last = lr[:, Q - 1:Q]
+        return (lr, jnp.exp(lr), jnp.exp(last - lr) * dr_ref[...],
+                jnp.exp(last) * jnp.ones((hg, N), jnp.float32))
+
+    def columns(block, j):
+        """Tile j's heads' rows of an (hg, Q) ``block`` down the lanes: (Q,
+        TW), head i's row along its P lanes, by a transpose (no sum across
+        lanes)."""
+        sub = jax.lax.broadcasted_iota(jnp.int32, (TW, Q), 0) // P
+        spread = jnp.broadcast_to(block[j * T:j * T + 1], (TW, Q))
+        for i in range(1, T):
+            spread = jnp.where(sub == i, block[j * T + i:j * T + i + 1],
+                               spread)
+        return jnp.transpose(spread)
+
+    def decays(lr, h):
+        """Head h's exp(l_t - l_s) (Q, Q); 1 past the diagonal."""
+        lc = jnp.transpose(jnp.broadcast_to(lr[h:h + 1], (Q, Q)))
+        return jnp.exp(jnp.minimum(lc - lr[h:h + 1], 0.0))
+
+    def grow(ref, grown, added):
+        """The group's states (hg * P, N) in ``ref``: head h's rows times
+        ``grown[h]`` plus ``added``'s."""
+        for h in range(hg):
+            at = slice(h * P, (h + 1) * P)
+            ref[at, :] = grown[h:h + 1] * ref[at, :] + added[at]
 
     def fwd_kernel(x_ref, b_ref, c_ref, lc_ref, lr_ref, dc_ref, dr_ref,
-                   y_ref, s_ref, state):
+                   y_ref, s_ref, state, xw):
         @pl.when(pl.program_id(2) == 0)
         def _():
             state[...] = jnp.zeros(state.shape, state.dtype)
 
-        Bq, Cq, CB, causal, _ = squares(b_ref, c_ref)
-        for h in range(hg):
-            lc, dtc = column(lc_ref, h), column(dc_ref, h)
-            lr, dtr = lr_ref[h:h + 1, :], dr_ref[h:h + 1, :]
-            xh, start = head(x_ref, h), state[h]
-            s_ref[h] = start
-            scores = CB * decays(lc, lr, causal) * dtr
-            y_ref[:, h * P:(h + 1) * P] = jnp.dot(
-                cast(scores), cast(xh), preferred_element_type=jnp.float32) \
-                + jnp.exp(lc) * _nt(Cq, cast(start))
-            last = lc[Q - 1:Q, :]
-            w = jnp.exp(last - lc) * dtc
-            state[h] = jnp.exp(last) * start + _tn(cast(xh * w), Bq)
+        Bq, Cq, CB = squares(b_ref, c_ref)
+        lr, e, w, grown = head_rows(lr_ref, dr_ref)
+        dtr = dr_ref[...]
+        start = state[...]
+        s_ref[...] = start.reshape(hg, P, N)
+        from_start = _nt(Cq, cast(start))         # C start^T, every head
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Q, TW), 1) // P
+        for j in range(hg // T):
+            tile = slice(j * TW, (j + 1) * TW)
+            x = x_ref[:, tile]
+            y = columns(e, j) * from_start[:, tile]
+            for i in range(T):
+                h = j * T + i
+                scores = CB * decays(lr, h) * dtr[h:h + 1]
+                y = jnp.where(lane == i, y + jnp.dot(
+                    cast(scores), cast(x),
+                    preferred_element_type=jnp.float32), y)
+            y_ref[:, tile] = y
+            xw[:, tile] = columns(w, j) * x
+        # the end state: grown * start + (x * w)^T B, every head
+        grow(state, grown, _tn(cast(xw[...]), Bq))
 
     def bwd_kernel(x_ref, b_ref, c_ref, lc_ref, lr_ref, dc_ref, dr_ref,
                    dy_ref, s_ref, dx_ref, db_ref, dcm_ref, ddtc_ref, ddtr_ref,
-                   dlc_ref, dlr_ref, dstate):
+                   dlc_ref, dlr_ref, dstate, edy, wx):
         @pl.when(pl.program_id(2) == 0)
         def _():
             dstate[...] = jnp.zeros(dstate.shape, dstate.dtype)
 
-        Bq, Cq, CB, causal, row = squares(b_ref, c_ref)
-        Bm = b_ref[...]
-        d_cb = jnp.zeros((Q, Q), jnp.float32)
-        dB = jnp.zeros(Bm.shape, jnp.float32)
-        dC = jnp.zeros(Bm.shape, jnp.float32)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (Q, hg), 1)
+        Bq, Cq, CB = squares(b_ref, c_ref)
+        lr, e, w, grown = head_rows(lr_ref, dr_ref)
+        dtr = dr_ref[...]
+        start, d_end = s_ref[...].reshape(hg * P, N), dstate[...]
+        from_start = _nt(Cq, cast(start))         # C start^T, every head
+        b_d_end = _nt(Bq, cast(d_end))            # B d_end^T, every head
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Q, TW), 1) // P
         sub = jax.lax.broadcasted_iota(jnp.int32, (hg, Q), 0)
-        ddt_c = jnp.zeros((Q, hg), jnp.float32)
-        dl_c = jnp.zeros((Q, hg), jnp.float32)
+        d_cb = jnp.zeros((Q, Q), jnp.float32)
+        row_sums = jnp.zeros((Q, hg), jnp.float32)
         ddt_r = jnp.zeros((hg, Q), jnp.float32)
         dl_r = jnp.zeros((hg, Q), jnp.float32)
+        for j in range(hg // T):
+            tile = slice(j * TW, (j + 1) * TW)
+            x, dy = x_ref[:, tile], dy_ref[:, tile]
+            w_j = columns(w, j)
+            dx = w_j * b_d_end[:, tile]
+            for i in range(T):
+                h = j * T + i
+                decay = decays(lr, h)
+                # y = (CB * decay * dt_s) x + e * C start^T
+                scores = CB * decay * dtr[h:h + 1]
+                d_scores = _nt(cast(jnp.where(lane == i, dy, 0.0)), cast(x))
+                d_decay = d_scores * decay
+                d_cb = d_cb + d_decay * dtr[h:h + 1]
+                d_cbd = d_decay * CB
+                pulled = d_cbd * dtr[h:h + 1]
+                row_sums = row_sums + summed(pulled, h)
+                ddt_r = jnp.where(sub == h, jnp.sum(d_cbd, axis=0,
+                                                    keepdims=True), ddt_r)
+                dl_r = jnp.where(sub == h, -jnp.sum(pulled, axis=0,
+                                                    keepdims=True), dl_r)
+                dx = jnp.where(lane == i, dx + _tn(cast(scores), cast(dy)),
+                               dx)
+            dx_ref[:, tile] = dx
+            edy[:, tile] = columns(e, j) * dy
+            wx[:, tile] = w_j * x
+        # what is left of the decays' and steps' gradients, every head at
+        # once: (Q, hg) columns, and (1, hg) for the chunk's last row
+        lc = lc_ref[...]
+        last = lc[Q - 1:Q, :]
+        since = jnp.exp(last - lc)
+        # the end state: grown * start + (x * w)^T B
+        dw = summed(x_ref[...] * b_d_end)
+        dw_w = dw * since * dc_ref[...]
+        kept = d_end * start
+        head = jax.lax.broadcasted_iota(jnp.int32, (1, hg), 1)
+        kept_sums = jnp.zeros((1, hg), jnp.float32)
         for h in range(hg):
-            lc, dtc = column(lc_ref, h), column(dc_ref, h)
-            lr, dtr = lr_ref[h:h + 1, :], dr_ref[h:h + 1, :]
-            xh, dyh = head(x_ref, h), head(dy_ref, h)
-            start, d_end = s_ref[h], dstate[h]
-            decay = decays(lc, lr, causal)
-            e, last = jnp.exp(lc), lc[Q - 1:Q, :]
-            grown = jnp.exp(last)
-            w = jnp.exp(last - lc) * dtc
-            # y = (CB * decay * dt_s) x + e * C start^T
-            scores = CB * decay * dtr
-            d_scores = _nt(cast(dyh), cast(xh))
-            d_cbd = d_scores * CB * decay
-            d_cb = d_cb + d_scores * decay * dtr
-            pulled = d_cbd * dtr
-            edy = e * dyh
-            from_start = _nt(Cq, cast(start))
-            # the end state: grown * start + (x * w)^T B
-            x_d_end = jnp.dot(cast(xh), cast(d_end),
-                              preferred_element_type=jnp.float32)
-            dw = jnp.sum(x_d_end * Bm, axis=1, keepdims=True)
-            dx_ref[:, h * P:(h + 1) * P] = _tn(cast(scores), cast(dyh)) \
-                + w * _nt(Bq, cast(d_end))
-            dB = dB + w * x_d_end
-            dC = dC + jnp.dot(cast(edy), cast(start),
-                              preferred_element_type=jnp.float32)
-            d_last = jnp.sum(d_end * grown * start) + jnp.sum(dw * w)
-            dl = jnp.sum(pulled, axis=1, keepdims=True) \
-                + jnp.sum(edy * from_start, axis=1, keepdims=True) - dw * w \
-                + jnp.where(row[:, :1] == Q - 1, d_last, 0.0)
-            ddt_c = jnp.where(lane == h, dw * jnp.exp(last - lc), ddt_c)
-            dl_c = jnp.where(lane == h, dl, dl_c)
-            ddt_r = jnp.where(sub == h, jnp.sum(d_cbd, axis=0,
-                                                keepdims=True), ddt_r)
-            dl_r = jnp.where(sub == h, -jnp.sum(pulled, axis=0,
-                                                keepdims=True), dl_r)
-            dstate[h] = grown * d_end + _tn(cast(edy), Cq)
-        db_ref[...] = dB + _tn(cast(d_cb), Cq)
-        dcm_ref[...] = dC + jnp.dot(cast(d_cb), Bq,
-                                    preferred_element_type=jnp.float32)
-        ddtc_ref[...], dlc_ref[...] = ddt_c, dl_c
+            kept_sums = jnp.where(head == h, jnp.sum(kept[h * P:(h + 1) * P]),
+                                  kept_sums)
+        d_last = jnp.exp(last) * kept_sums + jnp.sum(dw_w, axis=0,
+                                                      keepdims=True)
+        at_last = jax.lax.broadcasted_iota(jnp.int32, (Q, hg), 0) == Q - 1
+        dlc_ref[...] = row_sums + summed(edy[...] * from_start) - dw_w \
+            + jnp.where(at_last, d_last, 0.0)
+        ddtc_ref[...] = dw * since
         ddtr_ref[...], dlr_ref[...] = ddt_r, dl_r
+        d_cb = jnp.where(causal(), d_cb, 0.0)
+        e_dy = cast(edy[...])
+        db_ref[...] = jnp.dot(cast(wx[...]), cast(d_end),
+                              preferred_element_type=jnp.float32) \
+            + _tn(cast(d_cb), Cq)
+        dcm_ref[...] = jnp.dot(e_dy, cast(start),
+                               preferred_element_type=jnp.float32) \
+            + jnp.dot(cast(d_cb), Bq, preferred_element_type=jnp.float32)
+        grow(dstate, grown, _tn(e_dy, Cq))
 
     def specs(reverse):
         at = (lambda c: n - 1 - c) if reverse else (lambda c: c)
@@ -1596,8 +1667,8 @@ def _ssd_kernels(shape, groups, state_size, chunk, interpret):
                     [wide, states],
                     [jax.ShapeDtypeStruct((Bt, L, H * P), f32),
                      jax.ShapeDtypeStruct((Bt, n, H, P, N), f32)],
-                    [pltpu.VMEM((hg, P, N), f32)])(
-                        x, B, C, lc, lr, dc, dr)
+                    [pltpu.VMEM((hg * P, N), f32),
+                     pltpu.VMEM((Q, hg * P), f32)])(x, B, C, lc, lr, dc, dr)
 
     def backward(x, B, C, lc, lr, dc, dr, dy, starts):
         wide, group, cols, rows, states = specs(True)
@@ -1612,7 +1683,8 @@ def _ssd_kernels(shape, groups, state_size, chunk, interpret):
                      jax.ShapeDtypeStruct((Bt, G, hg, L), f32),
                      jax.ShapeDtypeStruct((Bt, G, L, hg), f32),
                      jax.ShapeDtypeStruct((Bt, G, hg, L), f32)],
-                    [pltpu.VMEM((hg, P, N), f32)])(
+                    [pltpu.VMEM((hg * P, N), f32)]
+                    + [pltpu.VMEM((Q, hg * P), f32)] * 2)(
                         x, B, C, lc, lr, dc, dr, dy, starts)
 
     return forward, backward
@@ -1638,16 +1710,32 @@ def ssd_scan(x, dt, A, B, C, D, chunk=SSD_CHUNK, interpret=None):
     interpreted): two kernels under the scope ``ssm.scan``, ``ssd_scan_fwd``
     and ``ssd_scan_bwd``, each a grid step a (row of the batch, group,
     chunk), the chunk axis walked in order forward and in reverse backward.
-    A step makes ``C B^T`` once for the group's heads, and each head's state
-    (``P x N`` float32) stays in VMEM from chunk to chunk; the forward
-    kernel writes ``y`` and each chunk's starting state, the backward kernel
-    carries the state's gradient back and gives the gradients of ``x``,
-    ``B``, ``C``, the steps and the decays, which XLA takes on to ``dt`` and
-    ``A``.  The products take bfloat16 operands and accumulate in float32,
-    what XLA's default precision gives float32 operands on the TPU; the
-    decays and the state are float32.  Elsewhere XLA's chunked form
-    (``_ssd_chunks_reference``): 2.8 times the kernels' time on a v5e at
-    8,192 rows of 64 heads (PERF.md, PR 41)."""
+    The group's states (``hg P x N`` float32, ``hg = H / G`` heads) stay in
+    VMEM from chunk to chunk.  The products that read the group's ``B`` or
+    ``C`` run once for its ``hg`` heads, over their operands side by side
+    (``x``, ``dy`` and ``y`` as ``Q x hg P``, the states as ``hg P x N``;
+    ``dB`` and ``dC``'s state terms contract over the heads): ``C B^T``, ``C
+    S^T``, the new states and forward, and ``B dS^T``, ``(w x) dS``, ``(e
+    dy) S`` and ``(e dy)^T C`` backward; only the products of each head's
+    own ``chunk x chunk`` square run a head at a time.  The heads a shared
+    product covers are counted as ``ssm.scan_group_heads`` (0 for XLA's
+    form).  The rest of a step's work walks the heads in tiles of a vector
+    register's 128 lanes (two heads of 64), so that what is done to ``x``,
+    ``dy`` and their gradients fills the lanes; a head's decays and steps
+    come down the lanes as transposes of their rows, and what is summed
+    across a head's lanes or a square's rows is summed by the MXU (the
+    float32 summands as two bfloat16 parts, to 16 bits), not across the
+    lanes one register at a time.  The forward kernel writes ``y`` and
+    each chunk's starting state, the backward kernel carries the state's
+    gradient back and gives the gradients of ``x``, ``B``, ``C``, the steps
+    and the decays, which XLA takes on to ``dt`` and ``A``.  The products
+    take bfloat16 operands and accumulate in float32, what XLA's default
+    precision gives float32 operands on the TPU; the decays and the state
+    are float32.  On a v5e at 8,192 rows of 64 heads in 8 groups a forward
+    call takes 0.91 ms and a backward call 1.42 (1.06 and 2.04 with a
+    head's products and sums a head at a time; PERF.md).  Elsewhere XLA's
+    chunked form (``_ssd_chunks_reference``), 2.8 times the first kernels'
+    time there."""
     import jax
     import jax.numpy as jnp
 
@@ -1672,10 +1760,12 @@ def ssd_scan(x, dt, A, B, C, D, chunk=SSD_CHUNK, interpret=None):
     if interpret is None and not (
             jax.default_backend() == "tpu" and (hg * P) % 128 == 0
             and N % 128 == 0 and chunk % 128 == 0):
+        profiler.count("ssm.scan_group_heads", 0)
         with jax.named_scope("ssm.scan"):
             y, _ = _ssd_chunks_reference(xp, dtp, l, Bp, Cp, chunk)
             y = y[:, :L] + D.astype(f32)[:, None] * x.astype(f32)
             return y.astype(x.dtype)
+    profiler.count("ssm.scan_group_heads", hg)
     forward, backward = _ssd_kernels((Bt, Lp, H, P), G, N, chunk,
                                      interpret)
 

@@ -26,6 +26,9 @@ from mxnet_tpu.serving.decode import (DecodeEngine, ShardedDecodeModel,
                                       TinyCausalLM)
 
 HBM_BYTES = 16 * 2 ** 30      # one v5e chip
+# the seventh cell's scan, forward and backward, compiled with each head's
+# products its own: sharing them across a group's heads may keep no more
+SCAN_TEMP_BYTES = 637985792
 
 # chip_smoke.py's serve phase: the width a chip is built for
 GEOM = dict(vocab_size=50304, hidden=2048, num_layers=16, max_len=4096)
@@ -345,6 +348,9 @@ def test_state_space_scan_compiles_for_v5e(one_chip):
     assert _pallas_grids(jax.make_jaxpr(step)(*shapes).jaxpr) == {
         "ssd_scan_fwd": (1, 8, 64), "ssd_scan_bwd": (1, 8, 64)}
     _fits(compiled)
+    # a group's heads share their products inside a grid step, in VMEM:
+    # the step keeps no more in HBM than when each head had its own
+    assert compiled.memory_analysis().temp_size_in_bytes <= SCAN_TEMP_BYTES
 
 
 def test_mamba2_mixer_compiles_for_v5e(one_chip, monkeypatch):

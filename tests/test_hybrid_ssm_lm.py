@@ -157,23 +157,33 @@ def _sequential(x, dt, A, B, C, D):
     return jnp.moveaxis(ys, 0, 1) + D[:, None] * x
 
 
-def _scan_operands(length, seed=0):
-    """4 heads of 8 in 2 groups of a state of 16, with published-style
-    decays and steps."""
+def _scan_operands(length, seed=0, heads=4, groups=2, width=8):
+    """``heads`` heads of ``width`` in ``groups`` groups of a state of 16,
+    with published-style decays and steps."""
     r = np.random.RandomState(seed)
-    step = np.exp(r.uniform(np.log(1e-3), np.log(1e-1), (BATCH, length, 4)))
+    step = np.exp(r.uniform(np.log(1e-3), np.log(1e-1),
+                            (BATCH, length, heads)))
     return [jnp.asarray(a, jnp.float32) for a in (
-        r.normal(size=(BATCH, length, 4, 8)), step, -r.uniform(1, 16, 4),
-        r.normal(size=(BATCH, length, 2, 16)),
-        r.normal(size=(BATCH, length, 2, 16)), r.normal(size=4))]
+        r.normal(size=(BATCH, length, heads, width)), step,
+        -r.uniform(1, 16, heads), r.normal(size=(BATCH, length, groups, 16)),
+        r.normal(size=(BATCH, length, groups, 16)), r.normal(size=heads))]
 
 
-@pytest.mark.parametrize("form", ["xla", "kernels"])
-@pytest.mark.parametrize("length", [40, 44])
-def test_scan_is_the_sequential_recurrence(form, length):
+@pytest.mark.parametrize("form,length,heads,groups,width", [
+    pytest.param(form, length, 4, 2, 8, id="%d-%s" % (length, form))
+    for length in (40, 44) for form in ("xla", "kernels")] + [
+    # a group of 8 heads, as the seventh cell's, whose shared products
+    # cover all 8: one group, and two
+    pytest.param("kernels", 44, 8, 1, 8, id="44-kernels-8x1"),
+    pytest.param("kernels", 44, 16, 2, 8, id="44-kernels-16x2"),
+    # heads of 64, two to a tile of 128 lanes, as the cell's: two tiles
+    pytest.param("kernels", 44, 4, 1, 64, id="44-kernels-4x1-of-64")])
+def test_scan_is_the_sequential_recurrence(form, length, heads, groups,
+                                           width):
     """Forward and every gradient, chunks of 8 over 40 rows and over 44 (the
     last chunk padded), the kernels interpreted."""
-    operands = _scan_operands(length)
+    operands = _scan_operands(length, heads=heads, groups=groups,
+                              width=width)
     cot = jax.random.normal(jax.random.PRNGKey(1), operands[0].shape)
     interpret = True if form == "kernels" else None
 
@@ -212,6 +222,10 @@ def _pallas_names(jaxpr):
     return names
 
 
+def _group_heads():
+    return profiler.totals()["ssm.scan_group_heads"]["max"]
+
+
 def test_scan_counts_its_chunks_and_runs_two_kernels():
     operands = _scan_operands(44)
     profiler.reset_spans()
@@ -220,6 +234,14 @@ def test_scan_counts_its_chunks_and_runs_two_kernels():
     assert _pallas_names(jax.make_jaxpr(step)(*operands).jaxpr) == {
         "ssd_scan_fwd", "ssd_scan_bwd"}
     assert profiler.totals()["ssm.chunks"]["max"] == BATCH * 6
+    assert _group_heads() == 2
+    profiler.reset_spans()
+    jax.make_jaxpr(step)(*_scan_operands(44, heads=16, groups=2))
+    assert _group_heads() == 8
+    profiler.reset_spans()
+    jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(pallas_ops.ssd_scan(
+        *a, chunk=8)), range(6)))(*operands)
+    assert _group_heads() == 0
     with pytest.raises(ValueError, match="whole groups"):
         pallas_ops.ssd_scan(operands[0][:, :, :3], *operands[1:3],
                             *operands[3:])
